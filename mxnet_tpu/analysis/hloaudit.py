@@ -70,6 +70,26 @@ _PROGRAM_FILE = {
 }
 
 
+def pin_cpu_with_spmd_dump(devices, prefix):
+    """Set-up shared by the fresh-process entry points that read the
+    post-SPMD HLO dump (``--hlo-check``, ``--bench``): ask XLA for the
+    dump (the flag is read once, at backend start), select `devices`
+    CPU devices, and turn the persistent compilation cache off for this
+    process — a cache hit compiles nothing, so it dumps nothing.
+    Returns the dump directory (the caller removes it)."""
+    import tempfile
+    import jax
+    from mxnet_tpu.config import pin_cpu
+    dump = tempfile.mkdtemp(prefix=prefix)
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_dump_to={dump} --xla_dump_hlo_as_text"
+        + " --xla_dump_hlo_pass_re=.*spmd.*")
+    pin_cpu(devices)
+    jax.config.update("jax_enable_compilation_cache", False)
+    return dump
+
+
 # -- pure HLO-text helpers ---------------------------------------------------
 # (no jax imports: unit-testable on strings, importable everywhere)
 
@@ -167,39 +187,42 @@ def collective_pairing_ok(hlo):
         for kind in COLLECTIVE_KINDS)
 
 
+# one collective instruction: its result type — one array, or the tuple
+# XLA's all-reduce combiner makes of several — then the op name
 _COLL_RX = re.compile(
-    r"=\s*(\w+)\[([\d,]*)\][^=\n]*?"
+    r"=\s*(\([^()\n]*\)|\w+\[[\d,]*\][^=\n]*?)\s*"
     rf"({'|'.join(re.escape(k) for k in COLLECTIVE_KINDS)})"
     r"(?:-start)?\(")
+_ARRAY_RX = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
 def collectives_in_text(hlo):
-    """kind -> [(dtype, "d0,d1,...")] for every collective in ONE module
-    text (a Compiled's as_text()). The in-process twin of
-    spmd_collectives for audits that already hold the optimized module —
-    no dump directory round-trip. Caveat: backend legalization may have
-    re-widened dtypes by this stage (cpu promotes bf16), so use it for
-    shape/count structure, the dump form for wire-dtype questions."""
+    """kind -> [(dtype, "d0,d1,...")] for every array a collective
+    moves in ONE module text (a Compiled's as_text(), or a dumped
+    module); a combined (tuple-typed) collective contributes each of its
+    arrays. Caveat: backend legalization may have re-widened dtypes in
+    the final module (cpu promotes bf16), so use that for shape/count
+    structure, the post-SPMD dump for wire-dtype questions."""
     colls = {kind: [] for kind in COLLECTIVE_KINDS}
     for m in _COLL_RX.finditer(hlo):
-        colls[m.group(3)].append([m.group(1), m.group(2)])
+        colls[m.group(2)].extend(
+            [dt, shape] for dt, shape in _ARRAY_RX.findall(m.group(1)))
     return colls
 
 
 def spmd_collectives(dump_dir, module_substr="jit_step"):
-    """kind -> [(dtype, "d0,d1,...")] for every collective in the
-    post-SPMD dump of modules matching ``module_substr``. Same dump
-    stage as spmd_allreduces (the wire dtype the partitioner chose);
-    reduce-scatter's dumped OUTPUT shape is the per-device SHARD —
-    collective_wire_bytes re-globalizes it with n_dev."""
+    """collectives_in_text over the post-SPMD dump of the modules
+    matching ``module_substr``. Same dump stage as spmd_allreduces (the
+    wire dtype the partitioner chose); reduce-scatter's dumped OUTPUT
+    shape is the per-device SHARD — collective_wire_bytes re-globalizes
+    it with n_dev."""
     colls = {kind: [] for kind in COLLECTIVE_KINDS}
     pat = os.path.join(dump_dir,
                        f"*{module_substr}*after_spmd-partitioning*")
     for f in sorted(glob.glob(pat)):
         with open(f, encoding="utf-8") as fh:
-            text = fh.read()
-        for m in _COLL_RX.finditer(text):
-            colls[m.group(3)].append([m.group(1), m.group(2)])
+            for kind, found in collectives_in_text(fh.read()).items():
+                colls[kind].extend(found)
     return colls
 
 
@@ -298,10 +321,11 @@ def parse_last_metric(stdout, metric):
 
 def _audit_programs():
     """Compile the program matrix and print ONE ``hlo_audit`` JSON line.
-    Must run in a process whose jax backend it owns (``_pin_cpu`` before
-    the first jax import)."""
-    from mxnet_tpu.amp.__main__ import _pin_cpu, _mlp_sym, _trainer
-    _pin_cpu(2)
+    Must run in a process whose jax backend it owns (``config.pin_cpu``
+    before the first backend use)."""
+    from mxnet_tpu.amp.__main__ import _mlp_sym, _trainer
+    from mxnet_tpu.config import pin_cpu
+    pin_cpu(2)
     import numpy as np
     import jax
     import mxnet_tpu as mx

@@ -218,8 +218,8 @@ _DOCUMENTED = {
     # docs/TELEMETRY.md): MXNET_DEVSTATS=0 disables XLA cost/memory
     # extraction, MFU/roofline step fields, HBM preflight and the
     # recompile sentinel (default on; off is bit-identical);
-    # _PEAK_TFLOPS/_PEAK_GBPS override the per-backend hardware peak
-    # table MFU/roofline divide by; _HBM_BYTES pins the device memory
+    # _PEAK_TFLOPS/_PEAK_GBPS override the per-device_kind peak table
+    # MFU/roofline divide by; _HBM_BYTES pins the device memory
     # budget the preflight checks against (autodetected from PJRT
     # memory_stats where the backend exposes it — cpu does not);
     # _RECOMPILE_LIMIT is the per-program compile count past which the
@@ -286,58 +286,42 @@ def list_vars():
 
 
 def enable_compile_cache(path):
-    """Point JAX's persistent XLA compilation cache at `path` (creating
-    it), so every jit/bind in this process — executor programs, Gluon
-    CachedOp, serving bucket plans — is written to and re-loaded from
-    disk across process restarts. The min-compile-time/min-entry-size
-    thresholds are zeroed where the jax version has them, so small
-    programs cache too (the warm-vs-cold win is measured by bench.py's
-    compile_cache lane). Returns True when the cache was wired."""
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                         ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:
-                pass    # older jax: threshold option absent
-        try:
-            # jax latches its cache handle at the first compile: if any
-            # program compiled before the dir was set, the cache sits
-            # initialized-with-no-dir and silently writes nothing —
-            # re-initialize so the new dir takes effect mid-process
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
-        return True
-    except Exception:
-        return False
+    """Turn on JAX's persistent XLA compilation cache, so every jit/bind
+    in this process — executor programs, Gluon CachedOp, serving bucket
+    plans — is written to and re-loaded from disk across process
+    restarts. Returns the directory in use.
+
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX itself has placed the
+    cache there and `path` is ignored: no code of this repo moves a
+    cache that was placed from outside (the directory is part of a
+    deployment, and of the cache's key). Otherwise the cache goes to
+    `path` (created), with the min-compile-time/min-entry-size
+    thresholds zeroed so small programs cache too."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return jax.config.jax_compilation_cache_dir
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches its cache handle at the first compile: if any program
+    # compiled before the dir was set, the cache sits initialized-with-
+    # no-dir and silently writes nothing — re-initialize so the new dir
+    # takes effect mid-process
+    compilation_cache.reset_cache()
+    return str(path)
 
 
-def disable_compile_cache():
-    """Undo enable_compile_cache: detach the persistent cache dir and
-    drop jax's latched cache handle, so later compiles in this process
-    go straight to XLA again. Needed by anything that enables the cache
-    temporarily (bench.py's compile_cache lane): on the cpu backend,
-    leaving the persistent cache armed has been observed to corrupt
-    later unrelated compiles (libc-level segfault executing a
-    freshly-compiled donated trainer step, jax 0.4.37 — reproduced with
-    the cache as the only variable), and it skews any subsequently
-    TIMED compile with cache-write I/O. Returns True when detached."""
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", None)
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
-        return True
-    except Exception:
-        return False
+def pin_cpu(n):
+    """Select the CPU backend with `n` virtual devices, before the first
+    backend use. For the `--selftest`/`--hlo-check`/`--bench` entry
+    points that assert on a CPU mesh of a stated size whatever the
+    machine has; the config values win over JAX_PLATFORMS,
+    JAX_NUM_CPU_DEVICES and XLA_FLAGS inherited from a parent."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", int(n))
 
 
 def _apply_startup():

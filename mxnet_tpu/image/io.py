@@ -220,7 +220,7 @@ def ImageRecordIter(path_imgrec, data_shape, batch_size, prefetch_buffer=2,
     Beyond-reference knob `output_dtype="uint8"`: deliver RAW bytes (crop/
     mirror only, no mean/std) — 4x less host->device transfer; normalize
     on-device (e.g. DataParallelTrainer input_preproc). The TPU-native
-    input regime for remote/tunneled or PCIe-bound hosts."""
+    input regime for transfer-bound hosts."""
     from .. import _native
     _pass_keys = ("shuffle", "preprocess_threads", "label_width",
                   "data_name", "label_name", "num_parts", "part_index",

@@ -100,7 +100,10 @@ class Initializer:
             desc.global_init = self
         init = desc.attrs.get("__init__", "")
         if init:
-            klass, kwargs = json.loads(init)
+            # `Initializer.dumps()` JSON, or the plain registered name a
+            # gluon Parameter(init="ones") leaves on its variable
+            klass, kwargs = json.loads(init) if init.startswith("[") \
+                else (init, {})
             create(klass, **kwargs)._init_weight(desc, arr)
             self._verbose_print(desc, init, arr)
         elif desc.endswith("weight"):

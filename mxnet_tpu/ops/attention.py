@@ -25,12 +25,11 @@ _LSE_LANES = 8    # minor replication of the per-row lse (TPU block tiling)
 
 
 def _auto_block(s):
-    """Default block size: the LARGEST of 512/256/128 dividing S. The r5
-    sweep (tools/attention_sweep.py, docs/ROUND5.md) measured 512-blocks
-    at ~1.9x the r4 default 128 on v5e (seq 4096 causal fwd+bwd: 984k vs
-    527k tok/s) — bigger tiles amortize the per-block softmax bookkeeping
-    and keep the MXU busier. Sequences not divisible by 128 fall back to
-    a single block (small-S case)."""
+    """Default block size: the LARGEST of 512/256/128 dividing S —
+    bigger tiles amortize the per-block softmax bookkeeping and keep the
+    MXU busier (tools/attention_sweep.py sweeps the curve; its gain on
+    this runtime is a claim to re-measure, ROADMAP S4). Sequences not
+    divisible by 128 fall back to a single block (small-S case)."""
     for blk in (512, 256, 128):
         if s % blk == 0:
             return blk
@@ -474,10 +473,7 @@ def _pallas_eligible(q, k, platform=None, block_q=None, block_k=None):
     # caller forces pallas explicitly
     if platform is not None:
         return platform == "tpu"
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
@@ -701,10 +697,7 @@ def _decode_eligible(q, k, platform=None):
         return False
     if platform is not None:
         return platform == "tpu"
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def decode_attention(q, k, v, lengths, scale=None, force=None,

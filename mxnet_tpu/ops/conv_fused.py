@@ -1,12 +1,13 @@
 """Pallas conv+BN(+ReLU) megakernels for the ResNet hot path.
 
-Role: built to test round 3's hypothesis (docs/perf_analysis_r03.md §6)
+Role: built to test the hypothesis
 that XLA would not fuse a reduction epilogue (BN statistics) into a
 convolution's output nor keep the normalize/mask chain in VMEM between
 a conv and its consumer — which, if true, would have made every
-BatchNorm cost a full extra read pass. THE HYPOTHESIS WAS REFUTED BY
-MEASUREMENT (docs/megakernel_r04.md): XLA already performs both
-fusions. The kernels implement, for the 1x1 convolutions (2/3 of
+BatchNorm cost a full extra read pass. An early device trace refuted
+it: XLA already performed both fusions (that record is gone; ROADMAP D5
+asks for the re-measurement that decides whether this file stays). The
+kernels implement, for the 1x1 convolutions (2/3 of
 ResNet-50's convs, touching its largest tensors):
 
   - `conv1x1(want_stats=True)`: y = w @ x with the per-channel sum /
@@ -25,12 +26,9 @@ im2col, src/operator/nn/convolution-inl.h, pays the same GEMM but through
 cuDNN). Weights (Co, Ci) live whole in VMEM (<=2 MB for every ResNet
 shape).
 
-All kernels are shape-specialized at trace time. These kernels are a
-MEASURED ARTIFACT, not the default conv path: on the real v5e they tie
-XLA's fused chain at best (XLA already output-fuses the BN statistics
-into conv fusions and runs flat chains at the HBM roofline) — see
-docs/megakernel_r04.md for the device-trace evidence. They remain
-importable and tested for direct use and future layout-regime work.
+All kernels are shape-specialized at trace time. They are NOT the default
+conv path (nothing in the package imports them); they remain importable
+and tested for direct use.
 """
 from __future__ import annotations
 

@@ -522,7 +522,7 @@ register("SoftmaxOutput", _softmax_output,
 def _bn_train(data, gamma, beta, axis, eps, fix_gamma, relu):
     """Training-mode BN core: returns (out, batch_mean, batch_var).
 
-    Hand-written vjp for HBM-roofline reasons (docs/perf_analysis_r03.md):
+    Hand-written vjp to save HBM passes:
     the backward does the minimal two passes (one for the dgamma/dbeta
     sums, one for dx) instead of autodiff's mean->var dependency chain.
     Stats accumulate in fp32 regardless of the activation dtype (stable

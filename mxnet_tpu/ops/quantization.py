@@ -463,10 +463,7 @@ def _qmm_eligible(x, q, platform=None):
         return False
     if platform is not None:
         return platform == "tpu"
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def quantized_matmul(x, q, scale, force=None, platform=None):

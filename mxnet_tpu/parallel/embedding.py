@@ -467,7 +467,12 @@ class EmbeddingTrainer:
                 rows = jax.lax.psum_scatter(
                     contrib, ax, scatter_dimension=0, tiled=True)
             else:
-                rows, inv = table, flat
+                rows, inv = jax.lax.pcast(table, ax, to="varying"), flat
+            # differentiate at device-varying copies of the replicated
+            # inputs: shard_map psums the cotangent of a replicated input
+            # by itself, and the explicit psums of [2]/[4] below would
+            # then count every device's gradient n_dev times
+            mlp_v = jax.lax.pcast(mlp, ax, to="varying")
 
             def loss_fn(rows, mlp):
                 emb = jnp.take(rows, inv, axis=0)
@@ -477,7 +482,7 @@ class EmbeddingTrainer:
                 return _bce_logits(self._mlp_forward(mlp, feat), labels)
 
             loss, (g_rows, g_mlp) = jax.value_and_grad(
-                loss_fn, argnums=(0, 1))(rows, mlp)
+                loss_fn, argnums=(0, 1))(rows, mlp_v)
 
             if sparse:
                 # [2] row-sparse gradient exchange: (rows, vals) pairs
@@ -501,6 +506,9 @@ class EmbeddingTrainer:
                 # those writes)
                 uniq2, inv2, nnz = sp.unique_rows(all_ids, n_dev * U,
                                                   sent)
+                # every device counts the same gathered id list; pmax of
+                # equal values is exact and types nnz as replicated
+                nnz = jax.lax.pmax(nnz, ax)
                 gsum = sp.segment_sum_rows(vals, inv2, n_dev * U)
                 owned2 = (uniq2 >= lo) & (uniq2 < lo + R)
                 rows2 = jnp.where(owned2, uniq2 - lo, R)
@@ -755,8 +763,8 @@ def selftest(argv_devices=2):
     import json
     import subprocess
     import sys
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(argv_devices)
+    from mxnet_tpu.config import pin_cpu
+    pin_cpu(argv_devices)
     import jax as _jax
     from mxnet_tpu.parallel import data_parallel_mesh
 
@@ -864,16 +872,8 @@ def hlo_check(exchange, compress="none", vocab=2048, devices=2,
     post-SPMD collectives + ring wire bytes, split into the embedding
     exchange vs the (vocab-independent) MLP all-reduce."""
     import json
-    import tempfile
-    import os as _os
-    dump = tempfile.mkdtemp(prefix="embed_hlo_")
-    _os.environ["XLA_FLAGS"] = (
-        _os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}"
-        + f" --xla_dump_to={dump} --xla_dump_hlo_as_text"
-        + " --xla_dump_hlo_pass_re=.*spmd.*")
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.analysis.hloaudit import pin_cpu_with_spmd_dump
+    dump = pin_cpu_with_spmd_dump(devices, "embed_hlo_")
     import jax as _jax
     from mxnet_tpu.parallel import data_parallel_mesh
     from mxnet_tpu.analysis.hloaudit import (spmd_collectives,
@@ -922,17 +922,9 @@ def bench(devices=8, steps=10, vocab=65536, dim=48, batch=256, slots=8):
     HLO-measured wire bytes per step for both arms, and the touched-row
     fraction. Prints one embed_bench JSON line."""
     import json
-    import tempfile
     import time
-    import os as _os
-    dump = tempfile.mkdtemp(prefix="embed_bench_hlo_")
-    _os.environ["XLA_FLAGS"] = (
-        _os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}"
-        + f" --xla_dump_to={dump} --xla_dump_hlo_as_text"
-        + " --xla_dump_hlo_pass_re=.*spmd.*")
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.analysis.hloaudit import pin_cpu_with_spmd_dump
+    dump = pin_cpu_with_spmd_dump(devices, "embed_bench_hlo_")
     import jax as _jax
     from mxnet_tpu.parallel import data_parallel_mesh
     from mxnet_tpu.analysis.hloaudit import (spmd_collectives,

@@ -3,16 +3,16 @@ come from.
 
 Every parallel module (dp/zero/tp/pp/sp/embedding, the planner) builds
 its mesh through these constructors and imports `shard_map`/`pcast`
-from here (re-exported from ._compat, the jax API-drift shim) — a mesh
-axis name used anywhere in the package is declared in AXIS_NAMES, and
-`axis_size`/`data_axis` replace the ad-hoc `mesh.shape[name]` /
+from here — a mesh axis name used anywhere in the package is declared in
+AXIS_NAMES, and `axis_size`/`data_axis` replace the ad-hoc `mesh.shape[name]` /
 `mesh.axis_names[0]` lookups that used to be copied per module.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from ._compat import pcast, shard_map  # noqa: F401  (re-exports)
+from jax import shard_map  # noqa: F401  (re-export)
+from jax.lax import pcast  # noqa: F401  (re-export)
 
 # canonical axis vocabulary (docs/PLANNER.md): data-parallel batch axis,
 # megatron/tensor axis, pipeline-stage axis, sequence axis, expert axis.

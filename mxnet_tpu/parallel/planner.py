@@ -31,8 +31,10 @@ picks one by MEASURED compiled cost instead of folklore:
                         + wire_bytes/wire_bw          (docs/PLANNER.md)
 
                wire_bw is MXNET_PLAN_WIRE_GBPS (default 25 GB/s — a
-               conservative ICI figure; override per fabric). A
-               compiled peak over the HBM budget rejects the plan too.
+               conservative ICI figure; override per fabric). A device
+               without a devstats peaks row (the CPU) ranks by the wire
+               term alone. A compiled peak over the HBM budget rejects
+               the plan too.
   selection    deterministic argmin over (cost_s, name); ties break
                lexicographically so two runs always agree.
 
@@ -514,8 +516,12 @@ def score_plan(model, plan, devices=None, wire_bw=None):
     wires = collective_wire_bytes(colls, plan.n_devices)
     wire = float(sum(wires.values()))
     pf, pb, _ = devstats.peaks()
-    cost = max(stats["flops"] / pf, stats["bytes_accessed"] / pb) \
-        + wire / wire_bw
+    # a device without a peaks row (the CPU) cannot price compute: its
+    # candidates rank by wire alone. To rank for a chip from a CPU mesh,
+    # name that chip's peaks with MXNET_DEVSTATS_PEAK_TFLOPS / _GBPS
+    compute = max(stats["flops"] / pf, stats["bytes_accessed"] / pb) \
+        if pf else None
+    cost = (compute or 0.0) + wire / wire_bw
     est = estimate_wire_bytes(model, plan,
                               bucket_bytes=getattr(tr, "_bucket_bytes",
                                                    None))
@@ -525,7 +531,7 @@ def score_plan(model, plan, devices=None, wire_bw=None):
             "wire_bytes_hlo": int(wire),
             "wire_bytes_estimate": est,
             "collectives": {k: len(v) for k, v in colls.items()},
-            "cost_s": cost}
+            "compute_s": compute, "cost_s": cost}
 
 
 class PlanReport:
@@ -681,8 +687,8 @@ def selftest(devices=8):
          steps, and its masters shard 1/(D·T).
     """
     import json
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.config import pin_cpu
+    pin_cpu(devices)
     import jax
     n_dev = min(devices, len(jax.devices()))
     model, batch, dim, nclass = _small_model()
@@ -781,8 +787,8 @@ def explain(plan_spec="auto", devices=8):
     """Print the per-candidate score table (the --explain CLI) plus one
     planner_explain JSON line."""
     import json
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.config import pin_cpu
+    pin_cpu(devices)
     import jax
     n_dev = min(devices, len(jax.devices()))
     model, _, _, _ = _small_model(batch=32, dim=64, hidden=256,
@@ -805,6 +811,8 @@ def explain(plan_spec="auto", devices=8):
             print(f"{name:>16}  {e['status']:>13}  {e['reason']}")
     print(f"{'-' * 72}\nselected: {report.chosen.name}  "
           f"knobs: {report.chosen.knobs()}")
+    if any(e.get("compute_s", 0) is None for e in report.entries):
+        print("no peaks row for this device: cost is the wire term alone")
     rec = {"metric": "planner_explain", "devices": n_dev}
     rec.update(report.to_dict())
     print(json.dumps(rec), flush=True)
@@ -819,8 +827,8 @@ def bench(devices=8, steps=8):
     and its predicted cost ranking; one plan_bench JSON line."""
     import json
     import time
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.config import pin_cpu
+    pin_cpu(devices)
     import jax
     n_dev = min(devices, len(jax.devices()))
     batch, dim, nclass, hidden = 16, 256, 16, 1024
@@ -884,15 +892,16 @@ def hlo_audit(devices=8):
     all-reduce, full donation, HLO wire bytes within 10% of the
     planner's analytic estimate. One planner_hlo_audit JSON line."""
     import json
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.config import pin_cpu
+    pin_cpu(devices)
     import jax
     from ..telemetry import devstats
     from ..analysis.hloaudit import (collectives_in_text,
                                      collective_wire_bytes,
                                      donated_param_indices,
                                      collective_pairing_ok, has_f64,
-                                     convert_count, allreduce_counts)
+                                     convert_count, allreduce_counts,
+                                     _elems)
     n_dev = min(devices, len(jax.devices()))
     t = 2 if n_dev % 2 == 0 and n_dev > 1 else 1
     model, batch, dim, nclass = _small_model(batch=16, dim=64,
@@ -904,14 +913,17 @@ def hlo_audit(devices=8):
     hlo = compiled.as_text()
     colls = collectives_in_text(hlo)
     wires = collective_wire_bytes(colls, n_dev)
-    # scalar all-reduces (loss/finite) ride every plan; gradient-SIZED
-    # ones mean the joint reduce-scatter regressed to dp
-    grad_ars = [c for c in colls["all-reduce"] if c[1]]
+    # small all-reduces ride every plan (loss/finite scalars, and the
+    # pmax that proves loss/outputs/aux replicated over the model axis);
+    # one the size of a gradient BUCKET means the joint reduce-scatter
+    # regressed to dp
+    L = tr._layout
+    grad_ars = [c for c in colls["all-reduce"]
+                if _elems(c[1]) >= min(L.padded)]
     wire_hlo = sum(wires.values())
     est = estimate_wire_bytes(model, plan,
                               bucket_bytes=tr._bucket_bytes)
     donated = donated_param_indices(hlo)
-    L = tr._layout
     expected = L.n_buckets * (1 + tr._n_states)   # masters + opt shards
     within = bool(est and abs(wire_hlo - est) <= 0.10 * est)
     n_sync, n_async = allreduce_counts(hlo)

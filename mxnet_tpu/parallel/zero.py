@@ -417,6 +417,7 @@ class ZeroTrainer(DataParallelTrainer):
         axes = self._shard_axes
         axis_names = tuple(self._mesh.axis_names)
         axis_sizes = self._axis_sizes
+        replica_axes = tuple(a for a in axis_names if a != ax)
         model_scale = (1.0 / self._model_factor
                        if self._model_factor > 1 else None)
         stage = self._zero_stage
@@ -569,6 +570,14 @@ class ZeroTrainer(DataParallelTrainer):
                 # shard-average for variances — docs/ZERO.md)
                 new_aux = tuple(jax.lax.pmean(a, ax) for a in new_aux)
             loss = jax.lax.psum(loss, ax)
+            if replica_axes:
+                # the model replicas of a data rank hold EQUAL loss/
+                # outputs/aux, but the joint all-gather types them as
+                # varying over the non-data axes; pmax of equal values is
+                # exact and gives them the replication the out_specs claim
+                new_aux, loss, outputs = jax.tree_util.tree_map(
+                    lambda x: jax.lax.pmax(x, replica_axes),
+                    (new_aux, loss, outputs))
             if has_ls:
                 new_ls = scaler.update_state(ls, finite)
                 return (tuple(new_masters), tuple(new_states), new_resid,
@@ -900,8 +909,8 @@ def selftest(argv_devices=2):
     import json
     import subprocess
     import sys
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(argv_devices)
+    from mxnet_tpu.config import pin_cpu
+    pin_cpu(argv_devices)
     import jax as _jax
     from mxnet_tpu.parallel import data_parallel_mesh
 
@@ -1023,16 +1032,8 @@ def hlo_check(stage, compress="none", dtype="float32", devices=2):
     report its post-SPMD collectives + ring wire bytes. stage 0 audits
     the plain dp baseline for the A/B."""
     import json
-    import tempfile
-    import os as _os
-    dump = tempfile.mkdtemp(prefix="zero_hlo_")
-    _os.environ["XLA_FLAGS"] = (
-        _os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}"
-        + f" --xla_dump_to={dump} --xla_dump_hlo_as_text"
-        + " --xla_dump_hlo_pass_re=.*spmd.*")
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.analysis.hloaudit import pin_cpu_with_spmd_dump
+    dump = pin_cpu_with_spmd_dump(devices, "zero_hlo_")
     import jax as _jax
     from mxnet_tpu.parallel import data_parallel_mesh
 
@@ -1086,17 +1087,9 @@ def bench(devices=8, steps=12, hidden=1024, batch=16):
     post-SPMD dump of each arm's distinctly-named module. Prints one
     zero_bench JSON line."""
     import json
-    import tempfile
     import time
-    import os as _os
-    dump = tempfile.mkdtemp(prefix="zero_bench_hlo_")
-    _os.environ["XLA_FLAGS"] = (
-        _os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}"
-        + f" --xla_dump_to={dump} --xla_dump_hlo_as_text"
-        + " --xla_dump_hlo_pass_re=.*spmd.*")
-    from mxnet_tpu.amp.__main__ import _pin_cpu
-    _pin_cpu(devices)
+    from mxnet_tpu.analysis.hloaudit import pin_cpu_with_spmd_dump
+    dump = pin_cpu_with_spmd_dump(devices, "zero_bench_hlo_")
     import jax as _jax
     from mxnet_tpu.parallel import data_parallel_mesh
     from mxnet_tpu.analysis.hloaudit import (spmd_collectives,

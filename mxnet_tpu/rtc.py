@@ -106,10 +106,9 @@ class PallasModule:
             if missing:
                 raise MXNetError(f"PallasModule: exports not found in "
                                  f"source: {missing}")
-        try:
-            self._interpret = jax.default_backend() == "cpu"
-        except Exception:
-            self._interpret = True
+        # the Pallas interpreter is for the CPU only; a backend that
+        # fails to answer is an error, never a reason to interpret
+        self._interpret = jax.default_backend() == "cpu"
 
     def get_kernel(self, name, num_inputs=1, signature=None):
         """Look up a kernel by name. `signature` accepted for CudaModule

@@ -76,14 +76,10 @@ class EnginePool:
 
     @staticmethod
     def _pick_device(i):
-        """Pin replica i to devices[i % n]; None (default device) when
-        the device query is unavailable (fakes, partial stubs)."""
-        try:
-            import jax
-            devs = jax.devices()
-            return devs[i % len(devs)] if devs else None
-        except Exception:
-            return None
+        """Pin replica i to devices[i % n]."""
+        import jax
+        devs = jax.devices()
+        return devs[i % len(devs)]
 
     # -- dispatch ------------------------------------------------------------
 

@@ -16,7 +16,8 @@ the numbers surface three ways:
   ``mxnet_devstats_roofline_frac`` gauges;
 - **per-step MFU/roofline** — trainers publish the step program's
   FLOPs/bytes per step; ``StepLogger`` calls :func:`step_sample` so each
-  JSONL row carries ``mfu`` (achieved FLOP/s over the backend peak) and
+  JSONL row carries ``mfu`` (achieved FLOP/s over the device's published
+  peak, ``PEAKS`` keyed by ``device_kind``; null on the CPU) and
   ``roofline_frac`` (over the bandwidth-aware roofline ceiling);
 - **HBM preflight** — when a device memory budget is known
   (``MXNET_DEVSTATS_HBM_BYTES``, or autodetected via PJRT
@@ -77,15 +78,14 @@ _AUTO_BUDGET = ["unset"]   # cached PJRT memory_stats autodetection
 _QUEUE = None
 _WORKER = None
 
-# Conservative per-backend peak table: (FLOP/s, bytes/s). tpu row is the
-# v5e bf16 MXU peak and HBM bandwidth (the numbers bench.py's roofline
-# lane uses); cpu is deliberately low so dev-box MFU reads as a sanity
-# signal, not a hardware claim. Override with MXNET_DEVSTATS_PEAK_TFLOPS
-# / MXNET_DEVSTATS_PEAK_GBPS.
-_PEAKS = {
-    "tpu": (197.0e12, 819.0e9),
-    "gpu": (312.0e12, 2039.0e9),
-    "cpu": (2.0e11, 5.0e10),
+# Published peaks of one chip, keyed by jax's `device_kind`: (bf16
+# FLOP/s, HBM bytes/s). Source: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 819 GB/s). The ONE peaks table of the repo — bench.py
+# and the planner read it. A TPU kind that is not here is an error, not a
+# default; the CPU has no row, so a CPU run reports no MFU. Override with
+# MXNET_DEVSTATS_PEAK_TFLOPS / MXNET_DEVSTATS_PEAK_GBPS.
+PEAKS = {
+    "TPU v5 lite": (197.0e12, 819.0e9),
 }
 
 
@@ -223,45 +223,53 @@ def note_compile(name, n=1):
 # ----------------------------------------------------------- peaks, MFU
 
 def peaks():
-    """(peak FLOP/s, peak bytes/s, source) for the active backend.
+    """(peak FLOP/s, peak bytes/s, source) of the device in use: the
+    ``PEAKS`` row of its ``device_kind`` (source ``table:<kind>``), with
     ``MXNET_DEVSTATS_PEAK_TFLOPS`` / ``MXNET_DEVSTATS_PEAK_GBPS``
-    override; otherwise the conservative per-backend table."""
+    overriding (source ``env``). The CPU has no row: (None, None,
+    "none") unless both are set. An accelerator whose kind is not in the
+    table raises — dividing by another chip's peak is a wrong number."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.device_kind in PEAKS:
+        pf, pb = PEAKS[dev.device_kind]
+        src = "table:%s" % dev.device_kind
+    elif dev.platform == "cpu":
+        pf, pb, src = None, None, "none"
+    else:
+        raise KeyError(
+            f"devstats: no peaks row for device_kind {dev.device_kind!r} "
+            f"(known: {sorted(PEAKS)}); add its published peaks to "
+            f"devstats.PEAKS")
     tf = os.environ.get("MXNET_DEVSTATS_PEAK_TFLOPS")
     gb = os.environ.get("MXNET_DEVSTATS_PEAK_GBPS")
-    plat = "cpu"
-    try:
-        import jax
-        plat = jax.default_backend()
-    except Exception:
-        pass
-    pf, pb = _PEAKS.get(plat, _PEAKS["cpu"])
-    src = "table:%s" % plat
-    try:
-        if tf:
-            pf = float(tf) * 1e12
-            src = "env"
-        if gb:
-            pb = float(gb) * 1e9
-            src = "env"
-    except ValueError:
-        pass
+    if tf:
+        pf, src = float(tf) * 1e12, "env"
+    if gb:
+        pb, src = float(gb) * 1e9, "env"
+    if pf is None or pb is None:
+        return None, None, "none"
     return pf, pb, src
 
 
 def mfu(flops_per_s):
-    """Model FLOPs utilization: achieved FLOP/s over the backend peak."""
+    """Model FLOPs utilization: achieved FLOP/s over the device's peak;
+    None where the device has no peaks row (the CPU)."""
     pf, _, _ = peaks()
-    return flops_per_s / pf if pf > 0 else 0.0
+    return flops_per_s / pf if pf else None
 
 
 def roofline_frac(flops_per_s, flops_per_step, bytes_per_step):
     """Attainment against the roofline ceiling for this program's
-    arithmetic intensity: min(peak_flops, intensity * peak_bw)."""
+    arithmetic intensity: min(peak_flops, intensity * peak_bw); None
+    where the device has no peaks row (the CPU)."""
     pf, pb, _ = peaks()
+    if not pf:
+        return None
     ceiling = pf
     if bytes_per_step > 0 and flops_per_step > 0:
         ceiling = min(pf, (flops_per_step / bytes_per_step) * pb)
-    return flops_per_s / ceiling if ceiling > 0 else 0.0
+    return flops_per_s / ceiling
 
 
 def set_step_costs(name, flops_per_step, bytes_per_step):
@@ -309,15 +317,18 @@ def step_sample(wall_s, steps):
     if f <= 0 or wall_s <= 0 or steps <= 0:
         return None
     fps = f * steps / wall_s
-    m = mfu(fps)
-    rf = roofline_frac(fps, f, b)
     _ensure_hook()
-    _gauge("mxnet_devstats_mfu",
-           "achieved FLOP/s over backend peak").set(m)
-    _gauge("mxnet_devstats_roofline_frac",
-           "achieved FLOP/s over roofline ceiling").set(rf)
     _gauge("mxnet_devstats_model_flops_per_s",
            "achieved model FLOP/s").set(fps)
+    m = mfu(fps)
+    if m is None:       # no peaks row (the CPU): a rate, never an MFU
+        return {"mfu": None, "roofline_frac": None,
+                "model_flops_per_s": fps}
+    rf = roofline_frac(fps, f, b)
+    _gauge("mxnet_devstats_mfu",
+           "achieved FLOP/s over device peak").set(m)
+    _gauge("mxnet_devstats_roofline_frac",
+           "achieved FLOP/s over roofline ceiling").set(rf)
     return {"mfu": round(m, 6), "roofline_frac": round(rf, 6),
             "model_flops_per_s": fps}
 
@@ -536,8 +547,8 @@ def counters():
         "programs": len(progs),
         "recompile_storms": storms,
         "hbm_budget_bytes": hbm_budget() or 0,
-        "peak_flops_per_s": pf,
-        "peak_bytes_per_s": pb,
+        "peak_flops_per_s": pf or 0,
+        "peak_bytes_per_s": pb or 0,
         "recompiles": compiles,
     }
     for stat in ("flops", "bytes_accessed", "peak_bytes", "argument_bytes",
@@ -668,7 +679,8 @@ def _selftest(max_overhead_pct=2.0):
     check('mxnet_devstats_flops{bucket="dp.step' in text,
           "fit_program_gauges_on_metrics")
     check("mxnet_recompiles_total" in text, "recompiles_counter_on_metrics")
-    check("mxnet_devstats_mfu" in text, "mfu_gauge_on_metrics")
+    check("mxnet_devstats_model_flops_per_s" in text,
+          "model_flops_gauge_on_metrics")
     costs = ds.step_costs()
     check(costs["flops"] > 0, "fit_step_costs_published")
 
@@ -821,20 +833,7 @@ def main(argv=None):
     if not ns.selftest:
         ap.print_help()
         return 0
-    # 2 virtual cpu devices before any jax import, matching the other
-    # telemetry selftests
-    os.environ.setdefault("JAX_NUM_CPU_DEVICES", "2")
-    if "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + " --xla_force_host_platform_"
-                                     "device_count=2")
-    import jax
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        pass
-    jax.config.update("jax_platforms", "cpu")
+    config.pin_cpu(2)     # like the other telemetry selftests
     from mxnet_tpu.telemetry import devstats as canonical
     return canonical._selftest(max_overhead_pct=ns.max_overhead_pct)
 

@@ -19,6 +19,7 @@ Covers the five amp contracts on the CPU mesh:
 """
 import json
 import logging
+import os
 import subprocess
 import sys
 
@@ -184,7 +185,7 @@ def test_hlo_bf16_allreduce_wire_dtype():
     proc = subprocess.run(
         [sys.executable, "-m", "mxnet_tpu.amp", "--hlo-check",
          "--dtype", "bfloat16"],
-        capture_output=True, text=True, timeout=300, cwd="/root/repo")
+        capture_output=True, text=True, timeout=300, cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
     assert rec["metric"] == "amp_hlo_check" and rec["ok"]

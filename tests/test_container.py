@@ -1,4 +1,4 @@
-"""Reference .params container interop (VERDICT-r4 #3).
+"""Reference .params container interop.
 
 Byte-level pinning of the reference NDArray container (magic 0xF993fac9,
 src/ndarray/ndarray.cc:1582-1808) plus round-trips: files this framework
@@ -153,7 +153,7 @@ def test_legacy_v1_and_prev1_load(tmp_path):
 
 
 def test_checkpoint_roundtrip_through_module(tmp_path):
-    """End-to-end VERDICT-r4 #3 criterion: a symbol-JSON + .params pair
+    """End-to-end criterion: a symbol-JSON + .params pair
     written by this framework loads back and serves inference — the
     .params being the reference binary container."""
     data = mx.sym.Variable("data")

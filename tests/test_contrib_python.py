@@ -257,6 +257,28 @@ def test_autograd_function_multi_output():
                                                np.full(3, 3.0)]), rtol=1e-6)
 
 
+def test_autograd_function_save_for_backward():
+    """The reference's save_for_backward/saved_tensors pair (the chip
+    lane's test_autograd_function_on_chip uses it)."""
+    class Sigmoid(mx.autograd.Function):
+        def forward(self, x):
+            y = 1.0 / (1.0 + mx.nd.exp(-x))
+            self.save_for_backward(y)
+            return y
+
+        def backward(self, dy):
+            (y,) = self.saved_tensors
+            return dy * y * (1 - y)
+    xv = np.array([0.5, -1.0, 2.0], np.float32)
+    x = mx.nd.array(xv)
+    x.attach_grad()
+    with mx.autograd.record():
+        y = Sigmoid()(x)
+    y.backward(mx.nd.ones_like(y))
+    sig = 1 / (1 + np.exp(-xv))
+    np.testing.assert_allclose(x.grad.asnumpy(), sig * (1 - sig), atol=1e-5)
+
+
 def test_torch_embedding_int_inputs():
     torch = pytest.importorskip("torch")
     from mxnet_tpu.contrib import torch_bridge

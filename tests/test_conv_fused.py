@@ -1,9 +1,8 @@
 """conv1x1 megakernel correctness (Pallas interpreter, CPU lane).
 
-The performance verdict on these kernels is docs/megakernel_r04.md: on
-the real v5e they tie XLA's fused chain at best (XLA already output-
-fuses BN stats into conv fusions and runs flat chains at the HBM
-roofline). The kernels remain supported and tested.
+Whether these kernels beat XLA's own fused chain on the chip is not
+measured on this runtime (ROADMAP D5). The kernels remain supported and
+tested.
 """
 import numpy as np
 import jax.numpy as jnp

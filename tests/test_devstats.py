@@ -117,6 +117,35 @@ def test_mfu_and_roofline_arithmetic(monkeypatch):
     assert summ["devstats_peak_source"] == "env"
 
 
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", (197.0e12, 819.0e9, "table:TPU v5 lite")),
+    ("cpu", "cpu", (None, None, "none")),
+    ("tpu", "TPU v9 imaginary", KeyError),
+])
+def test_peaks_keyed_by_device_kind(monkeypatch, platform, kind, want):
+    """One table keyed by device_kind: the v5e row for a v5e, no row
+    (and so no MFU) for the CPU, an error for a TPU it does not know."""
+    import jax
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(platform, kind)])
+    if want is KeyError:
+        with pytest.raises(KeyError, match="TPU v9 imaginary"):
+            devstats.peaks()
+        return
+    assert devstats.peaks() == want
+    if want[0] is None:
+        assert devstats.mfu(1.0e12) is None
+        assert devstats.roofline_frac(1.0e12, 1.0, 1.0) is None
+        devstats.set_step_costs("test.cpu", 5.0e9, 1.0e9)
+        s = devstats.step_sample(wall_s=0.01, steps=2)
+        assert s["mfu"] is None and s["roofline_frac"] is None
+        assert s["model_flops_per_s"] == pytest.approx(1.0e12)
+
+
 def test_step_sample_off_and_without_costs(monkeypatch):
     assert devstats.step_sample(0.01, 1) is None      # no program yet
     devstats.set_step_costs("p", 1e9, 1e9)

@@ -191,9 +191,9 @@ def test_layout_ownership_covers_table_and_mlp():
 
 # -- the fused step: exchange parity + checkpoint round-trip -----------------
 
-def _trainer(exchange, vocab=64, batch=16, slots=4, cap=None):
+def _trainer(exchange, vocab=64, batch=16, slots=4, cap=None, n_dev=8):
     return EmbeddingTrainer(
-        _mesh(), vocab=vocab, embed_dim=8, n_slots=slots, dense_dim=4,
+        _mesh(n_dev), vocab=vocab, embed_dim=8, n_slots=slots, dense_dim=4,
         mlp_hidden=(16,), optimizer="sgd", learning_rate=0.2,
         momentum=0.9, wd=0.01, rescale_grad=1.0 / batch,
         exchange=exchange, compress="none", unique_cap=cap,
@@ -218,6 +218,25 @@ def test_sparse_dense_bit_identity_all_rows_touched():
     for name in states["sparse"]:
         assert np.array_equal(states["sparse"][name],
                               states["dense"][name]), name
+
+
+@pytest.mark.parametrize("mode", ["sparse", "dense"])
+def test_step_independent_of_device_count(mode):
+    """The global-batch gradient is one sum however many devices share
+    it: two steps on 8 devices match the same steps on 1 device. (A
+    gradient of a replicated input taken inside shard_map arrives
+    already psum'd; summing it again counted it n_dev times.)"""
+    ids, dense, y = _permutation_data(64, 16, 4, 4, seed=5)
+    states = {}
+    for n_dev in (1, 8):
+        tr = _trainer(mode, n_dev=n_dev)
+        st = tr.init_state(16, seed=1)
+        for _ in range(2):
+            st, _, _ = tr.step(st, tr.shard_inputs([ids, dense, y]))
+        states[n_dev] = tr.export_training_state(st)[0]
+    for name, ref in states[1].items():
+        np.testing.assert_allclose(states[8][name], ref, rtol=1e-5,
+                                   atol=1e-6, err_msg=name)
 
 
 def test_export_import_roundtrip_across_cap_change():

@@ -1,4 +1,4 @@
-"""Model-store sha1 plumbing + pretrained-zoo interop (VERDICT-r4 #3).
+"""Model-store sha1 plumbing + pretrained-zoo interop.
 
 The end-to-end test writes a resnet18_v1 checkpoint in the REFERENCE
 binary container format under the store's name-{shorthash} naming,

@@ -76,6 +76,25 @@ def test_module_predict_and_input_grads():
     assert float(dgrad.abs().sum().asscalar()) > 0
 
 
+def test_module_initializes_gluon_symbol_params():
+    """A gluon block traced into a Symbol leaves the plain initializer
+    names of its Parameters (init="ones") on the variables; Module's
+    initializer must honour them like `Initializer.dumps()` JSON."""
+    net = mx.gluon.nn.HybridSequential()
+    net.add(mx.gluon.nn.Dense(4), mx.gluon.nn.BatchNorm())
+    sym = mx.sym.SoftmaxOutput(net(mx.sym.Variable("data")), name="softmax")
+    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod.bind(data_shapes=[("data", (2, 3))],
+             label_shapes=[("softmax_label", (2,))])
+    mod.init_params(mx.init.Xavier())
+    args, aux = mod.get_params()
+    by_suffix = {n.rsplit("_", 1)[-1]: v.asnumpy()
+                 for n, v in list(args.items()) + list(aux.items())}
+    assert (by_suffix["gamma"] == 1).all() and (by_suffix["beta"] == 0).all()
+    assert (by_suffix["var"] == 1).all() and (by_suffix["mean"] == 0).all()
+    assert np.abs(by_suffix["weight"]).sum() > 0
+
+
 def test_module_get_set_params_roundtrip():
     mod = mx.mod.Module(_mlp_sym(), context=mx.cpu())
     mod.bind(data_shapes=[("data", (4, 8))],

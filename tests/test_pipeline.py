@@ -266,16 +266,22 @@ def test_overlap_frac_bounds():
 
 # -- persistent compile cache ------------------------------------------------
 
-def test_enable_compile_cache_writes_entries(tmp_path):
+def _detach_compile_cache():
+    """Leave the process as the test found it: later tests must not write
+    cache entries into a tmp_path that pytest is about to delete."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
+
+
+def test_enable_compile_cache_writes_entries(tmp_path, monkeypatch):
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.config import disable_compile_cache, enable_compile_cache
+    from mxnet_tpu.config import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache_dir = str(tmp_path / "xla_cache")
-    # detach afterwards: an armed persistent cache is process-global and
-    # has been observed to segfault later unrelated cpu compiles (the
-    # shard_map trainer steps of test_zero.py, and bench.py's checkpoint
-    # lane before it detached too — see config.disable_compile_cache)
-    assert enable_compile_cache(cache_dir)
+    assert enable_compile_cache(cache_dir) == cache_dir
     try:
         @jax.jit
         def fn(x):
@@ -288,4 +294,18 @@ def test_enable_compile_cache_writes_entries(tmp_path):
         np.asarray(fn(np.ones((32, 32), np.float32)))
         assert len(os.listdir(cache_dir)) >= len(entries)
     finally:
-        assert disable_compile_cache()
+        _detach_compile_cache()
+
+
+def test_compile_cache_placed_from_outside_is_not_moved(tmp_path,
+                                                        monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX's own handling of it
+    is the only thing that places the cache: enable_compile_cache sets
+    no directory in code and reports the one in use."""
+    import jax
+    from mxnet_tpu.config import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache(str(tmp_path / "ours")) == before
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "ours").exists()

@@ -4,6 +4,8 @@ deterministic auto-selection, HBM-prefilter pruning BEFORE any
 compilation (via the MXNET_DEVSTATS_HBM_BYTES env path), fp32 bitwise
 parity of planner-built degenerate trainers against the directly
 constructed legacy trainers, and cross-plan checkpoint resume."""
+import os
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,20 @@ from mxnet_tpu.parallel.planner import (AUTO_KNOB_VARS, ModelSpec, Plan,
                                         plan_auto, _small_model)
 
 N_DEV = 8
+
+
+@pytest.fixture(autouse=True)
+def _restore_knob_env():
+    """make_trainer writes the chosen plan's knobs into os.environ
+    ("auto unless set"). Left there, MXNET_ZERO_STAGE turns the next
+    test file's plain DataParallelTrainer into a ZeroTrainer."""
+    saved = {k: os.environ.get(k) for k in AUTO_KNOB_VARS}
+    yield
+    for k, v in saved.items():
+        if v is None:
+            os.environ.pop(k, None)
+        else:
+            os.environ[k] = v
 
 
 def _data(batch, dim, nclass, seed=0):
@@ -124,6 +140,33 @@ def test_plan_auto_deterministic():
     t2 = [(e["plan"].name, round(e["cost_s"], 15)) for e in r2.entries
           if "cost_s" in e]
     assert t1 == t2 and len(t1) >= 3
+
+
+@pytest.mark.parametrize("tflops,gbps", [(None, None), ("197", "819")])
+def test_score_prices_compute_only_with_named_peaks(monkeypatch, tflops,
+                                                    gbps):
+    """The CPU has no peaks row: a candidate is priced by its wire alone
+    and says so (compute_s None). Naming a chip's peaks through the
+    devstats overrides adds the roofline term; no chip is assumed."""
+    for var, val in (("MXNET_DEVSTATS_PEAK_TFLOPS", tflops),
+                     ("MXNET_DEVSTATS_PEAK_GBPS", gbps)):
+        if val is None:
+            monkeypatch.delenv(var, raising=False)
+        else:
+            monkeypatch.setenv(var, val)
+    model, _, _, _ = _small_model()
+    rec = planner.score_plan(model, parse_plan("dp", N_DEV, model),
+                             wire_bw=25e9)
+    wire_s = rec["wire_bytes_hlo"] / 25e9
+    assert wire_s > 0
+    if tflops is None:
+        assert rec["compute_s"] is None
+        assert rec["cost_s"] == pytest.approx(wire_s, rel=1e-6)
+    else:
+        assert rec["compute_s"] == pytest.approx(
+            max(rec["flops"] / 197e12, rec["bytes"] / 819e9))
+        assert rec["cost_s"] == pytest.approx(rec["compute_s"] + wire_s,
+                                              rel=1e-6)
 
 
 # -- degenerate parity: planner-built vs direct legacy trainers -------------

@@ -1,4 +1,4 @@
-"""Per-stage compiled model parallelism (VERDICT-r4 #4).
+"""Per-stage compiled model parallelism.
 
 The group2ctx path must (a) compile once per stage — not retrace per
 step, (b) place each stage's compute on its group's device, (c) match

@@ -86,7 +86,7 @@ def test_flash_kernel_grads_under_shard_map_interpret():
     def shard_body(q, k, v):
         return flash_attention(q, k, v, causal=True, force="interpret")
 
-    from mxnet_tpu.parallel._compat import shard_map
+    from mxnet_tpu.parallel.mesh import shard_map
     fn = shard_map(shard_body, mesh=mesh, in_specs=(spec,) * 3,
                        out_specs=spec)
 

@@ -1,0 +1,123 @@
+"""Compile the Pallas kernels of the main paths for a DESCRIBED TPU.
+
+No chip is attached here: the TPU compiler compiles for a topology that
+is described ("v5e:2x2"), which refuses what interpret-mode tests cannot
+see — a slice that misses the tiling, too much VMEM, a kernel that cannot
+be partitioned. Real widths, a second or two each. A compile that passes
+is not a chip run; chip_smoke.py is.
+
+Only one process may load the TPU library, so the topology is described
+inside a module-scoped fixture of THIS file (never at import, never in a
+skipif/parametrize argument, never in conftest.py, not autouse), every
+test of it lives here, and nothing compiles in a child process.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *specs):
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+@pytest.mark.parametrize("b,h,h_kv,s", [
+    (8, 8, 8, 4096),        # MHA, the bench shape
+    (8, 8, 2, 4096),        # GQA: 2 kv heads shared in-kernel
+    (1, 2, 2, 16384),       # past 8k the kernels raise their VMEM limit
+])
+def test_flash_attention_forward_and_gradient(one_chip, b, h, h_kv, s):
+    from mxnet_tpu.ops.attention import flash_attention
+    q = jax.ShapeDtypeStruct((b, h, s, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h_kv, s, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def fwd(q, k, v):       # the auto pick, told its target is a TPU
+        return flash_attention(q, k, v, causal=True, platform="tpu")
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    assert _compiled_text(fwd, q, kv, kv).count(KERNEL) == 1
+    # dq, and dk+dv, each from their own recompute kernel beside the forward
+    assert _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          q, kv, kv).count(KERNEL) == 3
+
+
+@pytest.mark.parametrize("h_kv", [8, 2])
+def test_decode_attention(one_chip, h_kv):
+    from mxnet_tpu.ops.attention import decode_attention
+    b, h, s, d = 8, 8, 2048, 128
+    q = jax.ShapeDtypeStruct((b, h, d), jnp.float32, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h_kv, s, d), jnp.float32,
+                              sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+
+    def fn(q, k, v, lengths):
+        return decode_attention(q, k, v, lengths, platform="tpu")
+
+    assert _compiled_text(fn, q, kv, kv, lengths).count(KERNEL) == 1
+
+
+@pytest.mark.parametrize("m", [1, 8])
+@pytest.mark.parametrize("wdtype", ["int8", "float8_e4m3fn"])
+def test_quantized_matmul(one_chip, wdtype, m):
+    from mxnet_tpu.ops.quantization import quantized_matmul
+    k, n = 4096, 4096
+    x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((k, n), jnp.dtype(wdtype), sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+
+    def fn(x, w, scale):
+        return quantized_matmul(x, w, scale, platform="tpu")
+
+    text = _compiled_text(fn, x, w, scale)
+    assert text.count(KERNEL) == 1
+    # the narrow weight reaches the kernel as stored, never widened in HBM
+    assert ("s8[4096,4096]" if wdtype == "int8" else "f8e4m3fn[4096,4096]") \
+        in text
+
+
+def test_ring_attention_over_four_chips(topo):
+    """The sequence-parallel ring at 16k tokens over a 4-device ("sp",)
+    mesh: the flash kernels inside shard_map plus the K/V rotation."""
+    from mxnet_tpu.parallel import sp
+    mesh = Mesh(topo.devices[:4], ("sp",))
+    x = jax.ShapeDtypeStruct(
+        (1, 4, 16384, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, None, "sp", None)))
+
+    def loss(q, k, v):
+        return sp.ring_attention(q, k, v, mesh, causal=True) \
+            .astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                          x, x, x)
+    assert KERNEL in text and "collective-permute" in text

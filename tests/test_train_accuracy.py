@@ -60,6 +60,11 @@ def _lenet_symbol():
 
 
 def _fit_and_score(sym, train, val, batch=64, epochs=20, lr=0.1):
+    # seeded: unseeded, the shuffle order and Xavier draws inherit whatever
+    # state the tests before this one in the same worker left, and an
+    # unlucky draw lands a hair under the bar (0.9635 seen)
+    np.random.seed(0)
+    mx.random.seed(0)
     (xt, yt), (xv, yv) = train, val
     it = mx.io.NDArrayIter(xt, yt, batch_size=batch, shuffle=True,
                            label_name="softmax_label")
@@ -96,6 +101,7 @@ def test_mlp_gluon_trainer_reaches_97():
         net.add(nn.Dense(128, activation="relu"),
                 nn.Dense(64, activation="relu"),
                 nn.Dense(10))
+    mx.random.seed(0)
     net.initialize(mx.init.Xavier())
     net.hybridize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",

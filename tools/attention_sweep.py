@@ -1,4 +1,4 @@
-"""Flash-attention performance curve on the real chip (VERDICT-r4 #5).
+"""Flash-attention performance curve on the real chip.
 
 Sweeps seq x block-size x causal (+ GQA points) over the Pallas
 fwd+bwd kernels, reporting tokens/sec and model-flop MFU per point.
@@ -7,8 +7,7 @@ MFU convention matches bench.py: 6 S^2 D matmuls (fwd 2 + bwd 4) at
 passes the flash kernels actually execute are not credited.
 
 Run (on TPU): python tools/attention_sweep.py [--quick]
-Writes a markdown table to stdout; docs/ROUND5.md records the measured
-curve.
+Writes a markdown table to stdout.
 """
 from __future__ import annotations
 

@@ -79,6 +79,8 @@ case "$stage" in
   full)
     python -m pytest tests/ -q ;;
   tpu)
+    # needs the chip: each is its own process, one after the other
+    python chip_smoke.py
     python -m pytest tests_tpu/ -q ;;
   bench)
     python bench.py ;;

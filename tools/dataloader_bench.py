@@ -1,4 +1,4 @@
-"""DataLoader worker-type crossover bench (VERDICT-r4 #8).
+"""DataLoader worker-type crossover bench.
 
 Measures inline / thread / process workers on two dataset profiles:
 - "gil": a pure-python per-sample transform (holds the GIL) — the
@@ -8,7 +8,7 @@ Measures inline / thread / process workers on two dataset profiles:
 
 Guidance (see docstring in gluon/data/dataloader.py): threads for
 GIL-releasing pipelines; processes for GIL-bound python transforms,
-scaling roughly with cores. NOTE a 1-core host (like the r5 bench VM)
+scaling roughly with cores. NOTE a 1-core host
 cannot show the process win — run on a multi-core host for the
 crossover; the numbers below still show the bookkeeping overhead of
 each path.

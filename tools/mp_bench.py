@@ -1,5 +1,5 @@
 """Model-parallel path microbench: per-stage jitted segments vs the
-round-4 eager per-op walk (VERDICT-r4 #4 'done' evidence).
+earlier eager per-op walk.
 
 Both paths execute the SAME 4-stage group2ctx MLP training step (fwd +
 bwd + BN aux) over 4 CPU devices. The eager baseline reconstructs the

@@ -5,8 +5,7 @@ step (the exact bench.py configuration), aggregates device time / model
 FLOPs / bytes by HLO category, and prints a roofline verdict: what fraction
 of the step runs at the HBM bandwidth limit vs the MXU FLOPs limit.
 
-This is the evidence behind docs/perf_analysis_r03.md — rerun it whenever
-the step changes:
+Rerun it whenever the step changes:
 
     python tools/tpu_roofline.py [--batch 128] [--out trace_dir]
 
