@@ -175,7 +175,8 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
             if n in pmap:
                 pmap[n].set_data(nd_array(a))
 
-    from ..pipeline import feed_or_inline, close_feed
+    from ..pipeline import feed_or_inline, close_feed, staged_put
+    from ..telemetry import tracing as _tracing
 
     def _blocks(stream):
         while True:
@@ -188,9 +189,11 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
         # stack + device commit on the feeder thread: block N+1 is staged
         # while block N's fused scan executes (np.stack copies, so loader
         # buffer reuse is safe)
-        xs = np.stack([_np_of(b[0]) for b in block])
-        ys = np.stack([_np_of(b[1]) for b in block])
-        return trainer.shard_inputs([xs, ys], stacked=True), len(block)
+        with _tracing.span("feed.stack"):
+            xs = np.stack([_np_of(b[0]) for b in block])
+            ys = np.stack([_np_of(b[1]) for b in block])
+        return staged_put(trainer.shard_inputs, [xs, ys],
+                          stacked=True), len(block)
 
     # default K comes from MXNET_FUSED_K (the planner auto-tunes it per
     # chosen plan, "auto unless set"); 0/unset keeps the historical 8
@@ -200,7 +203,6 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
     k = int(steps_per_dispatch)
     epoch_losses = []
     from ..telemetry import maybe_step_logger
-    from ..telemetry import tracing as _tracing
     slog = maybe_step_logger("gluon_fused_fit", meta={
         "optimizer": optimizer, "steps_per_dispatch": k,
         "batch_size": batch, "num_epoch": num_epoch,
@@ -219,31 +221,38 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
             feed = feed_or_inline(_blocks(stream), _stage_block,
                                   name="gluon_fused_fit")
             try:
-                for inputs, n_blk in feed:
-                    # "compute" span: fused dispatch + the loss sync
+                for seq, (inputs, n_blk) in enumerate(feed):
+                    # "compute" span: fused dispatch + the loss sync,
+                    # each half under a span of its own (no phase: the
+                    # parent's time is the phase's)
                     with _tracing.span("step.fused_dispatch",
-                                       phase="compute", k=n_blk):
-                        params, states, aux, losses, _ = trainer.step_k(
-                            params, states, aux, inputs)
-                        blk_loss = float(np.sum(np.asarray(losses)))
+                                       phase="compute", k=n_blk, seq=seq):
+                        with _tracing.span("step.enqueue"):
+                            params, states, aux, losses, _ = \
+                                trainer.step_k(params, states, aux, inputs)
+                        with _tracing.span("step.metric_update"):
+                            blk_loss = float(np.sum(np.asarray(losses)))
                     total += blk_loss
                     count += n_blk * batch
                     # the np.asarray above already synced on the block's
                     # losses, so this wall time covers real device work
-                    slog.step(samples=n_blk * batch, steps=n_blk,
-                              loss=blk_loss / max(n_blk * batch, 1),
-                              extra={"epoch": epoch})
+                    with _tracing.span("step.log", seq=seq):
+                        slog.step(samples=n_blk * batch, steps=n_blk,
+                                  loss=blk_loss / max(n_blk * batch, 1),
+                                  extra={"epoch": epoch})
                     nbatch += n_blk
                     gstep += n_blk
                     if ckpt_mgr is not None:
                         if checkpoint_period and \
                                 gstep - last_ckpt >= int(checkpoint_period):
-                            ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
-                                          step=gstep)
+                            with _tracing.span("step.checkpoint", seq=seq):
+                                ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
+                                              step=gstep)
                             last_ckpt = gstep
                         if ckpt_mgr.preempted:
-                            ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
-                                          step=gstep, blocking=True)
+                            with _tracing.span("step.checkpoint", seq=seq):
+                                ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
+                                              step=gstep, blocking=True)
                             raise SystemExit(143)
             finally:
                 close_feed(feed)

@@ -421,13 +421,19 @@ class Module(BaseModule):
                         "step %s (epoch %d, batch %d)", ckpt_state.step,
                         begin_epoch, int(ckpt_state.meta.get("batch", 0)))
 
+        # the four steps of set-up each under a span of their own
+        # (fit.bind, fit.init_params, fit.trainer_init, fit.init_state)
+        from ..telemetry import tracing as _tracing
         # normal bind + init so the parameter draw is identical to K=1
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
+        with _tracing.span("fit.bind"):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
+        with _tracing.span("fit.init_params"):
+            self.init_params(initializer=initializer, arg_params=arg_params,
+                             aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
 
         if validation_metric is None:
             validation_metric = eval_metric
@@ -444,23 +450,25 @@ class Module(BaseModule):
         # for fp16 the DynamicLossScaler state rides the scan carry
         from .. import amp as _amp
         fit_dtype = _amp.get_dtype() if _amp.is_enabled() else "float32"
-        trainer = DataParallelTrainer(
-            self._symbol, mesh_for_contexts(self._context),
-            data_names=tuple(self._data_names),
-            label_names=tuple(self._label_names), optimizer=optimizer,
-            learning_rate=lr,
-            momentum=float(opt_params.pop("momentum", 0.0)),
-            wd=float(opt_params.pop("wd", 0.0)),
-            rescale_grad=float(opt_params.pop("rescale_grad",
-                                              1.0 / batch_size)),
-            clip_gradient=opt_params.pop("clip_gradient", None),
-            dtype=fit_dtype,
-            **opt_params)
+        with _tracing.span("fit.trainer_init"):
+            trainer = DataParallelTrainer(
+                self._symbol, mesh_for_contexts(self._context),
+                data_names=tuple(self._data_names),
+                label_names=tuple(self._label_names), optimizer=optimizer,
+                learning_rate=lr,
+                momentum=float(opt_params.pop("momentum", 0.0)),
+                wd=float(opt_params.pop("wd", 0.0)),
+                rescale_grad=float(opt_params.pop("rescale_grad",
+                                                  1.0 / batch_size)),
+                clip_gradient=opt_params.pop("clip_gradient", None),
+                dtype=fit_dtype,
+                **opt_params)
         shape_kwargs = {d.name: d.shape for d in
                         self._data_shapes + (self._label_shapes or [])}
-        params, states, aux = trainer.init_state(
-            shape_kwargs, arg_params=self._arg_params,
-            aux_params=self._aux_params)
+        with _tracing.span("fit.init_state"):
+            params, states, aux = trainer.init_state(
+                shape_kwargs, arg_params=self._arg_params,
+                aux_params=self._aux_params)
 
         gstep = 0
         ckpt_skip = 0
@@ -498,9 +506,8 @@ class Module(BaseModule):
             ckpt_mgr.install_sigterm_hook()
 
         from ..base import to_numpy as _np_of
-        from ..pipeline import feed_or_inline, close_feed
+        from ..pipeline import feed_or_inline, close_feed, staged_put
         from ..telemetry import maybe_step_logger
-        from ..telemetry import tracing as _tracing
         slog = maybe_step_logger("module_fit_fused", meta={
             "optimizer": optimizer, "steps_per_dispatch": int(k),
             "batch_size": int(batch_size), "begin_epoch": begin_epoch,
@@ -521,19 +528,22 @@ class Module(BaseModule):
             # N+1 is staged while block N's fused scan executes. np.stack
             # copies, so iterator buffer reuse is safe; a short tail block
             # compiles its own (cached) k'-step scan
-            stacked = []
-            for name in trainer.input_names:
-                if name in data_idx:
-                    col = [_np_of(b.data[data_idx[name]])
-                           for b in block]
-                else:
-                    col = [_np_of(b.label[label_idx[name]])
-                           for b in block]
-                stacked.append(np.stack(col))
-            inputs = trainer.shard_inputs(stacked, stacked=True)
-            labels = {
-                name: np.concatenate([_np_of(b.label[i]) for b in block])
-                for name, i in label_idx.items()}
+            with _tracing.span("feed.stack"):
+                stacked = []
+                for name in trainer.input_names:
+                    if name in data_idx:
+                        col = [_np_of(b.data[data_idx[name]])
+                               for b in block]
+                    else:
+                        col = [_np_of(b.label[label_idx[name]])
+                               for b in block]
+                    stacked.append(np.stack(col))
+                labels = {
+                    name: np.concatenate([_np_of(b.label[i])
+                                          for b in block])
+                    for name, i in label_idx.items()}
+            inputs = staged_put(trainer.shard_inputs, stacked,
+                                stacked=True)
             return inputs, labels, len(block)
 
         def _ckpt_capture(next_epoch, next_batch):
@@ -569,51 +579,69 @@ class Module(BaseModule):
                 feed = feed_or_inline(_blocks(src), _stage_block,
                                       name="module_fit_fused")
                 try:
-                    for inputs, label_np, n_blk in feed:
+                    for seq, (inputs, label_np, n_blk) in enumerate(feed):
                         # "compute" span: the fused dispatch plus the
                         # metric update that syncs on its outputs — i.e.
-                        # the device-bound slice of the loop body
+                        # the device-bound slice of the loop body. Its
+                        # two halves have spans of their own (no phase:
+                        # the parent's time is the phase's)
                         with _tracing.span("step.fused_dispatch",
-                                           phase="compute", k=n_blk):
-                            params, states, aux, losses, outputs = \
-                                trainer.step_k(params, states, aux,
-                                               inputs, outputs_mode="all")
+                                           phase="compute", k=n_blk,
+                                           seq=seq):
+                            # returns once the scan is enqueued, before
+                            # the device ends
+                            with _tracing.span("step.enqueue"):
+                                params, states, aux, losses, outputs = \
+                                    trainer.step_k(params, states, aux,
+                                                   inputs,
+                                                   outputs_mode="all")
                             # metric over ALL K batches at once: flatten
                             # the scan axis into the batch axis (same
                             # samples K=1 would feed one by one, one
                             # update call instead of K)
-                            pred_dict = {
-                                name: NDArray(
-                                    o.reshape((-1,) + o.shape[2:]))
-                                for name, o in zip(self._output_names,
-                                                   outputs)}
-                            label_dict = {name: NDArray(v)
-                                          for name, v in label_np.items()}
-                            eval_metric.update_dict(label_dict, pred_dict)
+                            with _tracing.span("step.metric_update"):
+                                pred_dict = {
+                                    name: NDArray(
+                                        o.reshape((-1,) + o.shape[2:]))
+                                    for name, o in zip(self._output_names,
+                                                       outputs)}
+                                label_dict = {
+                                    name: NDArray(v)
+                                    for name, v in label_np.items()}
+                                eval_metric.update_dict(label_dict,
+                                                        pred_dict)
                         # one record per fused dispatch (K steps); the
                         # metric update above already synced on outputs,
                         # so the wall time covers real device work
-                        slog.step(samples=n_blk * batch_size,
-                                  steps=n_blk, extra={"epoch": epoch})
+                        with _tracing.span("step.log", seq=seq):
+                            slog.step(samples=n_blk * batch_size,
+                                      steps=n_blk, extra={"epoch": epoch})
                         nbatch += n_blk
                         gstep += n_blk
                         if batch_callbacks:
-                            cb_param = BatchEndParam(epoch=epoch,
-                                                     nbatch=nbatch - 1,
-                                                     eval_metric=eval_metric,
-                                                     locals=locals())
-                            for callback in batch_callbacks:
-                                callback(cb_param)
+                            with _tracing.span("step.callbacks", seq=seq):
+                                cb_param = BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch - 1,
+                                    eval_metric=eval_metric,
+                                    locals=locals())
+                                for callback in batch_callbacks:
+                                    callback(cb_param)
                         if ckpt_mgr is not None:
                             if checkpoint_period and \
                                     gstep - last_ckpt >= \
                                     int(checkpoint_period):
-                                ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
-                                              step=gstep)
+                                with _tracing.span("step.checkpoint",
+                                                   seq=seq):
+                                    ckpt_mgr.save(
+                                        _ckpt_capture(epoch, nbatch),
+                                        step=gstep)
                                 last_ckpt = gstep
                             if ckpt_mgr.preempted:
-                                ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
-                                              step=gstep, blocking=True)
+                                with _tracing.span("step.checkpoint",
+                                                   seq=seq):
+                                    ckpt_mgr.save(
+                                        _ckpt_capture(epoch, nbatch),
+                                        step=gstep, blocking=True)
                                 raise SystemExit(143)
                 finally:
                     close_feed(feed)

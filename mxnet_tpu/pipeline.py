@@ -22,9 +22,16 @@ Mechanics:
     reason BaseModule.fit's fetch-after-update discipline exists;
   - feeder exceptions are re-raised in the consumer thread at the next
     __next__; close() drains and joins the thread (no leaked threads);
-  - counters (`feed_wait_us`, `feed_stage_us`, `overlap_frac`, ...) are
-    exported through profiler.register_counter_export under the
-    "device_feed" key, so profiler.dump() traces carry them.
+  - counters (`feed_wait_us`, `feed_stage_us`, `feed_staged_bytes`,
+    `overlap_frac`, ...) are exported through
+    profiler.register_counter_export under the "device_feed" key, so
+    profiler.dump() traces carry them;
+  - spans (telemetry/tracing.py; `mx.<name>` in a profiler trace), every
+    one carrying `seq`, the block's number since the feed opened: on the
+    feeder thread `feed.stage` over `feed.pull` (the source), whatever
+    the stage function opens (`feed.stack`, `feed.put`), then
+    `feed.enqueue` (blocked on a full ring); on the consumer's thread
+    `feed.wait`. Each counter is fed from its span's own clock reads.
 
 The loops threaded through it: Module/BaseModule.fit, the fused K-step
 drivers (Module._fit_fused, gluon.trainer.fused_fit), BaseModule.score /
@@ -34,6 +41,7 @@ the two against each other).
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -42,14 +50,14 @@ import numpy as np
 
 from .telemetry import tracing as _tracing
 
-__all__ = ["DeviceFeed", "module_stage", "enabled", "default_depth",
-           "stats", "reset_stats"]
+__all__ = ["DeviceFeed", "module_stage", "staged_put", "enabled",
+           "default_depth", "stats", "reset_stats"]
 
 # -- aggregate counters (exported via profiler.register_counter_export) -----
 
 _STATS_LOCK = threading.Lock()
 _TOTALS = {"feed_wait_us": 0, "feed_stage_us": 0, "feed_batches": 0,
-           "feeds_opened": 0, "feeds_closed": 0}
+           "feed_staged_bytes": 0, "feeds_opened": 0, "feeds_closed": 0}
 
 
 def _bump(key, val):
@@ -160,23 +168,26 @@ class DeviceFeed:
         # feed_stage_us is the full feeder-side cost per item — source
         # pull plus staging — i.e. exactly the host work the feed hides.
         try:
-            while not self._stop.is_set():
-                t0 = time.perf_counter()
+            for seq in itertools.count():
+                if self._stop.is_set():
+                    break
                 # feeder-side work records under "feed_stage", NOT
                 # "feed": StepLogger's feed_us/overlap fraction counts
                 # only consumer-blocked time (the "feed" phase below)
-                with _tracing.span("feed.stage", phase="feed_stage",
-                                   feed=self.name):
+                with _tracing.stopwatch("feed.stage", phase="feed_stage",
+                                        feed=self.name, seq=seq) as sw:
                     try:
-                        item = next(self._source)
+                        with _tracing.span("feed.pull"):
+                            item = next(self._source)
                     except StopIteration:
                         break
                     staged = self._stage(item)
-                dt_us = int((time.perf_counter() - t0) * 1e6)
+                dt_us = int(sw.dur_us)
                 self.stage_us += dt_us
                 _bump("feed_stage_us", dt_us)
-                if not self._put((_ITEM, staged)):
-                    return
+                with _tracing.span("feed.enqueue", seq=seq):
+                    if not self._put((_ITEM, staged)):
+                        return
             self._put((_END, None))
         except BaseException as exc:   # noqa: BLE001 — re-raised consumer-side
             self._put((_ERR, exc))
@@ -188,10 +199,10 @@ class DeviceFeed:
     def __next__(self):
         if self._done:
             raise StopIteration
-        t0 = time.perf_counter()
-        with _tracing.span("feed.wait", phase="feed", feed=self.name):
+        with _tracing.stopwatch("feed.wait", phase="feed", feed=self.name,
+                                seq=self.batches) as sw:
             kind, val = self._q.get()
-        dt_us = int((time.perf_counter() - t0) * 1e6)
+        dt_us = int(sw.dur_us)
         self.wait_us += dt_us
         _bump("feed_wait_us", dt_us)
         if kind == _ITEM:
@@ -237,6 +248,16 @@ class DeviceFeed:
 
 # -- stage builders ----------------------------------------------------------
 
+def staged_put(put, arrays, **kwargs):
+    """`put(arrays, **kwargs)`, a trainer's commit of host arrays to its
+    devices (`jax.device_put`), as a stage function calls it: under the
+    `feed.put` span, its bytes counted into `feed_staged_bytes`, so that
+    the copy rate is bytes over the span's time, read and not deduced."""
+    _bump("feed_staged_bytes", sum(int(a.nbytes) for a in arrays))
+    with _tracing.span("feed.put"):
+        return put(arrays, **kwargs)
+
+
 def module_stage(module):
     """Stage function for DataBatch streams feeding a bound module: each
     data/label array is committed to the placement the module's executor
@@ -266,18 +287,20 @@ def module_stage(module):
             target = ex._arg_sharding(name)
         else:
             target = ex._ctx.jax_device()
+        _bump("feed_staged_bytes", int(data.nbytes))
         return NDArray(jax.device_put(data, target))
 
     def stage(batch):
         ex = getattr(module, "_exec", None)
         if ex is None or getattr(ex, "arg_dict", None) is None:
             return batch
-        data = [_put(ex, n, a)
-                for n, a in zip(module.data_names, batch.data)]
-        label = batch.label
-        if label:
-            lnames = list(getattr(module, "label_names", None) or [])
-            label = [_put(ex, n, a) for n, a in zip(lnames, label)]
+        with _tracing.span("feed.put"):
+            data = [_put(ex, n, a)
+                    for n, a in zip(module.data_names, batch.data)]
+            label = batch.label
+            if label:
+                lnames = list(getattr(module, "label_names", None) or [])
+                label = [_put(ex, n, a) for n, a in zip(lnames, label)]
         return DataBatch(data=data, label=label, pad=batch.pad,
                          index=batch.index, bucket_key=batch.bucket_key,
                          provide_data=batch.provide_data,

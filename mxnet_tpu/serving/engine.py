@@ -222,38 +222,47 @@ class ServingEngine:
     def infer(self, *arrays):
         """Run one already-coalesced batch (n rows, 1 <= n <= max_batch,
         batch axis 0). Returns a list of numpy arrays sliced to n."""
-        arrays = [np.asarray(getattr(a, "_data", a), np.float32)
-                  for a in arrays]
-        if len(arrays) != len(self.input_names):
-            raise ValueError(f"expected {len(self.input_names)} inputs "
-                             f"{self.input_names}, got {len(arrays)}")
-        n = int(arrays[0].shape[0])
-        for name, a in zip(self.input_names, arrays):
-            want = self._pred._input_shapes[name]
-            if a.shape[0] != n or tuple(a.shape[1:]) != tuple(want[1:]):
-                raise ValueError(
-                    f"input {name!r}: shape {tuple(a.shape)} is not "
-                    f"(n<= {self.max_batch},)+{tuple(want[1:])}")
-        bucket = self.bucket_for(n)
-        if bucket != n:
-            arrays = [np.concatenate(
-                [a, np.zeros((bucket - n,) + a.shape[1:], a.dtype)],
-                axis=0) for a in arrays]
-        # "serve" span covers lock wait + plan execution — the
-        # request-visible compute latency
-        with _tracing.span("serve.compute", phase="serve",
-                           bucket=bucket, rows=n):
-            with self._lock:
-                # padding accounting under the lock: infer() runs
-                # concurrently on batcher-worker and direct-caller
-                # threads, and += on a bare attribute loses updates
-                # under that interleaving
+        # one parent over the whole call; its three children cover it:
+        # serve.pad (host conversion, checks and the padding
+        # concatenations), serve.compute (lock wait + plan run; returns
+        # before the device ends) and serve.fetch (the answer's copy to
+        # the host, which waits for the device)
+        with _tracing.span("serve.infer"):
+            with _tracing.span("serve.pad"):
+                arrays = [np.asarray(getattr(a, "_data", a), np.float32)
+                          for a in arrays]
+                if len(arrays) != len(self.input_names):
+                    raise ValueError(
+                        f"expected {len(self.input_names)} inputs "
+                        f"{self.input_names}, got {len(arrays)}")
+                n = int(arrays[0].shape[0])
+                for name, a in zip(self.input_names, arrays):
+                    want = self._pred._input_shapes[name]
+                    if a.shape[0] != n or \
+                            tuple(a.shape[1:]) != tuple(want[1:]):
+                        raise ValueError(
+                            f"input {name!r}: shape {tuple(a.shape)} is not "
+                            f"(n<= {self.max_batch},)+{tuple(want[1:])}")
+                bucket = self.bucket_for(n)
                 if bucket != n:
-                    self.padded_rows += bucket - n
-                outs = self._run(bucket, arrays)
-        return [np.asarray(o)[:n]
-                if getattr(o, "ndim", 0) and np.asarray(o).shape[0] == bucket
-                else np.asarray(o) for o in outs]
+                    arrays = [np.concatenate(
+                        [a, np.zeros((bucket - n,) + a.shape[1:], a.dtype)],
+                        axis=0) for a in arrays]
+            with _tracing.span("serve.compute", phase="serve",
+                               bucket=bucket, rows=n):
+                with self._lock:
+                    # padding accounting under the lock: infer() runs
+                    # concurrently on batcher-worker and direct-caller
+                    # threads, and += on a bare attribute loses updates
+                    # under that interleaving
+                    if bucket != n:
+                        self.padded_rows += bucket - n
+                    outs = self._run(bucket, arrays)
+            with _tracing.span("serve.fetch", bucket=bucket, rows=n):
+                return [np.asarray(o)[:n]
+                        if getattr(o, "ndim", 0)
+                        and np.asarray(o).shape[0] == bucket
+                        else np.asarray(o) for o in outs]
 
     def stats(self):
         return {"buckets": list(self.buckets),

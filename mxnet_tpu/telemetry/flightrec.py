@@ -43,6 +43,9 @@ __all__ = ["enabled", "record", "snapshot", "dump", "tail_lines",
 __analysis_thread_safe__ = {"_installed"}
 
 _lock = threading.Lock()
+_dump_lock = threading.RLock()   # re-entrant: SIGTERM may land mid-dump
+_PERIODIC = "periodic-flush"     # the flusher's reason
+_crash_boxes = set()             # paths a crash trigger has written
 _ring = None          # deque, created lazily at first record
 _total = 0            # appended since reset
 _installed = {
@@ -136,20 +139,28 @@ def dump(path=None, reason="on-demand", last_s=None):
         return None
     try:
         path = path or default_path()
-        st = stats()
-        box = {"version": 1, "rank": rank(), "pid": os.getpid(),
-               "reason": str(reason), "wall_time": time.time(),
-               "events": snapshot(last_s=last_s),
-               "dropped": st["dropped"], "total": st["total"]}
-        if box["events"]:
-            box["last_event_t"] = box["events"][-1]["t"]
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "w", encoding="utf-8") as f:
-            json.dump(box, f)
-        os.replace(tmp, path)
+        # one dump at a time, snapshot to rename: the flusher's box must
+        # not share a crash hook's tmp file, nor land over its box (a
+        # flush that was due when the crash came would take its reason)
+        with _dump_lock:
+            if reason != _PERIODIC:
+                _crash_boxes.add(path)
+            elif path in _crash_boxes:
+                return path
+            st = stats()
+            box = {"version": 1, "rank": rank(), "pid": os.getpid(),
+                   "reason": str(reason), "wall_time": time.time(),
+                   "events": snapshot(last_s=last_s),
+                   "dropped": st["dropped"], "total": st["total"]}
+            if box["events"]:
+                box["last_event_t"] = box["events"][-1]["t"]
+            d = os.path.dirname(path)
+            if d:
+                os.makedirs(d, exist_ok=True)
+            tmp = f"{path}.tmp.{os.getpid()}"
+            with open(tmp, "w", encoding="utf-8") as f:
+                json.dump(box, f)
+            os.replace(tmp, path)
         return path
     except Exception:                    # pragma: no cover
         return None
@@ -220,7 +231,7 @@ def _flusher(stop, directory):
             total = _total
         if total != last_total:
             last_total = total
-            dump(path=path, reason="periodic-flush")
+            dump(path=path, reason=_PERIODIC)
         if stop.wait(_flush_interval() or 0.5):
             return
 
@@ -279,6 +290,8 @@ def uninstall():
             _installed["sigterm"] = None
         flusher, _installed["flusher"] = _installed["flusher"], None
         _installed["dir"] = None
+    with _dump_lock:
+        _crash_boxes.clear()
     if flusher is not None:
         t, stop = flusher
         stop.set()

@@ -4,8 +4,16 @@ Host-side spans (context manager / decorator) threaded through the step
 phases the framework owns: DeviceFeed staging (`pipeline.py`), fused and
 per-batch dispatch (`module/`, `gluon/trainer.py`), dist.py barrier /
 allreduce waits, checkpoint stage/commit/seal, and the serving request
-lifecycle (queue -> batch -> compute). Three sinks per span close:
+lifecycle (queue -> batch -> compute). Four sinks per span:
 
+  - the JAX profiler's own trace: every timed span enters a
+    `jax.profiler.TraceAnnotation` named `"mx." + name` that carries the
+    span's arguments, so a profiler session (`jax.profiler.start_trace`)
+    holds the program's spans on the clock of the device trace and an
+    idle gap of the device can be put down to the host work under it.
+    An annotation records only while a session runs; otherwise it costs
+    under a microsecond a site. Retrospective `event()` spans cannot be
+    written into the profiler after the fact and stay out of it;
   - the shared profiler chrome-event ring (`profiler.EventRing`) as a
     complete ("X") event with cat `trace:<phase>`, pid=rank, tid=thread —
     so `trace-rank-K.json` shards are perfetto-loadable as-is;
@@ -16,10 +24,11 @@ lifecycle (queue -> batch -> compute). Three sinks per span close:
 
 Discipline: monotonic clocks only (`time.perf_counter`), zero device
 syncs, per-thread span stacks (threading.local), and `MXNET_TRACE=0`
-(the default) short-circuits `span()` to a shared no-op before any
-timestamp is taken — fit is bit-identical and pays one env lookup per
-span site. Never put a span inside a jit-traced function: the trace-
-purity lint (mxnet_tpu.analysis) flags wall-clock reads under trace.
+(the default) keeps spans out of the event ring and the phase totals;
+with `MXNET_FLIGHTREC=0` too, `span()` short-circuits to a shared no-op
+before any timestamp is taken. Fit is bit-identical either way. Never
+put a span inside a jit-traced function: the trace-purity lint
+(mxnet_tpu.analysis) flags wall-clock reads under trace.
 
 Cross-rank alignment: each rank's `perf_counter` has an arbitrary
 epoch, so every shard records its own wall<->perf offset, and the first
@@ -43,11 +52,14 @@ import re
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import flightrec
 from .. import profiler
 
-__all__ = ["enabled", "active", "span", "traced", "event", "set_step",
-           "current_stack", "phase_totals", "reset_phase_totals",
+__all__ = ["enabled", "active", "span", "stopwatch", "traced", "event",
+           "set_step", "current_stack", "phase_totals",
+           "reset_phase_totals",
            "dump", "shard_path", "merge", "format_summary",
            "arm_autodump", "disarm_autodump", "exchange_clock",
            "clock_info", "synth_shards", "main"]
@@ -139,7 +151,10 @@ def _emit(name, phase, t0_perf, dur_us, args, error=None):
 
 
 class _Span:
-    __slots__ = ("name", "phase", "args", "_t0")
+    """A timed span. `dur_us` is set when it closes. A span opened inside
+    another of the same thread takes over the parent's `seq` (the number
+    of the block of work both belong to) unless it names its own."""
+    __slots__ = ("name", "phase", "args", "dur_us", "_t0", "_ann")
 
     def __init__(self, name, phase, args):
         self.name = name
@@ -150,14 +165,22 @@ class _Span:
         st = getattr(_tls, "stack", None)
         if st is None:
             st = _tls.stack = []
-        st.append(self.name)
+        if st:
+            parent = st[-1].args
+            if parent and "seq" in parent and \
+                    "seq" not in (self.args or ()):
+                self.args = dict(self.args or (), seq=parent["seq"])
+        st.append(self)
+        self._ann = _TraceAnnotation("mx." + self.name, **(self.args or {}))
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur_us = (time.perf_counter() - self._t0) * 1e6
+        self.dur_us = (time.perf_counter() - self._t0) * 1e6
+        self._ann.__exit__(exc_type, exc, tb)
         _tls.stack.pop()
-        _emit(self.name, self.phase, self._t0, dur_us, self.args,
+        _emit(self.name, self.phase, self._t0, self.dur_us, self.args,
               error=exc_type.__name__ if exc_type is not None else None)
         return False
 
@@ -175,6 +198,19 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+class _Stopwatch:
+    """What `stopwatch()` hands out while no sink is on: the clock alone."""
+    __slots__ = ("dur_us", "_t0")
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur_us = (time.perf_counter() - self._t0) * 1e6
+        return False
+
+
 def span(name, phase=None, **args):
     """`with span("feed.wait", phase="feed", feed=name): ...` — times the
     block on this thread's span stack. Phases ("feed", "compute", "comm",
@@ -182,6 +218,15 @@ def span(name, phase=None, **args):
     step breakdown; omit for one-off spans."""
     if not active():
         return _NULL
+    return _Span(name, phase, args or None)
+
+
+def stopwatch(name, phase=None, **args):
+    """A span whose duration the caller reads back (`.dur_us`, after the
+    block): timed even while no sink is on, so that a counter and the
+    span come from one pair of clock reads."""
+    if not active():
+        return _Stopwatch()
     return _Span(name, phase, args or None)
 
 
@@ -210,7 +255,7 @@ def event(name, t0_perf, t1_perf=None, phase=None, **args):
 
 def current_stack():
     """This thread's open span names, outermost first (tests)."""
-    return tuple(getattr(_tls, "stack", ()) or ())
+    return tuple(s.name for s in getattr(_tls, "stack", ()) or ())
 
 
 def set_step(trace_id, step):
@@ -305,7 +350,8 @@ def dump(path=None, clear=False):
     d = os.path.dirname(path)
     if d:
         os.makedirs(d, exist_ok=True)
-    tmp = f"{path}.tmp.{os.getpid()}"
+    # per thread: the flusher and the atexit dump may write at once
+    tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
     with open(tmp, "w", encoding="utf-8") as f:
         json.dump(trace, f)
     os.replace(tmp, path)

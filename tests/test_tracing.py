@@ -9,7 +9,10 @@ jax-free or cheap.
 
 Full tier adds: MXNET_TRACE=0 vs =1 bit-identical Module.fit (tracing
 must never perturb numerics), the excepthook auto-dump, and the
-watchdog dump carrying the flight tail.
+watchdog dump carrying the flight tail; and (ISSUE 24) the spans of a
+fused fit read back from a JAX profiler trace — name, thread, parent
+and block number of each — with the counters and the serve.infer split
+that came with them.
 
 Slow tier (-m slow, Gloo backend): a real 2-rank gang with an injected
 SIGKILL — every rank leaves a black box, the launcher's triage and the
@@ -340,6 +343,262 @@ def test_fit_bit_identical_trace_on_vs_off():
         np.testing.assert_array_equal(on[n], off[n], err_msg=n)
 
 
+# -- the program's spans in the profiler's trace (ISSUE 24) -------------------
+
+K, FUSED_BATCH, FUSED_ROWS, FUSED_FEATURES = 4, 10, 160, 8
+FUSED_DISPATCHES = FUSED_ROWS // (K * FUSED_BATCH)
+LOOP_SPANS = ("feed.wait", "step.fused_dispatch", "step.enqueue",
+              "step.metric_update", "step.log", "step.callbacks",
+              "step.checkpoint")
+FEEDER_SPANS = ("feed.stage", "feed.pull", "feed.stack", "feed.put",
+                "feed.enqueue")
+SETUP_SPANS = ("fit.bind", "fit.init_params", "fit.trainer_init",
+               "fit.init_state")
+PARENT = {"step.enqueue": "step.fused_dispatch",
+          "step.metric_update": "step.fused_dispatch",
+          "feed.pull": "feed.stage", "feed.stack": "feed.stage",
+          "feed.put": "feed.stage"}
+
+
+def _fused_fit(checkpoint_dir=None):
+    """One epoch of a tiny fused fit (K=4, 4 dispatches); returns the
+    trained arguments."""
+    mx.random.seed(7)
+    np.random.seed(7)
+    rng = np.random.RandomState(0)
+    X = rng.uniform(-1, 1, (FUSED_ROWS, FUSED_FEATURES)).astype(np.float32)
+    Y = rng.randint(0, 4, (FUSED_ROWS,)).astype(np.float32)
+    it = mx.io.NDArrayIter(X, Y, batch_size=FUSED_BATCH, shuffle=False)
+    mod = mx.mod.Module(_mlp_sym(), context=mx.cpu(0))
+    mod.fit(it, num_epoch=1, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+            initializer=mx.init.Xavier(), steps_per_dispatch=K,
+            batch_end_callback=lambda param: None,
+            checkpoint_dir=checkpoint_dir,
+            checkpoint_period=K if checkpoint_dir else None)
+    args, _ = mod.get_params()
+    return {n: a.asnumpy() for n, a in args.items()}
+
+
+@pytest.fixture(scope="module")
+def profiled_fit(tmp_path_factory):
+    """{span name: [(line, start_ns, end_ns, stats)]} of the `mx.*` events
+    a profiler session recorded over one tiny fused fit that checkpoints
+    after every dispatch, read back with jax.profiler.ProfileData."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    tmp = tmp_path_factory.mktemp("profiled_fit")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp / "profile"), profiler_options=opts)
+    try:
+        _fused_fit(checkpoint_dir=str(tmp / "ckpt"))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp / "profile" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("mx."):
+                    spans.setdefault(ev.name[3:], []).append(
+                        ((plane.name, i), ev.start_ns,
+                         ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return spans
+
+
+@pytest.mark.parametrize("name", LOOP_SPANS + FEEDER_SPANS + SETUP_SPANS)
+def test_span_in_profiler_trace(profiled_fit, name):
+    """Every span of the table is in the profiler's own trace, on the
+    thread it belongs to, inside its parent, and numbered by block."""
+    assert name in profiled_fit, sorted(profiled_fit)
+    events = profiled_fit[name]
+    loop_line = profiled_fit["step.fused_dispatch"][0][0]
+    feeder_line = profiled_fit["feed.stage"][0][0]
+    assert loop_line != feeder_line
+    want_line = feeder_line if name in FEEDER_SPANS else loop_line
+    assert {line for line, *_ in events} == {want_line}
+    if name in SETUP_SPANS:
+        assert len(events) == 1
+        first_wait = min(s for _, s, _, _ in profiled_fit["feed.wait"])
+        assert events[0][2] <= first_wait     # set-up ends before the loop
+        return
+    # one event per block (the feed's last wait/stage/pull find the end)
+    seqs = sorted(st["seq"] for *_, st in events)
+    assert seqs[:FUSED_DISPATCHES] == list(range(FUSED_DISPATCHES))
+    assert len(seqs) <= FUSED_DISPATCHES + 1
+    if name in PARENT:
+        parents = {st["seq"]: (s, e)
+                   for _, s, e, st in profiled_fit[PARENT[name]]}
+        for _, s, e, st in events:
+            ps, pe = parents[st["seq"]]
+            assert ps <= s and e <= pe, (name, st)
+
+
+def test_profiler_trace_joins_a_block_by_seq(profiled_fit):
+    """Block n is staged, then waited for, then dispatched: the three
+    spans that carry seq n lie in that order, across two threads."""
+    def by_seq(name):
+        return {st["seq"]: (s, e) for _, s, e, st in profiled_fit[name]}
+    stage, wait, disp = (by_seq("feed.stage"), by_seq("feed.wait"),
+                         by_seq("step.fused_dispatch"))
+    for n in range(FUSED_DISPATCHES):
+        assert stage[n][1] <= wait[n][1] <= disp[n][0]
+    # the leaves of the loop thread do not overlap one another
+    leaves = sorted((s, e) for name in LOOP_SPANS
+                    if name != "step.fused_dispatch"
+                    for _, s, e, _ in profiled_fit[name])
+    assert all(a[1] <= b[0] for a, b in zip(leaves, leaves[1:]))
+
+
+def test_child_spans_add_no_phase_total(monkeypatch, tmp_path):
+    """The children of step.fused_dispatch and feed.stage carry no phase:
+    StepLogger's compute_us is the parent's time, counted once."""
+    log = tmp_path / "steps.jsonl"
+    monkeypatch.setenv("MXNET_TRACE", "1")
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    monkeypatch.setenv("MXNET_TELEMETRY_LOG", str(log))
+    _fused_fit()
+    counts = tracing.phase_counts()
+    assert counts["compute"] == FUSED_DISPATCHES
+    assert counts["feed"] == FUSED_DISPATCHES + 1       # + the end's wait
+    assert counts["feed_stage"] == FUSED_DISPATCHES + 1
+    assert set(counts) <= {"compute", "feed", "feed_stage", "ckpt", "comm"}
+    evs = _trace_events()
+    by_cat = {}
+    for e in evs:
+        by_cat.setdefault(e["cat"], set()).add(e["name"])
+    assert by_cat["trace:compute"] == {"step.fused_dispatch"}
+    assert {"step.enqueue", "step.metric_update", "step.log", "feed.pull",
+            "feed.stack", "feed.put", "feed.enqueue"} <= by_cat["trace:span"]
+    # children lie inside their parent (1µs float slack)
+    disp = {e["args"]["seq"]: e for e in evs
+            if e["name"] == "step.fused_dispatch"}
+    for e in evs:
+        if e["name"] in ("step.enqueue", "step.metric_update"):
+            p = disp[e["args"]["seq"]]
+            assert p["ts"] <= e["ts"] and \
+                e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1.0
+    steps = [json.loads(line) for line in
+             open(log, encoding="utf-8").read().splitlines()]
+    steps = [r for r in steps if r["event"] == "step"]
+    assert len(steps) == FUSED_DISPATCHES
+    for n, rec in enumerate(steps):
+        assert {"feed_us", "compute_us", "comm_us", "ckpt_us",
+                "feed_compute_overlap_frac",
+                "comm_compute_overlap_frac"} <= set(rec)
+        assert rec["compute_us"] == pytest.approx(disp[n]["dur"], abs=2.0)
+
+
+def test_fused_fit_bit_identical_and_span_sites_counted(monkeypatch):
+    """No profiler session, MXNET_TRACE=0: the fused fit with its span
+    sites timed (flight recorder on) equals the one with every site a
+    no-op, bit for bit; fewer than 16 span sites run per dispatch."""
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    monkeypatch.setenv("MXNET_FLIGHTREC", "0")
+    off = _fused_fit()
+    assert flightrec.stats()["total"] == 0 and _trace_events() == []
+    monkeypatch.setenv("MXNET_FLIGHTREC", "1")
+    on = _fused_fit()
+    assert _trace_events() == []
+    assert set(on) == set(off)
+    for n in on:
+        np.testing.assert_array_equal(on[n], off[n], err_msg=n)
+    spans = [e for e in flightrec.snapshot() if e["kind"] == "span"]
+    per_block = {}
+    for e in spans:
+        if e["name"] not in SETUP_SPANS:
+            per_block[e["seq"]] = per_block.get(e["seq"], 0) + 1
+    assert set(range(FUSED_DISPATCHES)) <= set(per_block)
+    assert all(5 <= n < 16 for s, n in per_block.items()
+               if s < FUSED_DISPATCHES), per_block
+
+
+def test_setup_spans_and_first_dispatch_in_flightrec(monkeypatch):
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    _fused_fit()
+    spans = [e for e in flightrec.snapshot() if e["kind"] == "span"]
+    names = [e["name"] for e in spans]
+    assert [n for n in names if n.startswith("fit.")] == list(SETUP_SPANS)
+    first = names.index("step.fused_dispatch")
+    assert first > names.index("fit.init_state")
+    assert spans[first]["seq"] == 0 and spans[first]["dur_us"] > 0
+    assert all(e["dur_us"] >= 0 for e in spans)
+
+
+def test_feed_staged_bytes_counts_the_blocks_staged():
+    from mxnet_tpu import pipeline
+    pipeline.reset_stats()
+    _fused_fit()
+    st = pipeline.stats()
+    assert st["feed_batches"] == FUSED_DISPATCHES
+    # a block: K batches of float32 features and labels
+    block = K * FUSED_BATCH * (FUSED_FEATURES + 1) * 4
+    assert st["feed_staged_bytes"] == FUSED_DISPATCHES * block
+
+
+def test_stopwatch_times_with_every_sink_off(monkeypatch):
+    """DeviceFeed's counters read the span's own clock: a stopwatch is
+    timed even when nothing records it."""
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    monkeypatch.setenv("MXNET_FLIGHTREC", "0")
+    with tracing.stopwatch("feed.wait", phase="feed") as sw:
+        time.sleep(0.002)
+        assert tracing.current_stack() == ()
+    assert sw.dur_us >= 1500.0
+    assert flightrec.stats()["total"] == 0 and tracing.phase_totals() == {}
+    monkeypatch.setenv("MXNET_FLIGHTREC", "1")
+    with tracing.stopwatch("feed.wait", phase="feed", seq=3) as sw:
+        with tracing.span("feed.inner"):
+            pass
+    (inner, outer) = flightrec.snapshot()
+    assert outer["dur_us"] == int(sw.dur_us)
+    assert inner["seq"] == 3                  # taken over from the parent
+
+
+def test_serve_infer_children_cover_infer(monkeypatch, tmp_path):
+    """serve.infer spans the whole of ServingEngine.infer; serve.pad,
+    serve.compute and serve.fetch lie inside it, in that order, and
+    leave none of it uncovered but the hops between them."""
+    from mxnet_tpu.contrib.export import export_model
+    from mxnet_tpu.serving import ServingEngine
+    sym = _mlp_sym()
+    mod = mx.mod.Module(sym, context=mx.cpu(0))
+    mod.bind(data_shapes=[("data", (8, 8))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params(mx.init.Xavier())
+    args, auxs = mod.get_params()
+    path = str(tmp_path / "model.mxa")
+    export_model(path, sym, args, auxs, {"data": (8, 8)})
+    engine = ServingEngine(path)
+    engine.warmup()
+    monkeypatch.setenv("MXNET_TRACE", "1")
+    profiler.clear_events()
+    t0 = time.perf_counter()
+    (out,) = engine.infer(np.ones((3, 8), np.float32))
+    wall_us = (time.perf_counter() - t0) * 1e6
+    assert out.shape == (3, 4)
+    evs = {e["name"]: e for e in _trace_events()}
+    assert set(evs) == {"serve.infer", "serve.pad", "serve.compute",
+                        "serve.fetch"}
+    parent = evs["serve.infer"]
+    assert evs["serve.compute"]["cat"] == "trace:serve"
+    assert parent["cat"] == "trace:span"      # no phase: counted once
+    kids = [evs[n] for n in ("serve.pad", "serve.compute", "serve.fetch")]
+    assert parent["ts"] <= kids[0]["ts"]
+    for a, b in zip(kids, kids[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1.0
+    assert kids[-1]["ts"] + kids[-1]["dur"] <= \
+        parent["ts"] + parent["dur"] + 1.0
+    assert evs["serve.compute"]["args"]["bucket"] == engine.bucket_for(3)
+    assert evs["serve.fetch"]["args"]["rows"] == 3
+    covered = sum(k["dur"] for k in kids)
+    assert covered <= parent["dur"] + 1.0 <= wall_us + 1.0
+    assert parent["dur"] - covered < 200.0 + 0.1 * parent["dur"]
+
+
 # -- flight recorder ---------------------------------------------------------
 
 def test_flightrec_ring_dump_and_tail(monkeypatch, tmp_path):
@@ -387,6 +646,30 @@ def test_flightrec_excepthook_dumps_blackbox(monkeypatch, tmp_path):
     finally:
         flightrec.uninstall()
     assert sys.excepthook is prev_hook
+
+
+def test_periodic_flush_never_takes_a_crash_box(monkeypatch, tmp_path):
+    """A flush that was due when a crash hook dumped must not land over
+    the crash's box and take its reason (the flusher and the hook race)."""
+    monkeypatch.setenv("MXNET_FLIGHTREC", "1")
+    path = str(tmp_path / "box.json")
+
+    def reason():
+        with open(path, encoding="utf-8") as f:
+            return json.load(f)["reason"]
+
+    flightrec.record("event", "beat")
+    try:
+        flightrec.dump(path=path, reason="periodic-flush")
+        assert reason() == "periodic-flush"
+        flightrec.dump(path=path, reason="SIGTERM")
+        flightrec.record("event", "later")
+        assert flightrec.dump(path=path, reason="periodic-flush") == path
+        assert reason() == "SIGTERM"
+    finally:
+        flightrec.uninstall()               # forgets the crash boxes
+    flightrec.dump(path=path, reason="periodic-flush")
+    assert reason() == "periodic-flush"
 
 
 def test_watchdog_dump_carries_flight_tail(monkeypatch, tmp_path):
