@@ -175,7 +175,7 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
             if n in pmap:
                 pmap[n].set_data(nd_array(a))
 
-    from ..pipeline import feed_or_inline, close_feed, staged_put
+    from ..pipeline import feed_or_inline, close_feed, BlockStager
     from ..telemetry import tracing as _tracing
 
     def _blocks(stream):
@@ -185,15 +185,15 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
                 return
             yield block
 
+    stager = BlockStager(trainer.shard_inputs)
+
     def _stage_block(block):
         # stack + device commit on the feeder thread: block N+1 is staged
-        # while block N's fused scan executes (np.stack copies, so loader
-        # buffer reuse is safe)
-        with _tracing.span("feed.stack"):
-            xs = np.stack([_np_of(b[0]) for b in block])
-            ys = np.stack([_np_of(b[1]) for b in block])
-        return staged_put(trainer.shard_inputs, [xs, ys],
-                          stacked=True), len(block)
+        # while block N's fused scan executes (the stager copies into host
+        # buffers of its own, so loader buffer reuse is safe)
+        columns = [[_np_of(b[0]) for b in block],
+                   [_np_of(b[1]) for b in block]]
+        return stager(columns, stacked=True), len(block)
 
     # default K comes from MXNET_FUSED_K (the planner auto-tunes it per
     # chosen plan, "auto unless set"); 0/unset keeps the historical 8

@@ -506,7 +506,7 @@ class Module(BaseModule):
             ckpt_mgr.install_sigterm_hook()
 
         from ..base import to_numpy as _np_of
-        from ..pipeline import feed_or_inline, close_feed, staged_put
+        from ..pipeline import feed_or_inline, close_feed, BlockStager
         from ..telemetry import maybe_step_logger
         slog = maybe_step_logger("module_fit_fused", meta={
             "optimizer": optimizer, "steps_per_dispatch": int(k),
@@ -523,27 +523,22 @@ class Module(BaseModule):
                     return
                 yield block
 
+        stager = BlockStager(trainer.shard_inputs)
+
         def _stage_block(block):
             # host stack + device commit run on the feeder thread: block
-            # N+1 is staged while block N's fused scan executes. np.stack
-            # copies, so iterator buffer reuse is safe; a short tail block
-            # compiles its own (cached) k'-step scan
-            with _tracing.span("feed.stack"):
-                stacked = []
-                for name in trainer.input_names:
-                    if name in data_idx:
-                        col = [_np_of(b.data[data_idx[name]])
-                               for b in block]
-                    else:
-                        col = [_np_of(b.label[label_idx[name]])
-                               for b in block]
-                    stacked.append(np.stack(col))
-                labels = {
-                    name: np.concatenate([_np_of(b.label[i])
-                                          for b in block])
-                    for name, i in label_idx.items()}
-            inputs = staged_put(trainer.shard_inputs, stacked,
-                                stacked=True)
+            # N+1 is staged while block N's fused scan executes. The
+            # stager copies into host buffers of its own before it
+            # returns, so iterator buffer reuse is safe; a short tail
+            # block compiles its own (cached) k'-step scan
+            columns = [
+                [_np_of(b.data[data_idx[name]]) if name in data_idx
+                 else _np_of(b.label[label_idx[name]]) for b in block]
+                for name in trainer.input_names]
+            inputs = stager(columns, stacked=True)
+            labels = {
+                name: np.concatenate([_np_of(b.label[i]) for b in block])
+                for name, i in label_idx.items()}
             return inputs, labels, len(block)
 
         def _ckpt_capture(next_epoch, next_batch):

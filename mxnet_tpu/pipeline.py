@@ -17,20 +17,32 @@ Mechanics:
     feeder stays at most `depth` batches ahead, so device memory holds a
     bounded number of staged batches no matter how fast the source is;
   - the stage function runs ON THE FEEDER THREAD and must copy out of
-    the source item (device_put / np.stack both do), which is what makes
-    prefetching safe over legacy buffer-reusing iterators — the very
-    reason BaseModule.fit's fetch-after-update discipline exists;
+    the source item before it returns (device_put does; so does
+    BlockStager's stack into a host buffer of its own), which is what
+    makes prefetching safe over legacy buffer-reusing iterators — the
+    very reason BaseModule.fit's fetch-after-update discipline exists;
+  - the fused K-step drivers stage through `BlockStager`: the (K, batch,
+    ...) block of every input column is stacked into one of two host
+    buffers the stager owns and refills, block after block (a fresh
+    np.stack result of that size is a fresh mmap whose every page faults
+    on first touch: 616 MB a dispatch for ResNet-50 at batch 256, which
+    set that fit's pace; PERF.md §6, PR 25). device_put returns before
+    the runtime has read the host array, so a buffer is refilled only
+    after the device arrays last staged from it are ready
+    (`feed.reuse_wait`), and never where they alias it;
   - feeder exceptions are re-raised in the consumer thread at the next
     __next__; close() drains and joins the thread (no leaked threads);
   - counters (`feed_wait_us`, `feed_stage_us`, `feed_staged_bytes`,
+    `feed_stack_reuses`, `feed_stack_allocs`, `feed_reuse_wait_us`,
     `overlap_frac`, ...) are exported through
     profiler.register_counter_export under the "device_feed" key, so
     profiler.dump() traces carry them;
   - spans (telemetry/tracing.py; `mx.<name>` in a profiler trace), every
     one carrying `seq`, the block's number since the feed opened: on the
     feeder thread `feed.stage` over `feed.pull` (the source), whatever
-    the stage function opens (`feed.stack`, `feed.put`), then
-    `feed.enqueue` (blocked on a full ring); on the consumer's thread
+    the stage function opens (`feed.reuse_wait`, `feed.stack`,
+    `feed.put`), then `feed.enqueue` (blocked on a full ring); on the
+    consumer's thread
     `feed.wait`. Each counter is fed from its span's own clock reads.
 
 The loops threaded through it: Module/BaseModule.fit, the fused K-step
@@ -50,14 +62,16 @@ import numpy as np
 
 from .telemetry import tracing as _tracing
 
-__all__ = ["DeviceFeed", "module_stage", "staged_put", "enabled",
-           "default_depth", "stats", "reset_stats"]
+__all__ = ["DeviceFeed", "BlockStager", "module_stage", "staged_put",
+           "enabled", "default_depth", "stats", "reset_stats"]
 
 # -- aggregate counters (exported via profiler.register_counter_export) -----
 
 _STATS_LOCK = threading.Lock()
 _TOTALS = {"feed_wait_us": 0, "feed_stage_us": 0, "feed_batches": 0,
-           "feed_staged_bytes": 0, "feeds_opened": 0, "feeds_closed": 0}
+           "feed_staged_bytes": 0, "feed_stack_reuses": 0,
+           "feed_stack_allocs": 0, "feed_reuse_wait_us": 0,
+           "feeds_opened": 0, "feeds_closed": 0}
 
 
 def _bump(key, val):
@@ -126,8 +140,9 @@ class DeviceFeed:
 
     `stage(item)` runs on the feeder thread and should return the
     device-committed form of `item` (it MUST copy out of any buffer the
-    source reuses; jax.device_put and np.stack both do). Omitting it
-    degrades gracefully to host-side prefetch of the raw items.
+    source reuses before it returns; jax.device_put does, and so does
+    BlockStager's stack into its own host buffer). Omitting it degrades
+    gracefully to host-side prefetch of the raw items.
 
     Iterator contract: yields staged items in source order; StopIteration
     at exhaustion; a feeder-side exception (from the source or the stage
@@ -256,6 +271,106 @@ def staged_put(put, arrays, **kwargs):
     _bump("feed_staged_bytes", sum(int(a.nbytes) for a in arrays))
     with _tracing.span("feed.put"):
         return put(arrays, **kwargs)
+
+
+_new_buffer = np.empty      # where BlockStager's host buffers come from
+
+
+def _may_alias(staged, buf):
+    """Whether `staged` (what a trainer's put returned for one block) may
+    share memory with the host buffer `buf`. Device memory of another
+    platform never does; the CPU backend hands a suitably aligned numpy
+    buffer to the device array without a copy (64-byte alignment, whatever
+    `may_alias` says; JAX 0.9.0), so there the shards' buffer pointers are
+    compared with the buffer's range. What cannot be told apart counts as
+    aliasing."""
+    import jax
+    lo = buf.ctypes.data
+    hi = lo + buf.nbytes
+    for leaf in jax.tree_util.tree_leaves(staged):
+        if isinstance(leaf, np.ndarray):
+            if np.may_share_memory(leaf, buf):
+                return True
+            continue
+        try:
+            for shard in leaf.addressable_shards:
+                if shard.device.platform == "cpu" and \
+                        lo <= shard.data.unsafe_buffer_pointer() < hi:
+                    return True
+        except (AttributeError, RuntimeError):    # no pointer to read
+            return True
+    return False
+
+
+class BlockStager:
+    """The fused drivers' stage step: `stager(columns, **kwargs)` stacks
+    each column (the K host arrays of one input) into a `(K, batch, ...)`
+    host buffer under the span `feed.stack` and commits the blocks through
+    `staged_put(put, blocks, **kwargs)`; returns what `put` returned. The
+    blocks hold the bytes `np.stack` would have given.
+
+    The buffers are the stager's own: two sets, taken in turn, each made
+    when a block first needs it and kept for as long as the blocks' shape
+    and dtype stay what they were. A block of fewer rows (the tail of an
+    epoch) fills and commits the leading rows of the same buffer; another
+    shape or dtype gets a new buffer and the old one is dropped. The stack
+    is synchronous, so the columns may be reused by their source as soon as
+    the call returns (DeviceFeed's contract).
+
+    `put` may return before its arrays have been read (jax.device_put
+    does: the runtime linearizes and transfers on threads of its own). So
+    the stager keeps what was last staged from each set and waits for it
+    (`jax.block_until_ready`, span `feed.reuse_wait`) before it writes
+    into that set again: with two sets that is the block before last, long
+    on the device. Where the staged arrays may alias a buffer
+    (`_may_alias`) the buffer is theirs: the stager lets go of it and
+    makes a new one at its next turn.
+
+    One thread at a time: the feeder's, or the loop's own where
+    MXNET_DEVICE_FEED=0 runs the stage function inline.
+    """
+
+    _SETS = 2
+
+    def __init__(self, put):
+        self._put = put
+        # per set: the columns' buffers, and what was last staged from them
+        self._bufs = [[] for _ in range(self._SETS)]
+        self._staged = [None] * self._SETS
+        self._turn = 0
+
+    def __call__(self, columns, **kwargs):
+        import jax
+        turn = self._turn % self._SETS
+        self._turn += 1
+        bufs = self._bufs[turn]
+        if self._staged[turn] is not None:
+            with _tracing.stopwatch("feed.reuse_wait") as sw:
+                jax.block_until_ready(self._staged[turn])
+            self._staged[turn] = None
+            _bump("feed_reuse_wait_us", int(sw.dur_us))
+        with _tracing.span("feed.stack"):
+            if len(bufs) != len(columns):
+                bufs[:] = [None] * len(columns)
+            blocks = []
+            for i, col in enumerate(columns):
+                rows, shape = len(col), np.shape(col[0])
+                dtype = np.result_type(*col)
+                buf = bufs[i]
+                if buf is None or buf.shape[0] < rows or \
+                        buf.shape[1:] != shape or buf.dtype != dtype:
+                    buf = bufs[i] = _new_buffer((rows,) + shape, dtype)
+                    _bump("feed_stack_allocs", 1)
+                else:
+                    _bump("feed_stack_reuses", 1)
+                blocks.append(np.stack(col, out=buf[:rows]))
+        staged = staged_put(self._put, blocks, **kwargs)
+        for i, buf in enumerate(bufs):
+            if _may_alias(staged, buf):
+                bufs[i] = None
+        if any(buf is not None for buf in bufs):
+            self._staged[turn] = staged
+        return staged
 
 
 def module_stage(module):
