@@ -1,0 +1,15 @@
+"""Trace: the latent-attention core's share of its roofline, forward and
+backward, in percent: the least time for flops_kimi_linear.mla_core_step's
+operations and bytes over the time under the scope `mx.flash_attention`
+(the three flash kernels)."""
+import flops_kimi_linear
+from reduce import op_scopes
+
+
+def compute(ctx):
+    if "sequences_per_step" not in ctx.host:
+        return None
+    tokens = ctx.host["sequences_per_step"] * ctx.config["sequence_length"]
+    return op_scopes.roofline_share(
+        ctx, "mx.flash_attention",
+        *flops_kimi_linear.mla_core_step(ctx.config, tokens))

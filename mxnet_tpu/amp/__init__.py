@@ -35,12 +35,14 @@ import weakref
 
 import numpy as _np
 
-from .policy import ALLOW, LOSS_HEADS, WIDEN
+from .policy import (ALLOW, EXACT_INPUTS, KEEP_FP32, LOSS_HEADS, MIXED,
+                     WIDEN)
 from .scaler import DynamicLossScaler
 
 __all__ = ["init", "disable", "is_enabled", "get_dtype", "compute_dtype",
-           "reduce_dtype", "cast_op_inputs", "counters", "DynamicLossScaler",
-           "ALLOW", "LOSS_HEADS", "WIDEN"]
+           "reduce_dtype", "cast_op_inputs", "exact_variables", "counters",
+           "DynamicLossScaler", "ALLOW", "LOSS_HEADS", "WIDEN", "MIXED",
+           "KEEP_FP32", "EXACT_INPUTS"]
 
 _DTYPES = ("float32", "bfloat16", "float16")
 
@@ -180,6 +182,26 @@ def cast_op_inputs(op_name, ins):
     if scale is not None and op_name in LOSS_HEADS and out:
         out[0] = _inject_grad_scale(out[0], scale)
     return out
+
+
+def exact_variables(symbol):
+    """Names of the symbol's variables that a trainer must hand to the
+    graph as they are, whatever its compute dtype: parameters that feed an
+    input listed in policy.KEEP_FP32 and data that feeds one listed in
+    policy.EXACT_INPUTS, directly."""
+    keep = set()
+    for node in symbol._topo():
+        if node.op is None:
+            continue
+        names = KEEP_FP32.get(node.op.name, ()) + \
+            EXACT_INPUTS.get(node.op.name, ())
+        if not names:
+            continue
+        parsed = node.op.parse_attrs(node.attrs)
+        for iname, (src, _) in zip(node.op.list_inputs(parsed), node.inputs):
+            if iname in names and src.op is None:
+                keep.add(src.name)
+    return frozenset(keep)
 
 
 # -- counters ---------------------------------------------------------------

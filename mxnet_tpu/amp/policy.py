@@ -21,6 +21,19 @@ OUR op registry names (ops/registry.py). Three buckets:
            bucket — bf16's 8-bit mantissa corrupts ids (parallel/dp.py
            learned this the hard way).
 
+  MIXED  — ops that hold several kinds of arithmetic and set each one's
+           precision themselves (ops/lm.py): their matrix products take
+           the dtype the inputs arrive in (the amp dtype, from the ALLOW
+           ops before them) and accumulate in fp32; decays, cumulative
+           sums, recurrent state, norm statistics, router scores with
+           their top-k and the loss are fp32 inside. The funnel casts
+           nothing for them.
+
+Two tables name inputs that no trainer may narrow on the way in
+(parallel/dp.py casts parameters and float data to the compute dtype
+before the graph runs; `amp.exact_variables` reads these tables):
+KEEP_FP32 for parameters, EXACT_INPUTS for data that carries integers.
+
 The lists are module-level frozensets so tests and docs/AMP.md can
 introspect them; `amp.init` does not mutate them.
 """
@@ -79,3 +92,31 @@ WIDEN = frozenset({
     "smooth_l1",
     "IdentityAttachKLSparseReg",
 })
+
+# several kinds of arithmetic inside, each at its own precision (above)
+MIXED = frozenset({
+    "RMSNorm",
+    "_contrib_kda",
+    "_contrib_moe_experts",
+    "_contrib_lm_head_ce",
+})
+
+# op -> inputs whose PARAMETER stays fp32 all the way into the op: the
+# decay's rate and bias (exp of a bf16 A_log is off by up to 0.4% a step,
+# compounded over the sequence), the router and its selection bias (a
+# rounded score changes which experts are chosen)
+KEEP_FP32 = {
+    "_contrib_kda": ("A_log", "dt_bias"),
+    "_contrib_moe_experts": ("router_weight", "router_bias"),
+}
+
+# op -> inputs that carry integers in a float array (MXNet's convention
+# for ids): bf16 holds integers exactly only up to 256
+EXACT_INPUTS = {
+    "Embedding": ("data",),
+    "_contrib_SparseEmbedding": ("data",),
+    "take": ("indices",),
+    "one_hot": ("indices",),
+    "pick": ("index",),
+    "_contrib_lm_head_ce": ("label",),
+}

@@ -21,6 +21,7 @@ import sys
 import time
 
 __all__ = ["module_checkpoint", "do_checkpoint", "log_train_metric",
+           "ExpertLoadCounters",
            "Speedometer", "ProgressBar", "LogValidationMetricsCallback"]
 
 
@@ -142,3 +143,56 @@ class LogValidationMetricsCallback:
         for name, value in name_value:
             logging.info("Epoch[%d] Validation-%s=%f", param.epoch, name,
                          value)
+
+
+class ExpertLoadCounters:
+    """batch_end_callback for graphs with mixture-of-experts layers: reads
+    the small statistics output the layers hand out of each step
+    (`ops/lm.py::moe_experts`: tokens per held expert, token-expert pairs
+    on held experts, pairs computed, dense fall-backs; stacked over layers
+    and, in the fused fit, over the K steps of a dispatch) and keeps the
+    telemetry registry's counters of them:
+
+      moe_tokens_routed_total     pairs that fell on experts held here
+      moe_tokens_dropped_total    pairs routed but not computed (always 0
+                                  with `moe_experts`, which drops none)
+      moe_dense_fallback_total    layer-steps that took the dense path
+      moe_expert_load_max / _mean largest and mean tokens of a held expert
+                                  in one layer-step of the last dispatch
+
+    `output` is the statistics output's index among the symbol's outputs.
+    """
+
+    def __init__(self, output=1):
+        from .telemetry import registry
+        self._output = output
+        self._routed = registry.counter(
+            "moe_tokens_routed_total",
+            help="token-expert pairs routed to experts held here")
+        self._dropped = registry.counter(
+            "moe_tokens_dropped_total",
+            help="token-expert pairs routed to held experts, not computed")
+        self._dense = registry.counter(
+            "moe_dense_fallback_total",
+            help="mixture layer-steps computed densely (over capacity)")
+        self._max = registry.gauge(
+            "moe_expert_load_max",
+            help="largest token count of a held expert, last dispatch")
+        self._mean = registry.gauge(
+            "moe_expert_load_mean",
+            help="mean token count of a held expert, last dispatch")
+
+    def __call__(self, param):
+        import numpy as np
+        outputs = (param.locals or {}).get("outputs")
+        if outputs is None or len(outputs) <= self._output:
+            return
+        stats = np.asarray(outputs[self._output]).astype(np.int64)
+        stats = stats.reshape(-1, stats.shape[-1])    # (steps*layers, E+3)
+        load, pairs, computed, dense = (stats[:, :-3], stats[:, -3],
+                                        stats[:, -2], stats[:, -1])
+        self._routed.inc(int(pairs.sum()))
+        self._dropped.inc(int((pairs - computed).sum()))
+        self._dense.inc(int(dense.sum()))
+        self._max.set(float(load.max()))
+        self._mean.set(float(load.mean()))

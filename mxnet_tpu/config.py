@@ -296,7 +296,11 @@ def enable_compile_cache(path):
     cache that was placed from outside (the directory is part of a
     deployment, and of the cache's key). Otherwise the cache goes to
     `path` (created), with the min-compile-time/min-entry-size
-    thresholds zeroed so small programs cache too."""
+    thresholds zeroed so small programs cache too, and without a size
+    limit: JAX_COMPILATION_CACHE_MAX_SIZE bounds the directory a
+    deployment placed, and here it would evict (a step program of a few
+    hundred MB pushes out every other entry of a 192 MiB cache, and the
+    next process hits nothing: `kimi_linear.train` on the v5e, PR 26)."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
@@ -305,6 +309,7 @@ def enable_compile_cache(path):
     jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_max_size", -1)
     # jax latches its cache handle at the first compile: if any program
     # compiled before the dir was set, the cache sits initialized-with-
     # no-dir and silently writes nothing — re-initialize so the new dir

@@ -14,6 +14,8 @@ grad_req: 'write' stores grads, 'add' accumulates into the bound grad arrays
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as _np
@@ -163,22 +165,63 @@ def _build_runner(symbol, is_train, platform=None):
     fused_bn, bn_passthrough = _fuse_bn_relu(symbol, topo)
     dead_bias = _dead_bias_convs(symbol, topo) if is_train else set()
 
+    # `mirror_stage` (AttrScope): under MXNET_BACKWARD_DO_MIRROR the nodes
+    # that share a stage are rematerialised as ONE unit: the backward keeps
+    # what enters the stage and recomputes the rest (a decoder layer keeps
+    # its residual stream, not its mixer's activations)
+    units = _mirror_units(topo, node_pos, out_entries) if do_mirror else None
+
     def run(arg_values, aux_values, rng):
         vals = [None] * len(topo)
         new_aux = list(aux_values)
         keys = jax.random.split(rng, max(1, len(rng_nodes))) \
             if rng_nodes else None
-        for pos, node in enumerate(topo):
+        if units:
+            return _run_units(units, vals, new_aux, keys, arg_values)
+        run_nodes(range(len(topo)), vals, new_aux, keys, arg_values,
+                  do_mirror)
+        outputs = tuple(vals[p][i] for (p, i) in out_entries)
+        return outputs, tuple(new_aux)
+
+    def _run_units(units, vals, new_aux, keys, arg_values):
+        for variables, positions, ext, produced in units:
+            run_nodes(variables, vals, new_aux, keys, arg_values, False)
+            if ext is None:                 # a node outside every stage
+                run_nodes(positions, vals, new_aux, keys, arg_values, True)
+                continue
+
+            def stage(ext_vals, aux_in, keys_in, _positions=positions,
+                      _ext=ext, _produced=produced):
+                local = [None] * len(topo)
+                _fill(local, _ext, ext_vals)
+                aux_out = list(aux_in)
+                run_nodes(_positions, local, aux_out, keys_in, arg_values,
+                          False)
+                return [local[p][i] for (p, i) in _produced], aux_out
+
+            outs, aux_out = jax.checkpoint(stage)(
+                [vals[p][i] for (p, i) in ext], list(new_aux), keys)
+            new_aux[:] = aux_out
+            _fill(vals, produced, outs)
+        outputs = tuple(vals[p][i] for (p, i) in out_entries)
+        return outputs, tuple(new_aux)
+
+    def run_nodes(positions, vals, new_aux, keys, arg_values, mirror_each):
+        # `vals` and `new_aux` are the lists ONE call of `run` (or one
+        # stage of it) made for itself: filled while that call is traced,
+        # never kept between calls
+        for pos in positions:
+            node = topo[pos]
             if node.op is None:
                 if id(node) in aux_index:
-                    vals[pos] = (new_aux[aux_index[id(node)]],)
+                    vals[pos] = (new_aux[aux_index[id(node)]],)  # analysis: allow=trace-state-mutation
                 else:
-                    vals[pos] = (arg_values[arg_index[id(node)]],)
+                    vals[pos] = (arg_values[arg_index[id(node)]],)  # analysis: allow=trace-state-mutation
                 continue
             if id(node) in bn_passthrough:
                 # relu folded into the producing BatchNorm (fusion pass)
                 src, _ = node.inputs[0]
-                vals[pos] = vals[node_pos[id(src)]][:1]
+                vals[pos] = vals[node_pos[id(src)]][:1]  # analysis: allow=trace-state-mutation
                 continue
             parsed = node.op.parse_attrs(node.attrs)
             if id(node) in fused_bn:
@@ -193,26 +236,107 @@ def _build_runner(symbol, is_train, platform=None):
             ins = _amp.cast_op_inputs(node.op.name, ins)
             key = keys[rng_slot[id(node)]] if id(node) in rng_slot else None
             octx = OpCtx(is_train=is_train, rng=key, platform=platform)
-            if do_mirror:
-                def _call(k, *a, _op=node.op, _p=parsed, _pf=platform):
-                    return _op.fcompute(
-                        _p, OpCtx(is_train=True, rng=k, platform=_pf), *a)
-                res = jax.checkpoint(_call)(key, *ins)
-            else:
-                res = node.op.fcompute(parsed, octx, *ins)
+            # `profiler_scope` (AttrScope): the node's operations carry the
+            # name in the profiler's metadata, forward and backward
+            scope = node.user_attrs.get("profiler_scope")
+            with jax.named_scope(scope) if scope else _NO_SCOPE:
+                if mirror_each:
+                    def _call(k, *a, _op=node.op, _p=parsed, _pf=platform):
+                        return _op.fcompute(
+                            _p, OpCtx(is_train=True, rng=k, platform=_pf),
+                            *a)
+                    res = jax.checkpoint(_call)(key, *ins)
+                else:
+                    res = node.op.fcompute(parsed, octx, *ins)
             if not isinstance(res, tuple):
                 res = (res,)
             n_out = node.num_outputs()
-            vals[pos] = res[:n_out]
+            vals[pos] = res[:n_out]  # analysis: allow=trace-state-mutation
             if node.op.mutates_aux and (is_train or node.op.aux_always):
                 for j, aux_i in enumerate(node.op.aux_indices):
                     n2, _ = node.inputs[aux_i]
                     if id(n2) in aux_index:
-                        new_aux[aux_index[id(n2)]] = res[n_out + j]
-        outputs = tuple(vals[p][i] for (p, i) in out_entries)
-        return outputs, tuple(new_aux)
+                        new_aux[aux_index[id(n2)]] = res[n_out + j]  # analysis: allow=trace-state-mutation
 
     return run
+
+
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def _fill(vals, entries, values):
+    """vals[pos][output] = value for each (pos, output) entry; a node's
+    slot grows to the outputs that are set."""
+    for (p, i), v in zip(entries, values):
+        slot = list(vals[p] or ())
+        slot += [None] * (i + 1 - len(slot))
+        slot[i] = v
+        vals[p] = slot  # analysis: allow=trace-state-mutation
+
+
+def _mirror_units(topo, node_pos, out_entries):
+    """Execution units for rematerialisation by stage, or None where no
+    node carries `mirror_stage`: [(variables, positions, ext, produced)]
+    in an order that respects the graph. `variables` are the variable nodes
+    the unit reads, filled before it runs. A stage's unit has `ext`, the
+    (pos, output) entries it reads from outside (variables among them),
+    and `produced`, those of its own that are read outside or are outputs
+    of the graph; a node outside every stage is a unit with ext None
+    (checkpointed alone, as MXNET_BACKWARD_DO_MIRROR does without
+    stages)."""
+    stage_of = {}
+    for pos, node in enumerate(topo):
+        st = node.user_attrs.get("mirror_stage") if node.op is not None \
+            else None
+        if st is not None:
+            stage_of[pos] = st
+    if not stage_of:
+        return None
+    # units in the order of their last node: everything a stage reads from
+    # outside comes before its last node in a topological order only if no
+    # outside node depends on the stage and feeds it back, which would be a
+    # cycle between units and is refused below
+    members = {}
+    for pos, st in stage_of.items():
+        members.setdefault(st, []).append(pos)
+    # who reads each (pos, output) entry, the graph's own outputs as -1
+    readers = {e: {-1} for e in out_entries}
+    for pos, node in enumerate(topo):
+        for (n2, i2) in node.inputs:
+            readers.setdefault((node_pos[id(n2)], i2), set()).add(pos)
+    units, emitted = [], set()
+    for pos, node in enumerate(topo):
+        if node.op is None or pos in emitted:
+            continue
+        st = stage_of.get(pos)
+        if st is None:
+            var_ins = [node_pos[id(n2)] for (n2, _) in node.inputs
+                       if n2.op is None]
+            units.append((var_ins, [pos], None, None))
+            emitted.add(pos)
+            continue
+        if pos != members[st][-1]:
+            continue                      # emit the stage at its last node
+        inside = set(members[st])
+        variables, ext = [], []
+        for p in members[st]:
+            for (n2, i2) in topo[p].inputs:
+                p2 = node_pos[id(n2)]
+                if p2 in inside or (p2, i2) in ext:
+                    continue
+                if n2.op is None:
+                    variables.append(p2)
+                elif p2 not in emitted:
+                    raise MXNetError(
+                        f"mirror_stage {st!r}: node {topo[p].name!r} "
+                        f"reads {n2.name!r}, which depends on the "
+                        "stage's own nodes")
+                ext.append((p2, i2))
+        produced = sorted(e for e, by in readers.items()
+                          if e[0] in inside and by - inside)
+        units.append((variables, members[st], ext, produced))
+        emitted.update(inside)
+    return units
 
 
 class _SegmentedRunner:

@@ -1,0 +1,277 @@
+"""A decoder language model assembled from a configuration's keys.
+
+One pre-norm block, `x <- x + mixer(RMSNorm(x))`, `x <- x + mlp(RMSNorm(x))`,
+whose mixer and MLP kinds are read per layer from the published keys of a
+hybrid model (ROADMAP R0): `linear_attn_config.kda_layers` /
+`full_attn_layers` (1-based, as config.json counts them) pick Kimi Delta
+Attention or multi-head latent attention, `first_k_dense_replace` picks the
+dense SwiGLU or the mixture of experts. First user: Kimi-Linear-48B-A3B
+(arXiv:2510.26692). `TransformerEncoder` (transformer.py) is the older,
+hard-wired block and stays as it is.
+
+A chip's share of a deployment: `experts_held = (first, n)` makes every
+mixture layer route over all `num_experts` and compute the terms of the n
+experts it holds; `vocab_size` is the slice of the vocabulary held here.
+
+Parameters are named `<prefix>l<i>_<name>`; matrices are (out, in), the
+experts (E, in, out) as `ops/lm.py` multiplies them (down: in is the
+expert's width). Weights default to normal(0, 0.02), norm scales to 1,
+`A_log`, `dt_bias` and the router's bias to 0. Under a Symbol every block
+tags its nodes with `profiler_scope` (the executor turns it into a `jax.named_scope`, so the
+profiler's operation metadata names the mechanism, forward and backward)
+and each layer with a `mirror_stage` of its own, the unit
+`MXNET_BACKWARD_DO_MIRROR` rematerialises.
+"""
+from __future__ import annotations
+
+from ... import initializer
+from ...base import AttrScope
+from ..block import HybridBlock
+
+__all__ = ["KDAMixer", "MLAMixer", "SwiGLU", "MoEMLP", "DecoderBlock",
+           "DecoderLM"]
+
+
+def _dense(F, x, weight, units):
+    return F.FullyConnected(x, weight, num_hidden=units, no_bias=True,
+                            flatten=False)
+
+
+def _getter(block):
+    """params.get with this file's defaults: `init` None is normal(0,
+    0.02), else a registered name ("ones", "zeros")."""
+    def get(name, shape, init=None):
+        return block.params.get(
+            name, shape=shape, init=init or initializer.Normal(0.02))
+    return get
+
+
+def _silu(F, x):
+    return x * F.sigmoid(x)
+
+
+def _scope(name):
+    return AttrScope(profiler_scope=name)
+
+
+class KDAMixer(HybridBlock):
+    """Kimi Delta Attention: gated delta-rule linear attention with short
+    convolutions, a low-rank per-channel decay and a low-rank output gate."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        lin = cfg["linear_attn_config"]
+        d = cfg["hidden_size"]
+        self._h, self._dk = lin["num_heads"], lin["head_dim"]
+        self._kw = lin["short_conv_kernel_size"]
+        self._d, self._eps = d, cfg["rms_norm_eps"]
+        c, low = self._h * self._dk, self._dk
+        get = _getter(self)
+        for n in ("q", "k", "v"):
+            setattr(self, f"w{n}", get(f"w{n}", shape=(c, d)))
+            setattr(self, f"conv_{n}", get(f"conv_{n}", shape=(c, self._kw)))
+        self.w_fa = get("w_fa", shape=(low, d))
+        self.w_fb = get("w_fb", shape=(c, low))
+        self.A_log = get("A_log", shape=(self._h,), init="zeros")
+        self.dt_bias = get("dt_bias", shape=(c,), init="zeros")
+        self.w_b = get("w_b", shape=(self._h, d))
+        self.w_ga = get("w_ga", shape=(low, d))
+        self.w_gb = get("w_gb", shape=(c, low))
+        self.o_norm = get("o_norm", shape=(self._dk,), init="ones")
+        self.wo = get("wo", shape=(d, c))
+
+    def hybrid_forward(self, F, x, wq, wk, wv, conv_q, conv_k, conv_v, w_fa,
+                       w_fb, A_log, dt_bias, w_b, w_ga, w_gb, o_norm, wo):
+        h, dk = self._h, self._dk
+        c, low = h * dk, dk
+        with _scope("mx.kda"):
+            o = F._contrib_kda(
+                _dense(F, x, wq, c), _dense(F, x, wk, c), _dense(F, x, wv, c),
+                _dense(F, _dense(F, x, w_fa, low), w_fb, c),
+                _dense(F, x, w_b, h), conv_q, conv_k, conv_v, A_log, dt_bias,
+                num_heads=h, kernel=self._kw)
+            gate = F.sigmoid(_dense(F, _dense(F, x, w_ga, low), w_gb, c))
+            o = F.RMSNorm(F.reshape(o, shape=(0, 0, h, dk)), o_norm,
+                          eps=self._eps)
+            o = F.reshape(o, shape=(0, 0, -1)) * gate
+            return _dense(F, o, wo, self._d)
+
+
+class MLAMixer(HybridBlock):
+    """Multi-head latent attention without positional rotation
+    (`mla_use_nope`): keys and values come up from a shared latent of
+    `kv_lora_rank`; the `qk_rope_head_dim` extra key dims are shared by all
+    heads. Query/key heads of nope + rope dims, value heads of v dims."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        if cfg.get("q_lora_rank") is not None:
+            raise NotImplementedError("MLAMixer: q_lora_rank is not null")
+        d = cfg["hidden_size"]
+        self._h = cfg["num_attention_heads"]
+        self._dn, self._dp, self._dv = (cfg["qk_nope_head_dim"],
+                                        cfg["qk_rope_head_dim"],
+                                        cfg["v_head_dim"])
+        self._r, self._d, self._eps = (cfg["kv_lora_rank"], d,
+                                       cfg["rms_norm_eps"])
+        h, get = self._h, _getter(self)
+        self.wq = get("wq", shape=(h * (self._dn + self._dp), d))
+        self.w_kva = get("w_kva", shape=(self._r + self._dp, d))
+        self.kv_norm = get("kv_norm", shape=(self._r,), init="ones")
+        self.w_kvb = get("w_kvb", shape=(h * (self._dn + self._dv), self._r))
+        self.wo = get("wo", shape=(d, h * self._dv))
+
+    def hybrid_forward(self, F, x, wq, w_kva, kv_norm, w_kvb, wo):
+        h, dn, dp, dv, r = self._h, self._dn, self._dp, self._dv, self._r
+
+        def heads(t, width):            # (B, S, H*w) -> (B, H, S, w)
+            return F.transpose(F.reshape(t, shape=(0, 0, h, width)),
+                               axes=(0, 2, 1, 3))
+
+        with _scope("mx.mla"):
+            q = heads(_dense(F, x, wq, h * (dn + dp)), dn + dp)
+            kva = _dense(F, x, w_kva, r + dp)
+            c_kv = F.slice_axis(kva, axis=-1, begin=0, end=r)
+            k_pe = F.slice_axis(kva, axis=-1, begin=r, end=r + dp)
+            kvb = heads(_dense(F, F.RMSNorm(c_kv, kv_norm, eps=self._eps),
+                               w_kvb, h * (dn + dv)), dn + dv)
+            k_pe = F.broadcast_axis(F.expand_dims(k_pe, axis=1), axis=1,
+                                    size=h)
+            k = F.concat(F.slice_axis(kvb, axis=-1, begin=0, end=dn), k_pe,
+                         dim=3)
+            v = F.slice_axis(kvb, axis=-1, begin=dn, end=dn + dv)
+            o = F._contrib_flash_attention(q, k, v, causal=True,
+                                           scale=(dn + dp) ** -0.5)
+            o = F.reshape(F.transpose(o, axes=(0, 2, 1, 3)),
+                          shape=(0, 0, -1))
+            return _dense(F, o, wo, self._d)
+
+
+class SwiGLU(HybridBlock):
+    """down(SiLU(gate x) * up x); `names` are the three parameters' names."""
+
+    def __init__(self, units, hidden, names=("w_gate", "w_up", "w_down"),
+                 **kwargs):
+        super().__init__(**kwargs)
+        self._units, self._hidden = units, hidden
+        get = _getter(self)
+        self.w_gate = get(names[0], shape=(hidden, units))
+        self.w_up = get(names[1], shape=(hidden, units))
+        self.w_down = get(names[2], shape=(units, hidden))
+
+    def hybrid_forward(self, F, x, w_gate, w_up, w_down):
+        hid = _silu(F, _dense(F, x, w_gate, self._hidden)) * \
+            _dense(F, x, w_up, self._hidden)
+        return _dense(F, hid, w_down, self._units)
+
+
+class MoEMLP(HybridBlock):
+    """Sigmoid-routed mixture: the experts held here (grouped products over
+    the token-expert pairs that fall on them) plus the shared expert, whole.
+    Returns (y, stats); stats as `ops/lm.py::moe_experts` gives them."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        d, w = cfg["hidden_size"], cfg["moe_intermediate_size"]
+        first, n = cfg["experts_held"]
+        self._attrs = dict(
+            num_experts=cfg["num_experts"], num_held=n, first_expert=first,
+            hidden_size=w, top_k=cfg["num_experts_per_token"],
+            scaling=cfg["routed_scaling_factor"],
+            renormalize=bool(cfg["moe_renormalize"]))
+        get = _getter(self)
+        self.w_r = get("w_r", shape=(cfg["num_experts"], d))
+        self.r_bias = get("r_bias", shape=(cfg["num_experts"],), init="zeros")
+        self.e_gate = get("e_gate", shape=(n, d, w))
+        self.e_up = get("e_up", shape=(n, d, w))
+        self.e_down = get("e_down", shape=(n, w, d))
+        with self.name_scope():
+            self.shared = SwiGLU(d, w * cfg["num_shared_experts"],
+                                 names=("s_gate", "s_up", "s_down"),
+                                 prefix="")
+
+    def hybrid_forward(self, F, x, w_r, r_bias, e_gate, e_up, e_down):
+        # the op names its two halves mx.moe.route and mx.moe.experts
+        routed = F._contrib_moe_experts(x, w_r, r_bias, e_gate, e_up,
+                                        e_down, **self._attrs)
+        with _scope("mx.moe.shared"):
+            y = routed[0] + self.shared(x)
+        return y, routed[1]
+
+
+class DecoderBlock(HybridBlock):
+    """One pre-norm layer; `layer` is its published 1-based number."""
+
+    def __init__(self, cfg, layer, **kwargs):
+        super().__init__(**kwargs)
+        lin = cfg["linear_attn_config"]
+        if layer in lin["kda_layers"]:
+            mixer = KDAMixer
+        elif layer in lin["full_attn_layers"]:
+            mixer = MLAMixer
+        else:
+            raise ValueError(f"layer {layer} is in neither kda_layers nor "
+                             "full_attn_layers")
+        d = cfg["hidden_size"]
+        self._eps = cfg["rms_norm_eps"]
+        self._moe = layer > cfg["first_k_dense_replace"]
+        get = _getter(self)
+        self.norm1 = get("norm1", shape=(d,), init="ones")
+        self.norm2 = get("norm2", shape=(d,), init="ones")
+        with self.name_scope():
+            self.mixer = mixer(cfg, prefix="")
+            self.mlp = MoEMLP(cfg, prefix="") if self._moe else \
+                SwiGLU(d, cfg["intermediate_size"], prefix="")
+
+    def hybrid_forward(self, F, x, norm1, norm2):
+        # one stage a layer: its backward recomputes it from the stream
+        with AttrScope(mirror_stage=self.prefix + "layer"):
+            x = x + self.mixer(F.RMSNorm(x, norm1, eps=self._eps))
+            h = F.RMSNorm(x, norm2, eps=self._eps)
+            if self._moe:
+                y, stats = self.mlp(h)
+                return x + y, stats
+            with _scope("mx.mlp"):
+                return x + self.mlp(h), None
+
+
+class DecoderLM(HybridBlock):
+    """Embedding, `num_hidden_layers` blocks (published layers 1..n), final
+    RMSNorm and an untied head. `net(tokens)` gives the logits;
+    `net(tokens, labels)` gives the (batch, sequence) cross-entropy of each
+    position and, where there are mixture layers, their stacked stats
+    (layers, experts held + 3) as a second output."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        d, v = cfg["hidden_size"], cfg["vocab_size"]
+        self._d, self._v, self._eps = d, v, cfg["rms_norm_eps"]
+        get = _getter(self)
+        self.embed = get("embed", shape=(v, d))
+        self.norm_f = get("norm_f", shape=(d,), init="ones")
+        self.head = get("head", shape=(v, d))
+        self.blocks = []
+        with self.name_scope():
+            for i in range(cfg["num_hidden_layers"]):
+                block = DecoderBlock(cfg, i + 1, prefix=f"l{i}_")
+                self.register_child(block)
+                self.blocks.append(block)
+
+    def hybrid_forward(self, F, tokens, labels=None, embed=None,
+                       norm_f=None, head=None):
+        x = F.Embedding(tokens, embed, input_dim=self._v, output_dim=self._d)
+        stats = []
+        for block in self.blocks:
+            x, s = block(x)
+            if s is not None:
+                stats.append(F.expand_dims(s, axis=0))
+        with _scope("mx.lm_head"), AttrScope(mirror_stage="head"):
+            x = F.RMSNorm(x, norm_f, eps=self._eps)
+            if labels is None:
+                return _dense(F, x, head, self._v)
+            loss = F._contrib_lm_head_ce(x, head, labels,
+                                         num_classes=self._v)
+        if not stats:
+            return loss
+        stats = stats[0] if len(stats) == 1 else F.concat(*stats, dim=0)
+        return loss, F.BlockGrad(stats)

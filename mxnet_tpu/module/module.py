@@ -9,6 +9,7 @@ batch-sharded inputs, psum-fused gradients — instead of executor replicas.
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import warnings
 
@@ -225,6 +226,32 @@ class Module(BaseModule):
                             for n in self._aux_names}
         self._params_dirty = False
 
+    def _params_to_host(self):
+        """Move what the executor holds per parameter (the parameter, its
+        gradient array, the aux states) to the host, in place, and this
+        module's copies with them. Returns [(array, where it lived)] for
+        `_params_to_devices`. The fused fit trains on the trainer's state:
+        meanwhile these would only fill the device."""
+        import jax
+        ex = self._exec
+        arrays = [a for a in itertools.chain(
+            (ex.arg_dict[n] for n in self._param_names),
+            (ex.grad_dict.get(n) for n in self._param_names),
+            (ex.aux_dict[n] for n in self._aux_names)) if a is not None]
+        homes = [(a, a._data.sharding) for a in arrays]
+        host = jax.devices("cpu")[0]
+        for a in arrays:
+            a._rebind(jax.device_put(a._data, host))
+        self._sync_params_from_devices()
+        return homes
+
+    def _params_to_devices(self, homes):
+        """Undo `_params_to_host`, with the values the arrays hold now."""
+        import jax
+        for a, sharding in homes:
+            a._rebind(jax.device_put(a._data, sharding))
+        self._sync_params_from_devices()
+
     # -- binding -------------------------------------------------------------
     @staticmethod
     def _norm_shapes(shapes):
@@ -348,7 +375,6 @@ class Module(BaseModule):
         Returns False (with a warning) when the config can't fuse —
         BaseModule.fit then runs the per-batch path."""
         import time
-        import itertools
         import numpy as np
         from ..parallel.dp import DataParallelTrainer, _OPT_OPS
         from ..parallel.mesh import mesh_for_contexts
@@ -465,99 +491,105 @@ class Module(BaseModule):
                 **opt_params)
         shape_kwargs = {d.name: d.shape for d in
                         self._data_shapes + (self._label_shapes or [])}
-        with _tracing.span("fit.init_state"):
-            params, states, aux = trainer.init_state(
-                shape_kwargs, arg_params=self._arg_params,
-                aux_params=self._aux_params)
-
-        gstep = 0
-        ckpt_skip = 0
-        if ckpt_state is not None:
-            if ckpt_state.meta.get("kind") == "module_fused" and \
-                    ckpt_state.meta.get("trainer") is not None:
-                # full fused-loop state: opt-state arrays + device t/rng/
-                # loss-scaler carries — the continuation is bit-identical
-                # (import device_puts the reassembled host arrays onto
-                # THIS run's mesh, so an elastic restore at a different
-                # device count reshards here)
-                params, states, aux = trainer.import_training_state(
-                    ckpt_state.arrays, ckpt_state.meta["trainer"])
-            else:
-                self.logger.warning(
-                    "checkpoint: snapshot kind=%r has no fused-trainer "
-                    "state; params restored, optimizer state starts "
-                    "fresh", ckpt_state.meta.get("kind"))
-            from .. import random as _random
-            if ckpt_state.meta.get("rng") is not None:
-                _random.set_state(ckpt_state.meta["rng"])
-            gstep = int(ckpt_state.meta.get("step", 0))
-            from ..checkpoint.state import rescale_cursor
-            ckpt_skip = rescale_cursor(ckpt_state.meta, batch_size)
-            saved_topo = ckpt_state.meta.get("topology") or {}
-            if saved_topo.get("device_count") is not None:
-                import jax
-                cur = int(jax.device_count())
-                if int(saved_topo["device_count"]) != cur:
-                    self.logger.info(
-                        "checkpoint: topology changed since save "
-                        "(%s -> %d devices); state resharded onto the "
-                        "current mesh", saved_topo["device_count"], cur)
-        if ckpt_mgr is not None:
-            ckpt_mgr.install_sigterm_hook()
-
-        from ..base import to_numpy as _np_of
-        from ..pipeline import feed_or_inline, close_feed, BlockStager
-        from ..telemetry import maybe_step_logger
-        slog = maybe_step_logger("module_fit_fused", meta={
-            "optimizer": optimizer, "steps_per_dispatch": int(k),
-            "batch_size": int(batch_size), "begin_epoch": begin_epoch,
-            "num_epoch": num_epoch,
-            "amp_dtype": fit_dtype if fit_dtype != "float32" else None})
-        data_idx = {n: i for i, n in enumerate(self._data_names)}
-        label_idx = {n: i for i, n in enumerate(self._label_names)}
-
-        def _blocks(data_iter):
-            while True:
-                block = list(itertools.islice(data_iter, k))
-                if not block:
-                    return
-                yield block
-
-        stager = BlockStager(trainer.shard_inputs)
-
-        def _stage_block(block):
-            # host stack + device commit run on the feeder thread: block
-            # N+1 is staged while block N's fused scan executes. The
-            # stager copies into host buffers of its own before it
-            # returns, so iterator buffer reuse is safe; a short tail
-            # block compiles its own (cached) k'-step scan
-            columns = [
-                [_np_of(b.data[data_idx[name]]) if name in data_idx
-                 else _np_of(b.label[label_idx[name]]) for b in block]
-                for name in trainer.input_names]
-            inputs = stager(columns, stacked=True)
-            labels = {
-                name: np.concatenate([_np_of(b.label[i]) for b in block])
-                for name, i in label_idx.items()}
-            return inputs, labels, len(block)
-
-        def _ckpt_capture(next_epoch, next_batch):
-            # synchronous snapshot of the (donated) device tuples — must
-            # happen between dispatches; the atomic write itself still
-            # overlaps the following steps on the saver thread
-            from ..checkpoint.state import TrainingState
-            from .. import random as _random
-            arrays, tmeta = trainer.export_training_state(params, states,
-                                                          aux)
-            return TrainingState(arrays=arrays, meta={
-                "kind": "module_fused", "epoch": int(next_epoch),
-                "batch": int(next_batch), "step": int(gstep),
-                "batch_size": int(batch_size),
-                "trainer": tmeta, "rng": _random.get_state(),
-                "amp_dtype": fit_dtype if fit_dtype != "float32"
-                else None})
-
+        homes, slog = None, None
         try:
+            with _tracing.span("fit.init_state"):
+                # the trainer's state does the training: what this module
+                # holds on the device for its executor (parameters, their
+                # gradient arrays, the module's own copies: three times the
+                # parameters' bytes, fp32) waits on the host meanwhile
+                homes = self._params_to_host()
+                params, states, aux = trainer.init_state(
+                    shape_kwargs, arg_params=self._arg_params,
+                    aux_params=self._aux_params)
+
+            gstep = 0
+            ckpt_skip = 0
+            if ckpt_state is not None:
+                if ckpt_state.meta.get("kind") == "module_fused" and \
+                        ckpt_state.meta.get("trainer") is not None:
+                    # full fused-loop state: opt-state arrays + device t/rng/
+                    # loss-scaler carries — the continuation is bit-identical
+                    # (import device_puts the reassembled host arrays onto
+                    # THIS run's mesh, so an elastic restore at a different
+                    # device count reshards here)
+                    params, states, aux = trainer.import_training_state(
+                        ckpt_state.arrays, ckpt_state.meta["trainer"])
+                else:
+                    self.logger.warning(
+                        "checkpoint: snapshot kind=%r has no fused-trainer "
+                        "state; params restored, optimizer state starts "
+                        "fresh", ckpt_state.meta.get("kind"))
+                from .. import random as _random
+                if ckpt_state.meta.get("rng") is not None:
+                    _random.set_state(ckpt_state.meta["rng"])
+                gstep = int(ckpt_state.meta.get("step", 0))
+                from ..checkpoint.state import rescale_cursor
+                ckpt_skip = rescale_cursor(ckpt_state.meta, batch_size)
+                saved_topo = ckpt_state.meta.get("topology") or {}
+                if saved_topo.get("device_count") is not None:
+                    import jax
+                    cur = int(jax.device_count())
+                    if int(saved_topo["device_count"]) != cur:
+                        self.logger.info(
+                            "checkpoint: topology changed since save "
+                            "(%s -> %d devices); state resharded onto the "
+                            "current mesh", saved_topo["device_count"], cur)
+            if ckpt_mgr is not None:
+                ckpt_mgr.install_sigterm_hook()
+
+            from ..base import to_numpy as _np_of
+            from ..pipeline import feed_or_inline, close_feed, BlockStager
+            from ..telemetry import maybe_step_logger
+            slog = maybe_step_logger("module_fit_fused", meta={
+                "optimizer": optimizer, "steps_per_dispatch": int(k),
+                "batch_size": int(batch_size), "begin_epoch": begin_epoch,
+                "num_epoch": num_epoch,
+                "amp_dtype": fit_dtype if fit_dtype != "float32" else None})
+            data_idx = {n: i for i, n in enumerate(self._data_names)}
+            label_idx = {n: i for i, n in enumerate(self._label_names)}
+
+            def _blocks(data_iter):
+                while True:
+                    block = list(itertools.islice(data_iter, k))
+                    if not block:
+                        return
+                    yield block
+
+            stager = BlockStager(trainer.shard_inputs)
+
+            def _stage_block(block):
+                # host stack + device commit run on the feeder thread: block
+                # N+1 is staged while block N's fused scan executes. The
+                # stager copies into host buffers of its own before it
+                # returns, so iterator buffer reuse is safe; a short tail
+                # block compiles its own (cached) k'-step scan
+                columns = [
+                    [_np_of(b.data[data_idx[name]]) if name in data_idx
+                     else _np_of(b.label[label_idx[name]]) for b in block]
+                    for name in trainer.input_names]
+                inputs = stager(columns, stacked=True)
+                labels = {
+                    name: np.concatenate([_np_of(b.label[i]) for b in block])
+                    for name, i in label_idx.items()}
+                return inputs, labels, len(block)
+
+            def _ckpt_capture(next_epoch, next_batch):
+                # synchronous snapshot of the (donated) device tuples — must
+                # happen between dispatches; the atomic write itself still
+                # overlaps the following steps on the saver thread
+                from ..checkpoint.state import TrainingState
+                from .. import random as _random
+                arrays, tmeta = trainer.export_training_state(params, states,
+                                                              aux)
+                return TrainingState(arrays=arrays, meta={
+                    "kind": "module_fused", "epoch": int(next_epoch),
+                    "batch": int(next_batch), "step": int(gstep),
+                    "batch_size": int(batch_size),
+                    "trainer": tmeta, "rng": _random.get_state(),
+                    "amp_dtype": fit_dtype if fit_dtype != "float32"
+                    else None})
+
             for epoch in range(begin_epoch, num_epoch):
                 epoch_start = time.time()
                 eval_metric.reset()
@@ -673,6 +705,8 @@ class Module(BaseModule):
                         raise SystemExit(143)
 
                 if eval_data is not None:
+                    # score runs the executor: its arrays go back for it
+                    self._params_to_devices(homes)
                     for name, val in self.score(
                             eval_data, validation_metric,
                             score_end_callback=eval_end_callback,
@@ -680,16 +714,25 @@ class Module(BaseModule):
                             epoch=epoch):
                         self.logger.info("Epoch[%d] Validation-%s=%f",
                                          epoch, name, val)
+                    homes = self._params_to_host()
                 train_data.reset()
         finally:
+            # whatever ended the fit, the module holds its arrays where it
+            # held them before, with the last values written back; the
+            # trainer's state goes first, so that the two need not fit the
+            # device together (a callback that kept `locals` keeps it)
+            params = states = aux = cb_param = None
+            if homes is not None:
+                self._params_to_devices(homes)
             # run_end carries the step program's XLA cost digest (which
             # program the per-step MFU was measured against, its
             # FLOPs/bytes per step, the peak table in force)
             from ..telemetry import devstats as _devstats
-            try:
-                slog.close(**_devstats.fit_summary())
-            except Exception:
-                slog.close()
+            if slog is not None:
+                try:
+                    slog.close(**_devstats.fit_summary())
+                except Exception:
+                    slog.close()
             if ckpt_mgr is not None:
                 ckpt_mgr.remove_sigterm_hook()
                 ckpt_mgr.close()

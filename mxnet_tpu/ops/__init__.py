@@ -9,5 +9,6 @@ from . import contrib  # noqa: F401
 from . import quantization  # noqa: F401
 from . import extra  # noqa: F401
 from . import attention  # noqa: F401
+from . import lm  # noqa: F401
 
 from .registry import get_op, list_ops  # noqa: F401

@@ -91,7 +91,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_len,
     q_start = pl.program_id(1) * q_block
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (q_block, 1), 0)
 
-    acc0 = jnp.zeros((q_block, q.shape[1]), jnp.float32)
+    acc0 = jnp.zeros((q_block, v_ref.shape[1]), jnp.float32)
     m0 = jnp.full((q_block, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((q_block, 1), jnp.float32)
     n_blocks = seq_len // block_k
@@ -243,8 +243,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)          # (Bk, D)
         return dk_acc, dv_acc
 
-    z = jnp.zeros(k.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(first_block, n_blocks, body, (z, z))
+    dk, dv = jax.lax.fori_loop(
+        first_block, n_blocks, body,
+        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
@@ -296,13 +297,15 @@ def _kv_index_map(h, h_kv):
 
 def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
                   block_k=None):
-    """Forward kernel. q (B, H, S, D), k/v (B, H_kv, S, D) with
-    H % H_kv == 0 (GQA/MQA share kv blocks in-kernel), S % block == 0 and
-    D % 128 == 0 (or 64). Returns (out (B,H,S,D), lse (B*H, S, 8) f32 —
+    """Forward kernel. q, k (B, H | H_kv, S, D) and v (B, H_kv, S, Dv)
+    with H % H_kv == 0 (GQA/MQA share kv blocks in-kernel), S % block == 0
+    and D, Dv as _pallas_eligible takes them (Dv may differ from D: latent
+    attention's 192/128). Returns (out (B,H,S,Dv), lse (B*H, S, 8) f32 —
     the row statistic lane-replicated for TPU block tiling)."""
     import jax.experimental.pallas as pl
 
     b, h, s, d = q.shape
+    d_v = v.shape[-1]
     h_kv = k.shape[1]
     block_q = min(block_q or _auto_block(s), s)
     block_k = min(block_k or _auto_block(s), s)
@@ -313,7 +316,7 @@ def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
                          f"blocks ({block_q}, {block_k})")
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * h_kv, s, d)
-    vf = v.reshape(b * h_kv, s, d)
+    vf = v.reshape(b * h_kv, s, d_v)
     kv_map = _kv_index_map(h, h_kv)
     kernel = functools.partial(_flash_kernel, block_k=block_k, seq_len=s,
                                causal=causal, scale=scale)
@@ -323,21 +326,22 @@ def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((None, s, d), kv_map),
-            pl.BlockSpec((None, s, d), kv_map),
+            pl.BlockSpec((None, s, d_v), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((None, block_q, d_v), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((None, block_q, _LSE_LANES),
                          lambda bh, qi: (bh, qi, 0)),
         ],
         out_shape=[
-            _sds((b * h, s, d), q.dtype, q),
+            _sds((b * h, s, d_v), q.dtype, q),
             _sds((b * h, s, _LSE_LANES), jnp.float32, q),
         ],
         interpret=interpret,
-        **_vmem_params(s, d, 2, interpret, q.dtype.itemsize),
+        name="mx_flash_attention_fwd",
+        **_vmem_params(s, max(d, d_v), 2, interpret, q.dtype.itemsize),
     )(qf, kf, vf)
-    return out.reshape(b, h, s, d), lse
+    return out.reshape(b, h, s, d_v), lse
 
 
 def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
@@ -355,6 +359,7 @@ def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
     import jax.experimental.pallas as pl
 
     b, h, s, d = q.shape
+    d_v = v.shape[-1]
     h_kv = k.shape[1]
     kv_map = _kv_index_map(h, h_kv)
     block_q = min(block_q or _auto_block(s), s)
@@ -364,9 +369,9 @@ def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
                          f"by blocks ({block_q}, {block_k})")
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * h_kv, s, d)
-    vf = v.reshape(b * h_kv, s, d)
-    dof = g.reshape(b * h, s, d)
-    of = o.reshape(b * h, s, d)
+    vf = v.reshape(b * h_kv, s, d_v)
+    dof = g.reshape(b * h, s, d_v)
+    of = o.reshape(b * h, s, d_v)
     have_glse = g_lse is not None
     if have_glse:
         # the masked-row lse can be +/-inf sentinels; 0*inf would NaN, so
@@ -387,8 +392,12 @@ def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
             lambda *refs, k: k(*refs[:n_lead], None, *refs[n_lead:]),
             k=kernel)
 
-    full_spec = pl.BlockSpec((None, s, d), lambda bh, i: (bh, 0, 0))
-    kv_full = pl.BlockSpec((None, s, d), kv_map)
+    q_full = pl.BlockSpec((None, s, d), lambda bh, i: (bh, 0, 0))
+    o_full = pl.BlockSpec((None, s, d_v), lambda bh, i: (bh, 0, 0))
+    k_full = pl.BlockSpec((None, s, d), kv_map)
+    v_full = pl.BlockSpec((None, s, d_v), kv_map)
+    q_blk = pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0))
+    o_blk = pl.BlockSpec((None, block_q, d_v), lambda bh, qi: (bh, qi, 0))
     lse_full = pl.BlockSpec((None, s, _LSE_LANES), lambda bh, i: (bh, 0, 0))
     lse_blk = pl.BlockSpec((None, block_q, _LSE_LANES),
                            lambda bh, qi: (bh, qi, 0))
@@ -399,61 +408,58 @@ def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
     dq = pl.pallas_call(
         dq_kernel,
         grid=(b * h, s // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            kv_full, kv_full,
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            lse_blk,
-        ] + ([lse_blk] if have_glse else []),
-        out_specs=pl.BlockSpec((None, block_q, d),
-                               lambda bh, qi: (bh, qi, 0)),
+        in_specs=[q_blk, k_full, v_full, o_blk, o_blk, lse_blk]
+        + ([lse_blk] if have_glse else []),
+        out_specs=q_blk,
         out_shape=_sds((b * h, s, d), q.dtype, q),
         interpret=interpret,
-        **_vmem_params(s, d, 2, interpret, q.dtype.itemsize),
+        name="mx_flash_attention_bwd_dq",
+        **_vmem_params(s, max(d, d_v), 2, interpret, q.dtype.itemsize),
     )(qf, kf, vf, dof, of, lse, *glse_args)
 
     if h == h_kv:
-        kv_blk = pl.BlockSpec((None, block_k, d),
-                              lambda bh, ki: (bh, ki, 0))
+        def kv_blk_map(bh, ki):
+            return (bh, ki, 0)
     else:
         group = h // h_kv
-        kv_blk = pl.BlockSpec(
-            (None, block_k, d),
-            lambda bh, ki: ((bh // h) * h_kv + (bh % h) // group, ki, 0))
+
+        def kv_blk_map(bh, ki):
+            return ((bh // h) * h_kv + (bh % h) // group, ki, 0)
+    k_blk = pl.BlockSpec((None, block_k, d), kv_blk_map)
+    v_blk = pl.BlockSpec((None, block_k, d_v), kv_blk_map)
     dkv_kernel = _with_optional_glse(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
                           seq_len=s, causal=causal, scale=scale), 6)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(b * h, s // block_k),
-        in_specs=[
-            full_spec, kv_blk, kv_blk,
-            full_spec, full_spec, lse_full,
-        ] + ([lse_full] if have_glse else []),
+        in_specs=[q_full, k_blk, v_blk, o_full, o_full, lse_full]
+        + ([lse_full] if have_glse else []),
         out_specs=[
             pl.BlockSpec((None, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((None, block_k, d_v), lambda bh, ki: (bh, ki, 0)),
         ],
         out_shape=[
             _sds((b * h, s, d), k.dtype, q),
-            _sds((b * h, s, d), v.dtype, q),
+            _sds((b * h, s, d_v), v.dtype, q),
         ],
         interpret=interpret,
-        **_vmem_params(s, d, 3, interpret, q.dtype.itemsize),
+        name="mx_flash_attention_bwd_dkv",
+        **_vmem_params(s, max(d, d_v), 3, interpret, q.dtype.itemsize),
     )(qf, kf, vf, dof, of, lse, *glse_args)
 
     dq = dq.reshape(b, h, s, d)
     dk = dk.reshape(b, h, s, d)
-    dv = dv.reshape(b, h, s, d)
+    dv = dv.reshape(b, h, s, d_v)
     if h != h_kv:
         group = h // h_kv
         dk = dk.reshape(b, h_kv, group, s, d).sum(2).astype(k.dtype)
-        dv = dv.reshape(b, h_kv, group, s, d).sum(2).astype(v.dtype)
+        dv = dv.reshape(b, h_kv, group, s, d_v).sum(2).astype(v.dtype)
     return dq, dk, dv
 
 
-def _pallas_eligible(q, k, platform=None, block_q=None, block_k=None):
+def _pallas_eligible(q, k, platform=None, block_q=None, block_k=None,
+                     v=None):
     b, h, s, d = q.shape
     if k.shape != q.shape:
         # GQA/MQA (fewer kv heads, same seq) stays kernel-eligible; true
@@ -461,7 +467,11 @@ def _pallas_eligible(q, k, platform=None, block_q=None, block_k=None):
         if k.shape[0] != b or k.shape[2] != s or k.shape[3] != d \
                 or k.shape[1] == 0 or h % k.shape[1] != 0:
             return False
-    if d % 128 != 0 and d not in (64,):
+    if v is not None and v.shape[:3] != k.shape[:3]:
+        return False
+    # the query/key width and the value width may differ (latent
+    # attention: 192 and 128); each a multiple of 64
+    if d % 64 != 0 or (v is not None and v.shape[3] % 64 != 0):
         return False
     if s % min(block_q or _auto_block(s), s) != 0 or \
             s % min(block_k or _auto_block(s), s) != 0:
@@ -567,10 +577,30 @@ def flash_attention(q, k, v, causal=False, scale=None, force=None,
                                        block_k=block_k)
     if force == "pallas" or (force is None and
                              _pallas_eligible(q, k, platform, block_q,
-                                              block_k)):
+                                              block_k, v=v)):
         return _flash_pallas_trainable(q, k, v, causal, scale,
                                        block_q=block_q, block_k=block_k)
+    if force is None and (platform or jax.default_backend()) == "tpu":
+        _count_dense_fallback(q, k, v)
     return reference_attention(q, k, v, causal, scale)
+
+
+DENSE_FALLBACK_COUNTER = "attention_dense_fallback_total"
+
+
+def _count_dense_fallback(q, k, v):
+    """A program for a TPU took the dense path, which materialises the
+    (S, S) scores: counted once per trace in the telemetry registry and
+    logged, so that a shape the kernel cannot take is seen, not guessed."""
+    import logging
+    from ..telemetry import registry
+    registry.counter(
+        DENSE_FALLBACK_COUNTER,
+        help="attention calls traced for a TPU whose shapes the flash "
+             "kernel does not take (dense S x S scores instead)").inc()
+    logging.getLogger(__name__).warning(
+        "flash_attention: q %s k %s v %s not eligible for the TPU kernel; "
+        "dense (S, S) scores", q.shape, k.shape, v.shape)
 
 
 # -- decode mode (q_len = 1 against a KV cache) -----------------------------
@@ -723,14 +753,18 @@ def decode_attention(q, k, v, lengths, scale=None, force=None,
 # -- registry surface -------------------------------------------------------
 
 def _flash_attention_op(attrs, octx, q, k, v):
-    return _t(flash_attention(q, k, v, causal=attrs["causal"],
-                              scale=attrs["scale"],
-                              platform=octx.platform))
+    with jax.named_scope("mx.flash_attention"):
+        return _t(flash_attention(q, k, v, causal=attrs["causal"],
+                                  scale=attrs["scale"],
+                                  platform=octx.platform))
 
 
 register("_contrib_flash_attention", _flash_attention_op,
          params={"causal": Param("bool", False),
                  "scale": Param("float", None)},
          inputs=("query", "key", "value"),
-         infer_shape=lambda attrs, s: (s, [s[0]]))
+         # the output has the value's width (latent attention: 192 / 128)
+         infer_shape=lambda attrs, s: (s, [
+             None if s[0] is None or s[2] is None
+             else tuple(s[0][:-1]) + (s[2][-1],)]))
 

@@ -1,0 +1,492 @@
+"""Operators of decoder language models with hybrid mixers and sparse MLPs.
+
+TPU-first new surface (the reference has none of these): `RMSNorm`, the
+Kimi Delta Attention mixer core `_contrib_kda` (short convolutions, decay
+and a chunkwise-parallel gated delta rule), the held-experts mixture
+`_contrib_moe_experts` and the fused head `_contrib_lm_head_ce` whose
+output is the per-token loss. Each manages its own precision (amp/policy.py
+lists them under MIXED): matrix products take the dtype their inputs
+arrive in and accumulate in float32; the decay, the chunk state, the
+norms' statistics, the router and the loss are float32 whatever arrives.
+
+The delta rule, per head, with S in R^(dk x dv) and g the log-decay:
+
+    S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_(t-1) + b_t k_t v_t^T
+    o_t = S_t^T q_t
+
+`kda_chunked` computes it a chunk of C tokens at a time (Yang et al. 2024,
+"Parallelizing linear transformers with the delta rule", extended to a
+per-channel decay as in the Kimi Linear report, arXiv:2510.26692): inside a
+chunk the corrections u_t = b_t (v_t - (Diag(exp g_t) S_(t-1))^T k_t) solve
+a unit lower-triangular system (I + A) U = b (V - (K * G) S_0), so that
+only S_0 -> S_C is sequential. Pairwise decays exp(G_i - G_j) are taken
+directly inside sub-blocks of `sub` tokens and through the sub-block's
+first row otherwise: every exponent is <= 0, nothing can overflow whatever
+the decay.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .registry import Param, register
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+
+
+def _t(*o):
+    return tuple(o)
+
+
+# -- RMSNorm ------------------------------------------------------------------
+
+def rms_norm(x, gamma, eps=1e-5):
+    """x * rsqrt(mean(x^2) + eps) * gamma over the last axis; statistics
+    in float32, result in x's dtype."""
+    xf = x.astype(_F32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps) * gamma.astype(_F32)).astype(
+        x.dtype)
+
+
+def _rms_norm_op(attrs, octx, data, gamma):
+    return _t(rms_norm(data, gamma, attrs["eps"]))
+
+
+def _rms_infer(attrs, in_shapes):
+    in_shapes = list(in_shapes)
+    if in_shapes[0] is not None and in_shapes[1] is None:
+        in_shapes[1] = (in_shapes[0][-1],)
+    return in_shapes, [in_shapes[0]]
+
+
+register("RMSNorm", _rms_norm_op, params={"eps": Param("float", 1e-5)},
+         inputs=("data", "gamma"), infer_shape=_rms_infer)
+
+
+# -- Kimi Delta Attention -------------------------------------------------------
+
+def short_conv_silu(x, w):
+    """SiLU of the causal depthwise convolution over time: x (B, S, C),
+    w (C, kw), zeros before the start."""
+    kw = w.shape[1]
+    s = x.shape[1]
+    pad = jnp.pad(x, ((0, 0), (kw - 1, 0), (0, 0)))
+    y = sum(pad[:, j:j + s, :] * w[:, j].astype(x.dtype) for j in range(kw))
+    return jax.nn.silu(y)
+
+
+def _l2_normalize(x, eps=1e-6):
+    xf = x.astype(_F32)
+    return xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), -1, keepdims=True)
+                              + eps)
+
+
+def _mm(a, b, spec, dtype):
+    """einsum with operands in `dtype` and a float32 result; float32
+    operands multiply at highest precision."""
+    prec = _HIGHEST if dtype == _F32 else None
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      precision=prec, preferred_element_type=_F32)
+
+
+def _pairwise(q, k, gc, beta, sub, dtype):
+    """For chunks (..., C, dk) with cumulative log-decay gc (float32):
+    bm[i, j] = sum_d q_i k_j exp(gc_i - gc_j) for j <= i, and
+    am[i, j] = beta_i sum_d k_i k_j exp(gc_i - gc_j) for j < i."""
+    c, dk = q.shape[-2], q.shape[-1]
+    n_sub = c // sub
+    lead = q.shape[:-2]
+    qs, ks, gs = (a.astype(_F32).reshape(lead + (n_sub, sub, dk))
+                  for a in (q, k, gc))
+    ref = gs[..., :1, :]                          # a sub-block's first row
+    row = jnp.exp(gs - ref)                       # <= 1
+    # columns as seen from sub-block I: exp(ref_I - gc_j), only used for
+    # j before I (exponent <= 0 there; clamped elsewhere, masked below)
+    col = jnp.exp(jnp.minimum(
+        ref - gc.astype(_F32)[..., None, :, :], 0.0))   # (.., n_sub, C, dk)
+    k_col = k.astype(_F32)[..., None, :, :] * col
+    off_b = _mm(qs * row, k_col, "...isd,...ijd->...isj", dtype)
+    off_a = _mm(ks * row, k_col, "...isd,...ijd->...isj", dtype)
+    sub_of = jnp.arange(c) // sub
+    before = (sub_of[None, :] < sub_of[:, None])            # (C, C)
+    off_b = off_b.reshape(lead + (c, c)) * before
+    off_a = off_a.reshape(lead + (c, c)) * before
+    # inside a sub-block: the decays of every pair, directly
+    e = jnp.exp(jnp.minimum(gs[..., :, None, :] - gs[..., None, :, :], 0.0))
+    kk = ks[..., None, :, :] * e                            # (.., s, s, dk)
+    d_b = jnp.sum(qs[..., :, None, :] * kk, -1)
+    d_a = jnp.sum(ks[..., :, None, :] * kk, -1)
+    tri = jnp.tril(jnp.ones((sub, sub), bool))
+    d_b = d_b * tri
+    d_a = d_a * jnp.tril(jnp.ones((sub, sub), bool), -1)
+    eye = jnp.eye(n_sub, dtype=_F32)
+
+    def block_diag(d):
+        full = d[..., :, :, None, :] * eye[:, None, :, None]
+        return full.reshape(lead + (c, c))
+
+    bm = off_b + block_diag(d_b)
+    am = (off_a + block_diag(d_a)) * beta.astype(_F32)[..., None]
+    return am, bm
+
+
+def _unit_lower_inverse(am):
+    """(I + am)^-1 for strictly lower-triangular am (..., C, C), float32:
+    the Neumann series of the nilpotent -am, by repeated squaring."""
+    c = am.shape[-1]
+    x = -am
+    t = jnp.eye(c, dtype=_F32) + x
+    p = x
+    n = 2
+    while n < c:
+        p = jnp.einsum("...ij,...jk->...ik", p, p, precision=_HIGHEST)
+        t = t + jnp.einsum("...ij,...jk->...ik", t, p, precision=_HIGHEST)
+        n *= 2
+    return t
+
+
+def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, segment=4):
+    """The gated delta rule, chunkwise. q, k, g (B, S, H, dk), v
+    (B, S, H, dv), beta (B, S, H); g float32 log-decay (<= 0). Products
+    take q's dtype and accumulate in float32; the state is float32.
+    Returns (B, S, H, dv) float32. `segment` chunks at a time go through
+    the pairwise stage, which bounds its temporaries."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    dtype = q.dtype
+    chunk = min(chunk, -(-s // sub) * sub)
+    sub = min(sub, chunk)
+    pad = -s % chunk
+    if pad:     # zeros change neither the state nor the kept outputs
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n = (s + pad) // chunk
+
+    def chunks(a):          # (B, S, H, d) -> (N, B, H, C, d)
+        a = a.reshape(b, n, chunk, h, -1)
+        return jnp.transpose(a, (1, 0, 3, 2, 4))
+
+    qc, kc, vc, gc = chunks(q), chunks(k), chunks(v), chunks(g.astype(_F32))
+    bc = chunks(beta[..., None])[..., 0].astype(_F32)      # (N, B, H, C)
+    gc = jnp.cumsum(gc, axis=-2)                           # inclusive
+    decay = jnp.exp(gc)                                    # <= 1
+    g_last = gc[..., -1:, :]
+
+    @jax.checkpoint     # or the map's backward keeps every pairwise decay
+    def stage1(args):
+        q_, k_, v_, gc_, b_, decay_ = args
+        am, bm = _pairwise(q_, k_, gc_, b_, sub, dtype)
+        t = _unit_lower_inverse(am)
+        kf = k_.astype(_F32)
+        w = _mm(t, kf * decay_ * b_[..., None], "...ij,...jd->...id", dtype)
+        u = _mm(t, v_.astype(_F32) * b_[..., None], "...ij,...jd->...id",
+                dtype)
+        # the scan below multiplies them in `dtype`: keep them so
+        return w.astype(dtype), u.astype(dtype), bm.astype(dtype)
+
+    seg = max(1, min(segment, n))
+    while n % seg:
+        seg -= 1
+    grouped = tuple(a.reshape((n // seg, seg) + a.shape[1:])
+                    for a in (qc, kc, vc, gc, bc, decay))
+    w, u, bm = jax.lax.map(stage1, grouped)
+    w, u, bm = (a.reshape((n,) + a.shape[2:]) for a in (w, u, bm))
+    q_dec = (qc.astype(_F32) * decay).astype(dtype)
+    k_rest = (kc.astype(_F32) * jnp.exp(g_last - gc)).astype(dtype)  # <= 1
+
+    def step(state, xs):
+        w_, u_, bm_, qd_, kr_, gl_ = xs
+        u_ = u_ - _mm(w_, state, "bhck,bhkv->bhcv", dtype)
+        o = _mm(qd_, state, "bhck,bhkv->bhcv", dtype) + \
+            _mm(bm_, u_, "bhij,bhjv->bhiv", dtype)
+        state = state * jnp.swapaxes(jnp.exp(gl_), -1, -2) + \
+            _mm(kr_, u_, "bhck,bhcv->bhkv", dtype)
+        return state, o
+
+    state0 = jnp.zeros((b, h, dk, dv), _F32)
+    _, o = jax.lax.scan(step, state0, (w, u, bm, q_dec, k_rest, g_last))
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, n * chunk, h, dv)
+    return o[:, :s]
+
+
+def kda_log_decay(f, a_log, dt_bias, num_heads):
+    """g = -exp(A_log[h]) * softplus(f + dt_bias), float32; f (B, S, H*dk)
+    -> (B, S, H, dk)."""
+    b, s, c = f.shape
+    x = f.astype(_F32) + dt_bias.astype(_F32)
+    g = -jnp.exp(a_log.astype(_F32))[:, None] * jax.nn.softplus(
+        x.reshape(b, s, num_heads, c // num_heads))
+    return g
+
+
+def _kda_op(attrs, octx, q, k, v, f, beta, conv_q, conv_k, conv_v, a_log,
+            dt_bias):
+    h = attrs["num_heads"]
+    b, s, c = q.shape
+    dk = c // h
+
+    @jax.checkpoint       # the backward recomputes the chunks from inputs
+    def core(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias):
+        q, k, v = (short_conv_silu(x, w).reshape(b, s, h, -1)
+                   for x, w in ((q, conv_q), (k, conv_k), (v, conv_v)))
+        dtype = q.dtype
+        q = (_l2_normalize(q) * dk ** -0.5).astype(dtype)
+        k = _l2_normalize(k).astype(dtype)
+        g = kda_log_decay(f, a_log, dt_bias, h)
+        bt = jax.nn.sigmoid(beta.astype(_F32))
+        with jax.named_scope("mx.kda.core"):
+            o = kda_chunked(q, k, v, g, bt, chunk=attrs["chunk"])
+        return o.reshape(b, s, -1).astype(dtype)
+
+    return _t(core(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log,
+                   dt_bias))
+
+
+def _kda_infer(attrs, in_shapes):
+    in_shapes = list(in_shapes)
+    qs = in_shapes[0]
+    if qs is not None:
+        c, h, kw = qs[-1], attrs["num_heads"], attrs["kernel"]
+        fill = {1: qs, 2: qs, 3: qs, 4: tuple(qs[:-1]) + (h,),
+                5: (c, kw), 6: (c, kw), 7: (c, kw), 8: (h,), 9: (c,)}
+        for i, shp in fill.items():
+            if in_shapes[i] is None:
+                in_shapes[i] = shp
+        # v may be wider or narrower than q per head
+        return in_shapes, [in_shapes[2]]
+    return in_shapes, [None]
+
+
+register("_contrib_kda", _kda_op,
+         params={"num_heads": Param("int", required=True),
+                 "kernel": Param("int", 4), "chunk": Param("int", 64)},
+         inputs=("query", "key", "value", "decay", "beta", "conv_query",
+                 "conv_key", "conv_value", "A_log", "dt_bias"),
+         infer_shape=_kda_infer)
+
+
+# -- held-experts mixture -------------------------------------------------------
+
+def moe_route(x, router_weight, router_bias, top_k, scaling, renormalize):
+    """(chosen experts (T, k) int32, their weights (T, k) float32):
+    sigmoid scores in float32, the `top_k` largest of score + bias (the
+    bias takes no gradient), weights renormalised over the chosen."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", x.astype(_F32), router_weight.astype(_F32),
+        precision=_HIGHEST))
+    _, idx = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(router_bias.astype(_F32)), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalize:
+        w = w / jnp.sum(w, -1, keepdims=True)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def _swiglu_experts(x, w_gate, w_up, w_down, group_sizes):
+    """Grouped SwiGLU: rows of x (M, D) sorted by expert, weights
+    (E, D, W) / (E, W, D)."""
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
+                            preferred_element_type=_F32)
+    hidden = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
+    return dot(hidden.astype(x.dtype), w_down)
+
+
+def _swiglu_one(x, wg, wu, wd):
+    hid = jax.nn.silu(_mm(x, wg, "td,dw->tw", x.dtype)) * \
+        _mm(x, wu, "td,dw->tw", x.dtype)
+    return _mm(hid, wd, "tw,wd->td", x.dtype)
+
+
+@jax.custom_vjp
+def _dense_experts(x, per_expert, w_gate, w_up, w_down):
+    """sum_e per_expert[:, e] * expert_e(x), every held expert on every
+    token, one expert at a time: forward and backward each keep one
+    expert's activations (a scan that autodiff transposes would keep all
+    eight's)."""
+    def one(y, args):
+        w_e, wg, wu, wd = args
+        return y + w_e[:, None] * _swiglu_one(x, wg, wu, wd), None
+    y, _ = jax.lax.scan(one, jnp.zeros(x.shape, _F32),
+                        (per_expert.T, w_gate, w_up, w_down))
+    return y
+
+
+def _dense_experts_fwd(x, per_expert, w_gate, w_up, w_down):
+    return (_dense_experts(x, per_expert, w_gate, w_up, w_down),
+            (x, per_expert, w_gate, w_up, w_down))
+
+
+def _dense_experts_bwd(res, dy):
+    x, per_expert, w_gate, w_up, w_down = res
+
+    def one(dx, args):
+        w_e, wg, wu, wd = args
+        out, vjp = jax.vjp(_swiglu_one, x, wg, wu, wd)
+        gx, gwg, gwu, gwd = vjp(dy * w_e[:, None])
+        return dx + gx.astype(_F32), (gwg, gwu, gwd, jnp.sum(dy * out, -1))
+
+    dx, (gwg, gwu, gwd, gw) = jax.lax.scan(
+        one, jnp.zeros(x.shape, _F32), (per_expert.T, w_gate, w_up, w_down))
+    return dx.astype(x.dtype), gw.T, gwg, gwu, gwd
+
+
+_dense_experts.defvjp(_dense_experts_fwd, _dense_experts_bwd)
+
+
+# rows of the grouped products, in balanced shares (T * top_k * held / all):
+# four shares hold every step but those in which the tokens crowd onto the
+# held experts; past them the dense path takes over, so the number decides
+# speed and memory, never the result
+MOE_CAPACITY = 4
+
+
+def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
+                first_expert, top_k, scaling, renormalize):
+    """y (T, D) = sum over the chosen experts held here of weight *
+    expert(x), and stats (E_held + 3,) int32: tokens per held expert,
+    token-expert pairs on held experts, pairs computed, dense fall-backs.
+
+    The pairs that fall on held experts are sorted by expert into
+    `capacity` rows (MOE_CAPACITY times the balanced share) and go
+    through grouped products; should more pairs than that arrive, the
+    layer computes every held expert on every token instead: no pair is
+    ever dropped."""
+    t, d = x.shape
+    n_held = w_gate.shape[0]
+    n_all = router_weight.shape[0]
+    with jax.named_scope("mx.moe.route"):
+        idx, w = moe_route(x, router_weight, router_bias, top_k, scaling,
+                           renormalize)
+        local = idx - first_expert
+        held = (local >= 0) & (local < n_held)
+        key = jnp.where(held, local, n_held).reshape(-1)       # (T*k,)
+        load = jnp.bincount(key, length=n_held + 1)[:n_held].astype(
+            jnp.int32)
+        n_pairs = jnp.sum(load)
+        balanced = -(-t * top_k * n_held // n_all)
+        capacity = min(t * top_k,
+                       -(-balanced * MOE_CAPACITY // 128) * 128)
+        order = jnp.argsort(key, stable=True)[:capacity]
+        token = order // top_k
+        # rows past the held pairs belong to no group: a grouped product
+        # leaves them unwritten (on the TPU: whatever the memory held), so
+        # they are SELECTED away on both sides of it, never multiplied away
+        valid = (jnp.arange(capacity) < n_pairs)[:, None]
+        weight = w.reshape(-1)[order][:, None]
+
+    def grouped(_):
+        rows = jnp.where(valid, jnp.take(x, token, axis=0), 0)
+        with jax.named_scope("mx.moe.experts.matmul"):
+            out = _swiglu_experts(rows, w_gate, w_up, w_down, load)
+        return jnp.zeros((t, d), _F32).at[token].add(
+            jnp.where(valid, out * weight, 0.0))
+
+    def dense(_):
+        per_expert = jnp.stack(
+            [jnp.sum(jnp.where(local == e, w, 0.0), -1)
+             for e in range(n_held)], axis=-1)                 # (T, E_held)
+        return _dense_experts(x, per_expert, w_gate, w_up, w_down)
+
+    with jax.named_scope("mx.moe.experts"):
+        fits = n_pairs <= capacity
+        y = jax.lax.cond(fits, grouped, dense, None)
+    stats = jnp.concatenate([load, jnp.stack([
+        n_pairs, n_pairs, 1 - fits.astype(jnp.int32)]).astype(jnp.int32)])
+    return y.astype(x.dtype), jax.lax.stop_gradient(stats)
+
+
+def _moe_op(attrs, octx, data, router_weight, router_bias, w_gate, w_up,
+            w_down):
+    shape = data.shape
+    y, stats = moe_experts(
+        data.reshape(-1, shape[-1]), router_weight, router_bias,
+        w_gate, w_up, w_down, first_expert=attrs["first_expert"],
+        top_k=attrs["top_k"], scaling=attrs["scaling"],
+        renormalize=attrs["renormalize"])
+    return _t(y.reshape(shape), stats)
+
+
+def _moe_infer(attrs, in_shapes):
+    in_shapes = list(in_shapes)
+    ds = in_shapes[0]
+    n_held, width = attrs["num_held"], attrs["hidden_size"]
+    if ds is not None:
+        d = ds[-1]
+        fill = {1: (attrs["num_experts"], d), 2: (attrs["num_experts"],),
+                3: (n_held, d, width), 4: (n_held, d, width),
+                5: (n_held, width, d)}
+        for i, shp in fill.items():
+            if in_shapes[i] is None:
+                in_shapes[i] = shp
+    return in_shapes, [ds, (n_held + 3,)]
+
+
+register("_contrib_moe_experts", _moe_op,
+         params={"num_experts": Param("int", required=True),
+                 "num_held": Param("int", required=True),
+                 "first_expert": Param("int", 0),
+                 "hidden_size": Param("int", required=True),
+                 "top_k": Param("int", required=True),
+                 "scaling": Param("float", 1.0),
+                 "renormalize": Param("bool", True)},
+         inputs=("data", "router_weight", "router_bias", "gate_weight",
+                 "up_weight", "down_weight"),
+         num_outputs=2, infer_shape=_moe_infer,
+         infer_type=lambda attrs, in_types: [in_types[0], "int32"])
+
+
+# -- the head: per-token cross-entropy --------------------------------------------
+
+def lm_head_ce(x, weight, label, block=2048):
+    """Cross-entropy of each row of x (T, D) against label (T,) under the
+    logits x @ weight^T, float32 (T,). The logits exist a `block` of rows
+    at a time, forward and backward."""
+    t, d = x.shape
+    block = min(block, t)
+    pad = -t % block
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        label = jnp.pad(label, (0, pad))
+    label = label.astype(jnp.int32)
+
+    @jax.checkpoint
+    def rows(args):
+        xb, lb = args
+        logits = _mm(xb, weight, "td,vd->tv", xb.dtype)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        return lse - jnp.take_along_axis(logits, lb[:, None], -1)[:, 0]
+
+    out = jax.lax.map(rows, (x.reshape(-1, block, d),
+                             label.reshape(-1, block)))
+    return out.reshape(-1)[:t]
+
+
+def _lm_head_ce_op(attrs, octx, data, weight, label):
+    lead = data.shape[:-1]
+    loss = lm_head_ce(data.reshape(-1, data.shape[-1]), weight,
+                      label.reshape(-1), attrs["block"])
+    return _t(loss.reshape(lead))
+
+
+def _head_infer(attrs, in_shapes):
+    in_shapes = list(in_shapes)
+    ds = in_shapes[0]
+    if ds is not None:
+        if in_shapes[1] is None:
+            in_shapes[1] = (attrs["num_classes"], ds[-1])
+        if in_shapes[2] is None:
+            in_shapes[2] = tuple(ds[:-1])
+        return in_shapes, [tuple(ds[:-1])]
+    return in_shapes, [None]
+
+
+register("_contrib_lm_head_ce", _lm_head_ce_op,
+         params={"num_classes": Param("int", required=True),
+                 "block": Param("int", 2048)},
+         inputs=("data", "weight", "label"), infer_shape=_head_infer,
+         infer_type=lambda attrs, in_types: ["float32"])

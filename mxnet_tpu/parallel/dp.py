@@ -156,7 +156,14 @@ class DataParallelTrainer:
         has_ls = self._has_ls
         scaler = self._scaler
         data_name_set = frozenset(data_names)
-        cast_input = [arg_names[p] in data_name_set for p in input_pos]
+        # what amp's policy says must reach its op as it is: fp32 decay
+        # and router parameters, ids carried in float arrays
+        from .. import amp as _amp
+        exact = _amp.exact_variables(symbol) \
+            if compute_dtype is not None else frozenset()
+        cast_input = [arg_names[p] in data_name_set and
+                      arg_names[p] not in exact for p in input_pos]
+        cast_param = [n not in exact for n in self._param_names]
         # input_preproc(name, value) -> value runs INSIDE the compiled
         # step, before any bf16 cast — the device-side half of the
         # ship-uint8/normalize-on-chip input regime (pair with
@@ -191,7 +198,8 @@ class DataParallelTrainer:
             # cast, so the update sees the same values as differentiating
             # the fp32 masters directly — only the all-reduce narrows.
             cparams = params if compute_dtype is None else tuple(
-                jnp.asarray(v, compute_dtype) for v in params)
+                jnp.asarray(v, compute_dtype) if cast else v
+                for v, cast in zip(params, cast_param))
 
             def loss_fn(cparams):
                 args = [None] * n_args
@@ -257,24 +265,25 @@ class DataParallelTrainer:
                 a2["t"] = t
             octx = OpCtx(is_train=True)
             new_params, new_states = [], []
-            for w, g, st in zip(params, grads, states):
-                # upcast into the fused fp32 master update; fp16 also
-                # unscales — in fp32, so an overflowed grad stays inf
-                # (detectable above) instead of wrapping
-                if g.dtype != jnp.float32:
-                    g = g.astype(jnp.float32)
-                if has_ls:
-                    g = g * inv_scale
-                res = fcompute(a2, octx, w, g, *st)
-                if has_ls:
-                    # skipped step: params/states stay bit-identical
-                    new_params.append(jnp.where(finite, res[0], w))
-                    new_states.append(tuple(
-                        jnp.where(finite, s, s0)
-                        for s, s0 in zip(res[1:], st)))
-                else:
-                    new_params.append(res[0])
-                    new_states.append(tuple(res[1:]))
+            with jax.named_scope("mx.optimizer"):
+                for w, g, st in zip(params, grads, states):
+                    # upcast into the fused fp32 master update; fp16 also
+                    # unscales — in fp32, so an overflowed grad stays inf
+                    # (detectable above) instead of wrapping
+                    if g.dtype != jnp.float32:
+                        g = g.astype(jnp.float32)
+                    if has_ls:
+                        g = g * inv_scale
+                    res = fcompute(a2, octx, w, g, *st)
+                    if has_ls:
+                        # skipped step: params/states stay bit-identical
+                        new_params.append(jnp.where(finite, res[0], w))
+                        new_states.append(tuple(
+                            jnp.where(finite, s, s0)
+                            for s, s0 in zip(res[1:], st)))
+                    else:
+                        new_params.append(res[0])
+                        new_states.append(tuple(res[1:]))
             if has_ls:
                 # an overflowed forward would poison BN running stats too
                 new_aux = tuple(jnp.where(finite, a, a0)

@@ -462,7 +462,7 @@ def Group(symbols):
 # hidden node attrs the reference's C API strips/renames on save+load
 # (c_api_symbolic.cc:40-42 kHiddenKeys)
 _HIDDEN_KEYS = ("ctx_group", "lr_mult", "wd_mult", "force_mirroring",
-                "mirror_stage")
+                "mirror_stage", "profiler_scope")
 _CURRENT_REF_VERSION = 10100    # the reference fork is MXNet ~1.1.0
 
 

@@ -1,0 +1,359 @@
+"""Kimi-Linear's mechanisms against the plain reference
+(tests/reference_models/kimi_linear.py), at a small size on the CPU: the
+chunked delta rule, the flash kernels at unequal head widths, latent
+attention, the held-experts mixture and its shares, the whole model, the
+fused fit with adam, and what amp must leave exact."""
+import functools
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import amp
+from mxnet_tpu.ops import attention, lm
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REF_PATH = os.path.join(HERE, "reference_models", "kimi_linear.py")
+COPY_PATH = os.path.join(os.path.dirname(HERE), "benchmarks", "models",
+                         "kimi_linear_reference.py")
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load(REF_PATH, "kimi_linear_reference_under_test")
+
+CFG = {
+    "hidden_size": 32, "intermediate_size": 64, "num_hidden_layers": 5,
+    "first_k_dense_replace": 1, "rms_norm_eps": 1e-5,
+    "linear_attn_config": {"kda_layers": [1, 2, 3, 5],
+                           "full_attn_layers": [4], "num_heads": 2,
+                           "head_dim": 16, "short_conv_kernel_size": 4},
+    "num_attention_heads": 2, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "kv_lora_rank": 24,
+    "q_lora_rank": None, "num_experts": 16, "num_experts_per_token": 4,
+    "num_shared_experts": 1, "moe_intermediate_size": 24,
+    "moe_renormalize": True, "routed_scaling_factor": 2.446,
+    "experts_held": [4, 4], "vocab_size": 300,
+}
+PREFIX = "kimi_"
+
+
+def _close(a, b, tol):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    err = np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30)
+    assert err <= tol, f"relative error {err} > {tol}"
+
+
+def _batch(seed, b=2, s=40, vocab=CFG["vocab_size"]):
+    tokens = np.random.default_rng(seed).integers(0, vocab, (b, s))
+    return tokens, np.roll(tokens, -1, axis=1)
+
+
+def _net(cfg, params):
+    net = mx.gluon.nn.DecoderLM(cfg, prefix=PREFIX)
+    net.collect_params().initialize()
+    system = ref.system_params(params, PREFIX)
+    assert set(net.collect_params().keys()) == set(system)
+    for name, p in net.collect_params().items():
+        p.set_data(mx.nd.array(system[name]))
+    return net
+
+
+@pytest.fixture(autouse=True)
+def _amp_off():
+    amp._reset_for_tests()
+    yield
+    amp._reset_for_tests()
+
+
+def _kda_inputs(s, b=2, h=3, dk=8, dv=8, seed=0):
+    rng = np.random.default_rng(seed)
+    q, k = (rng.standard_normal((b, s, h, dk)).astype("float32")
+            for _ in range(2))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.standard_normal((b, s, h, dv)).astype("float32")
+    # decays from almost none to exp(-20) a step: the pairwise stage must
+    # not overflow on the steep ones
+    g = -np.exp(rng.uniform(-3, 3, (b, s, h, dk))).astype("float32")
+    beta = rng.uniform(0, 1, (b, s, h)).astype("float32")
+    return tuple(jnp.asarray(a) for a in (q, k, v, g, beta))
+
+
+@pytest.mark.parametrize("s", [150, 37], ids=["several_chunks", "ragged"])
+def test_kda_chunked_matches_recurrence(s):
+    args = _kda_inputs(s)
+    _close(lm.kda_chunked(*args), ref.kda_recurrence(*args), 2e-5)
+    grads = [jax.grad(lambda *a, f=f: jnp.sum(jnp.sin(f(*a))),
+                      argnums=(0, 1, 2, 3, 4))(*args)
+             for f in (lm.kda_chunked, ref.kda_recurrence)]
+    for got, want in zip(*grads):
+        _close(got, want, 5e-5)
+
+
+def test_flash_kernels_unequal_head_widths():
+    """Latent attention's 192/128 through the Pallas kernels (interpret
+    mode), forward and both backward kernels, against explicit softmax."""
+    rng = np.random.default_rng(0)
+    b, h, s = 1, 2, 256
+    q, k = (jnp.asarray(rng.standard_normal((b, h, s, 192)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((b, h, s, 128)), jnp.float32)
+    scale = 192 ** -0.5
+
+    def run(force):
+        def f(q, k, v):
+            o = attention.flash_attention(q, k, v, causal=True, scale=scale,
+                                          force=force, block_q=128,
+                                          block_k=128)
+            return jnp.sum(jnp.sin(o)), o
+        (_, o), g = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return (o,) + g
+
+    for got, want in zip(run("interpret"), run("xla")):
+        assert got.shape == want.shape
+        _close(got, want, 2e-5)
+
+
+def test_dense_fallback_on_tpu_is_counted():
+    from mxnet_tpu.telemetry import registry
+    counter = registry.counter(attention.DENSE_FALLBACK_COUNTER)
+    x = jnp.ones((1, 1, 16, 40), jnp.float32)      # 40: no kernel takes it
+    before = counter.value()
+    attention.flash_attention(x, x, x, platform="cpu")
+    assert counter.value() == before
+    attention.flash_attention(x, x, x, platform="tpu")
+    assert counter.value() == before + 1
+
+
+def _layer(params, li):
+    return {k: jnp.asarray(v) for k, v in ref.layer_params(params, li).items()}
+
+
+def test_mla_block_matches_reference():
+    params = ref.init_params(CFG, 5)
+    x = np.random.default_rng(2).standard_normal((2, 24, 32)).astype("f4")
+    block = mx.gluon.nn.MLAMixer(CFG, prefix="l3_")
+    block.collect_params().initialize()
+    for name, p in block.collect_params().items():
+        p.set_data(mx.nd.array(params[name]))
+    want = ref.mla_mixer(CFG, _layer(params, 3), jnp.asarray(x))
+    _close(block(mx.nd.array(x)).asnumpy(), want, 2e-5)
+    _close(ref.mla_mixer(CFG, _layer(params, 3), jnp.asarray(x), q_block=8),
+           want, 2e-6)
+
+
+def _moe_system(cfg, p, x):
+    first, n = cfg["experts_held"]
+    t = jnp.asarray(x).reshape(-1, x.shape[-1])
+    y, stats = lm.moe_experts(
+        t, p["w_r"], p["r_bias"], jnp.swapaxes(p["e_gate"], 1, 2),
+        jnp.swapaxes(p["e_up"], 1, 2), jnp.swapaxes(p["e_down"], 1, 2),
+        first_expert=first, top_k=cfg["num_experts_per_token"],
+        scaling=cfg["routed_scaling_factor"],
+        renormalize=cfg["moe_renormalize"])
+    return y.reshape(x.shape), np.asarray(stats)
+
+
+@pytest.mark.parametrize("crowd", [0.0, 10.0],
+                         ids=["grouped", "dense_fallback"])
+def test_moe_routed_part_matches_reference(crowd):
+    """The pairs on held experts through grouped products, and through
+    the dense path that takes over when they exceed the capacity (here:
+    the router's selection bias sends every token to both held experts,
+    twice the rows the grouped products have): the same result, nothing
+    dropped either way."""
+    cfg = dict(CFG, num_experts=32, experts_held=[0, 2])
+    params = ref.init_params(cfg, 6)
+    p = _layer(params, 1)
+    bias = np.random.default_rng(0).normal(0, 0.1, 32)
+    bias[:2] += crowd
+    p["r_bias"] = jnp.asarray(bias, jnp.float32)
+    x = np.random.default_rng(3).standard_normal((2, 64, 32)).astype("f4")
+    y, stats = _moe_system(cfg, p, x)
+    _close(y, ref.moe_mlp(cfg, p, jnp.asarray(x), shared=False), 2e-5)
+    load, n_pairs, computed, fell_back = (stats[:2], stats[2], stats[3],
+                                          stats[4])
+    assert load.sum() == n_pairs == computed
+    assert fell_back == (crowd > 0)
+    if crowd:
+        assert n_pairs == 2 * 2 * 64
+    fn = lambda x: jnp.sum(jnp.sin(ref.moe_mlp(cfg, p, x, shared=False)))
+    fs = lambda x: jnp.sum(jnp.sin(_moe_system(cfg, p, x)[0]))
+    _close(jax.grad(fs)(jnp.asarray(x)), jax.grad(fn)(jnp.asarray(x)), 5e-5)
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """The model-configs guide's share test: the routed parts of the four
+    shares (4 experts each) plus the shared expert, counted once, are the
+    uncut layer of 16 experts."""
+    whole = dict(CFG, experts_held=[0, 16])
+    params = ref.init_params(whole, 7)
+    p = _layer(params, 2)
+    x = np.random.default_rng(4).standard_normal((2, 24, 32)).astype("f4")
+    total = np.asarray(ref.moe_mlp(whole, p, jnp.asarray(x), routed=False))
+    pairs = 0
+    for first in range(0, 16, 4):
+        share = dict(p, **{k: p[k][first:first + 4]
+                           for k in ("e_gate", "e_up", "e_down")})
+        y, stats = _moe_system(dict(CFG, experts_held=[first, 4]), share, x)
+        total = total + np.asarray(y)
+        pairs += stats[4]
+    assert pairs == 2 * 24 * CFG["num_experts_per_token"]
+    _close(total, ref.moe_mlp(whole, p, jnp.asarray(x)), 2e-5)
+
+
+def test_model_logits_and_loss_match_reference():
+    params = ref.init_params(CFG, 3)
+    tokens, labels = _batch(1)
+    net = _net(CFG, params)
+    _close(net(mx.nd.array(tokens)).asnumpy(),
+           ref.logits(CFG, params, jnp.asarray(tokens)), 2e-5)
+    loss, stats = net(mx.nd.array(tokens), mx.nd.array(labels))
+    _close(loss.asnumpy(), ref.token_losses(
+        CFG, params, jnp.asarray(tokens), jnp.asarray(labels)), 2e-5)
+    stats = stats.asnumpy()
+    assert stats.shape == (4, 7) and (stats[:, 6] == 0).all()
+
+
+def _symbol(cfg):
+    net = mx.gluon.nn.DecoderLM(cfg, prefix=PREFIX)
+    return mx.sym.Group(list(net(mx.sym.Variable("data"),
+                                 mx.sym.Variable("label"))))
+
+
+def _grads_through_executor(params, tokens, labels):
+    sym = _symbol(CFG)
+    system = ref.system_params(params, PREFIX)
+    args = {k: mx.nd.array(v) for k, v in system.items()}
+    args["data"] = mx.nd.array(tokens)
+    args["label"] = mx.nd.array(labels)
+    grads = {k: mx.nd.zeros(v.shape) for k, v in system.items()}
+    exe = sym.bind(mx.cpu(), args, args_grad=grads)
+    out = exe.forward(is_train=True)
+    exe.backward([mx.nd.ones(out[0].shape) / out[0].size,
+                  mx.nd.zeros(out[1].shape)])
+    return out[0].asnumpy(), {k: g.asnumpy() for k, g in grads.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_grads():
+    params = ref.init_params(CFG, 3)
+    tokens, labels = _batch(1)
+    return jax.jit(lambda p, t, l: ref.loss_and_grads(CFG, p, t, l))(
+        {k: jnp.asarray(v) for k, v in params.items()},
+        jnp.asarray(tokens), jnp.asarray(labels))
+
+
+@pytest.mark.parametrize("mirror", [False, True],
+                         ids=["saved", "mirror_stages"])
+def test_symbol_gradients_match_reference(mirror, monkeypatch):
+    """The graph the trainers run: every parameter's gradient against
+    jax.grad of the reference; with MXNET_BACKWARD_DO_MIRROR each layer is
+    rematerialised as one stage and nothing changes."""
+    if mirror:
+        monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    params = ref.init_params(CFG, 3)
+    tokens, labels = _batch(1)
+    loss, grads = _grads_through_executor(params, tokens, labels)
+    want_loss, want = _reference_grads()
+    _close(loss.mean(), want_loss, 1e-5)
+    want = ref.system_params({k: np.asarray(v) for k, v in want.items()},
+                             PREFIX)
+    assert set(grads) == set(want)
+    for name in sorted(want):
+        if name.endswith("r_bias"):
+            assert not grads[name].any()       # selection only: no gradient
+        else:
+            _close(grads[name], want[name], 2e-3)
+
+
+def test_fit_fused_adam_reproduces_reference_losses():
+    """Module.fit(steps_per_dispatch=2) with adam in fp32: the losses of
+    four steps are the reference's plain Adam's, and fall; so are the
+    parameters they leave."""
+    params = ref.init_params(CFG, 3)
+    batches = [_batch(10 + i) for i in range(4)]
+    lr = 3e-3
+    trained, want = ref.adam_steps(
+        CFG, params, [(jnp.asarray(t), jnp.asarray(l)) for t, l in batches],
+        lr=lr)
+    sym = _symbol(CFG)
+    loss_name = sym.list_outputs()[0]
+    it = mx.io.NDArrayIter(
+        data={"data": np.concatenate([t for t, _ in batches]).astype("f4")},
+        label={"label": np.concatenate([l for _, l in batches]).astype("f4")},
+        batch_size=2)
+    seen, got = [0.0, 0], []
+
+    def watch(param):
+        m = param.eval_metric
+        got.append((m.sum_metric - seen[0]) / (m.num_inst - seen[1]))
+        seen[:] = [m.sum_metric, m.num_inst]
+        assert "trainer" in param.locals
+
+    mod = mx.mod.Module(sym, data_names=["data"], label_names=["label"],
+                        context=mx.cpu())
+    system = ref.system_params(params, PREFIX)
+    mod.fit(it, num_epoch=1, optimizer="adam",
+            optimizer_params={"learning_rate": lr, "beta1": 0.9,
+                              "beta2": 0.95, "epsilon": 1e-8,
+                              "rescale_grad": 1.0 / (2 * 40)},
+            arg_params={k: mx.nd.array(v) for k, v in system.items()},
+            eval_metric=mx.metric.Loss(output_names=[loss_name]),
+            batch_end_callback=watch, steps_per_dispatch=2)
+    _close(got, [np.mean(want[:2]), np.mean(want[2:])], 2e-5)
+    assert got[1] < got[0]
+    # Adam divides a gradient by its own size: where one is all rounding
+    # its sign is too, so the parameters' CHANGE agrees to a percent, not
+    # to rounding; a state left unchanged would read 1
+    after = mod.get_params()[0]
+    for name, value in ref.system_params(
+            {k: np.asarray(v) for k, v in trained.items()}, PREFIX).items():
+        if not name.endswith("r_bias"):
+            _close(after[name].asnumpy() - system[name],
+                   value - system[name], 2e-2)
+        else:
+            assert not (after[name].asnumpy() - system[name]).any()
+
+
+def test_amp_leaves_ids_and_fp32_parameters_exact():
+    """bf16 holds integers only up to 256 and the decay only to 3 digits:
+    under amp the trainer hands ids (float arrays, MXNet's convention) and
+    the parameters amp/policy.py lists to the graph as they are."""
+    sym = _symbol(CFG)
+    exact = amp.exact_variables(sym)
+    assert {"data", "label"} <= exact
+    assert {n.split("_", 2)[-1] for n in exact - {"data", "label"}} == \
+        {"A_log", "dt_bias", "w_r", "r_bias"}
+    from mxnet_tpu.parallel.dp import DataParallelTrainer
+    from mxnet_tpu.parallel.mesh import mesh_for_contexts
+    bits = 9
+    table = (np.arange(300)[:, None] >> np.arange(bits)) & 1
+    out = mx.sym.Embedding(mx.sym.Variable("data"), mx.sym.Variable("w"),
+                           input_dim=300, output_dim=bits)
+    trainer = DataParallelTrainer(
+        mx.sym.MakeLoss(out), mesh_for_contexts([mx.cpu(0)]),
+        data_names=("data",), label_names=(), dtype="bfloat16")
+    params, states, aux = trainer.init_state(
+        {"data": (4,)}, arg_params={"w": mx.nd.array(table)})
+    ids = np.array([255.0, 257.0, 283.0, 299.0], "f4")
+    res = trainer.step(params, states, aux, trainer.shard_inputs([ids]))
+    got = np.asarray(res[4][0], np.float32)
+    assert ((got > 0.5) * (1 << np.arange(bits))).sum(1).tolist() == \
+        ids.astype(int).tolist()
+
+
+def test_reference_copy_is_equal():
+    with open(REF_PATH) as a, open(COPY_PATH) as b:
+        assert a.read() == b.read()

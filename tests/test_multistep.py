@@ -142,6 +142,57 @@ def test_module_fit_fused_metric_and_callbacks():
     assert mod.score(_digits_iter(), mx.metric.Accuracy())
 
 
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_module_fit_fused_module_whole_at_every_moment(ndev):
+    """The fused fit keeps the module's arrays on the host while the
+    trainer's state trains: a callback that reads the module mid-epoch
+    finds parameters (the draw, until an epoch wrote back), and an
+    exception mid-epoch leaves the module bound, on its devices, with the
+    executor it had: forward runs and retraces nothing of its own."""
+    ctx = [mx.cpu(i) for i in range(ndev)]
+    mod = mx.mod.Module(_mlp(), context=ctx if ndev > 1 else ctx[0])
+    it = _digits_iter()
+    mod.bind(it.provide_data, it.provide_label)
+    mod.init_params(mx.init.Xavier())
+    draw = {n: a.asnumpy() for n, a in mod.get_params()[0].items()}
+    exe = mod._exec
+    homes = {n: a._data.sharding for n, a in exe.arg_dict.items()}
+    seen = []
+
+    def interrupt(param):
+        args, aux = mod.get_params()
+        seen.append({n: a.asnumpy() for n, a in args.items()})
+        raise KeyboardInterrupt
+
+    with pytest.raises(KeyboardInterrupt):
+        mod.fit(it, num_epoch=1, optimizer="sgd",
+                optimizer_params={"learning_rate": 0.1},
+                batch_end_callback=interrupt, steps_per_dispatch=4)
+    assert mod.binded and mod._exec is exe
+    for got in seen + [{n: a.asnumpy()
+                        for n, a in mod.get_params()[0].items()}]:
+        assert set(got) == set(draw)
+        for n in draw:
+            np.testing.assert_array_equal(got[n], draw[n])
+    for n, a in exe.arg_dict.items():
+        assert a._data.sharding == homes[n], n
+    for n, g in exe.grad_dict.items():
+        assert g is None or g._data.sharding == homes[n], n
+    assert mod.score(_digits_iter(), mx.metric.Accuracy())
+    # and a fit that runs to its end trains the same executor's arrays
+    # (a forward over several devices has spread them over the mesh)
+    homes = {n: a._data.sharding for n, a in exe.arg_dict.items()}
+    it.reset()
+    mod.fit(it, num_epoch=1, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1}, steps_per_dispatch=4)
+    assert mod._exec is exe
+    trained = {n: a.asnumpy() for n, a in mod.get_params()[0].items()}
+    assert any(np.abs(trained[n] - draw[n]).max() > 1e-4 for n in draw)
+    for n in draw:
+        np.testing.assert_array_equal(exe.arg_dict[n].asnumpy(), trained[n])
+        assert exe.arg_dict[n]._data.sharding == homes[n]
+
+
 def test_module_fit_fused_fallback_warns():
     """An optimizer without a fused update op falls back to per-batch
     dispatch with a warning — and still trains."""

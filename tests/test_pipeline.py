@@ -638,6 +638,30 @@ def test_enable_compile_cache_writes_entries(tmp_path, monkeypatch):
         _detach_compile_cache()
 
 
+def test_compile_cache_placed_here_has_no_size_limit(tmp_path, monkeypatch):
+    """A limit that the machine sets for the cache IT placed
+    (JAX_COMPILATION_CACHE_MAX_SIZE) does not evict from a cache that
+    `enable_compile_cache` places: a second large entry would push out
+    the first, and the next process would compile everything again."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.config import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_max_size
+    jax.config.update("jax_compilation_cache_max_size", 1)   # one byte
+    cache_dir = str(tmp_path / "xla_cache")
+    try:
+        enable_compile_cache(cache_dir)
+        for n in (8, 16):
+            np.asarray(jax.jit(lambda x: jnp.tanh(x) @ x.T)(
+                np.ones((n, n), np.float32)))
+        kept = [e for e in os.listdir(cache_dir) if e.endswith("-cache")]
+        assert len(kept) >= 2, kept
+    finally:
+        jax.config.update("jax_compilation_cache_max_size", was)
+        _detach_compile_cache()
+
+
 def test_compile_cache_placed_from_outside_is_not_moved(tmp_path,
                                                         monkeypatch):
     """Where JAX_COMPILATION_CACHE_DIR is set, JAX's own handling of it

@@ -71,6 +71,23 @@ def test_flash_attention_forward_and_gradient(one_chip, b, h, h_kv, s):
                           q, kv, kv).count(KERNEL) == 3
 
 
+def test_flash_attention_unequal_head_widths(one_chip):
+    """Latent attention at Kimi-Linear's published widths: query/key heads
+    of 128 + 64, value heads of 128, 32 heads, one sequence of 8,192."""
+    from mxnet_tpu.ops.attention import flash_attention
+    qk = jax.ShapeDtypeStruct((1, 32, 8192, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=192 ** -0.5,
+                               platform="tpu").astype(jnp.float32).sum()
+
+    assert _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          qk, qk, v).count(KERNEL) == 3
+
+
 @pytest.mark.parametrize("h_kv", [8, 2])
 def test_decode_attention(one_chip, h_kv):
     from mxnet_tpu.ops.attention import decode_attention
