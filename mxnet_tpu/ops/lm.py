@@ -23,6 +23,12 @@ only S_0 -> S_C is sequential. Pairwise decays exp(G_i - G_j) are taken
 directly inside sub-blocks of `sub` tokens and through the sub-block's
 first row otherwise: every exponent is <= 0, nothing can overflow whatever
 the decay.
+
+Which path runs where: `kda` sends a bf16 program for a TPU with head
+widths that are multiples of 128 through the Pallas kernels of
+`ops/kda_pallas.py` (forward and backward under one custom VJP, the same
+work held in VMEM); a CPU program, float32 operands and every other shape
+take `kda_chunked`, which is also what the kernels are tested against.
 """
 from __future__ import annotations
 
@@ -214,6 +220,45 @@ def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, segment=4):
     return o[:, :s]
 
 
+KDA_KERNEL_COUNTER = "kda_kernel_calls_total"
+KDA_FALLBACK_COUNTER = "kda_xla_fallback_total"
+
+
+def _count_kda(name, help_text):
+    from ..telemetry import registry
+    registry.counter(name, help=help_text).inc()
+
+
+def kda(q, k, v, g, beta, chunk=64, force=None, platform=None):
+    """The gated delta rule by the path the operands call for; shapes as
+    `kda_chunked`'s, result in q's dtype.
+
+    force: None (auto) | 'pallas' | 'xla' | 'interpret' (the kernels under
+    the Pallas interpreter: CPU-testable), as in `flash_attention`.
+    `platform` is the platform the program is compiled for (the executor's
+    OpCtx). Both paths are counted once a trace in the telemetry registry:
+    a kernel call, and a bf16 program for a TPU whose shapes the kernels
+    refuse."""
+    from . import kda_pallas
+    if force in ("pallas", "interpret") or (
+            force is None and kda_pallas.eligible(
+                q.dtype, q.shape[-1], v.shape[-1], chunk, platform)):
+        if force is None:
+            _count_kda(KDA_KERNEL_COUNTER, "KDA cores traced for a TPU that "
+                       "went through the Pallas kernels")
+        return kda_pallas.kda_kernels(q, k, v, g, beta, chunk=chunk,
+                                      interpret=force == "interpret")
+    if force is None and q.dtype == jnp.bfloat16 and \
+            (platform or jax.default_backend()) == "tpu":
+        import logging
+        _count_kda(KDA_FALLBACK_COUNTER, "bf16 KDA cores traced for a TPU "
+                   "whose shapes the Pallas kernels do not take")
+        logging.getLogger(__name__).warning(
+            "kda: q %s v %s chunk %d not eligible for the TPU kernels; "
+            "the XLA path", q.shape, v.shape, chunk)
+    return kda_chunked(q, k, v, g, beta, chunk=chunk).astype(q.dtype)
+
+
 def kda_log_decay(f, a_log, dt_bias, num_heads):
     """g = -exp(A_log[h]) * softplus(f + dt_bias), float32; f (B, S, H*dk)
     -> (B, S, H, dk)."""
@@ -230,21 +275,30 @@ def _kda_op(attrs, octx, q, k, v, f, beta, conv_q, conv_k, conv_v, a_log,
     b, s, c = q.shape
     dk = c // h
 
-    @jax.checkpoint       # the backward recomputes the chunks from inputs
-    def core(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias):
+    def prepare(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias):
         q, k, v = (short_conv_silu(x, w).reshape(b, s, h, -1)
                    for x, w in ((q, conv_q), (k, conv_k), (v, conv_v)))
         dtype = q.dtype
         q = (_l2_normalize(q) * dk ** -0.5).astype(dtype)
         k = _l2_normalize(k).astype(dtype)
         g = kda_log_decay(f, a_log, dt_bias, h)
-        bt = jax.nn.sigmoid(beta.astype(_F32))
-        with jax.named_scope("mx.kda.core"):
-            o = kda_chunked(q, k, v, g, bt, chunk=attrs["chunk"])
-        return o.reshape(b, s, -1).astype(dtype)
+        return q, k, v, g, jax.nn.sigmoid(beta.astype(_F32))
 
-    return _t(core(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log,
-                   dt_bias))
+    def core(*args):
+        with jax.named_scope("mx.kda.core"):
+            o = kda(*args, chunk=attrs["chunk"], platform=octx.platform)
+        return o.reshape(b, s, -1)
+
+    inputs = (q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias)
+    from . import kda_pallas
+    if kda_pallas.eligible(q.dtype, dk, v.shape[-1] // h, attrs["chunk"],
+                           octx.platform):
+        # the kernels' custom VJP keeps the core's operands and its
+        # chunk-start states, and nothing of their insides: no checkpoint
+        # (the layer's own rematerialisation bounds how long they live)
+        return _t(core(*prepare(*inputs)))
+    # the XLA path's backward recomputes the chunks from the inputs
+    return _t(jax.checkpoint(lambda *a: core(*prepare(*a)))(*inputs))
 
 
 def _kda_infer(attrs, in_shapes):

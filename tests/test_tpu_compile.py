@@ -88,6 +88,28 @@ def test_flash_attention_unequal_head_widths(one_chip):
                           qk, qk, v).count(KERNEL) == 3
 
 
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_kda_kernels_forward_and_gradient(one_chip, chunk):
+    """The delta rule's kernels at `kimi_linear.train`'s shape: 2 sequences
+    of 8,192, 32 heads of 128 / 128, bf16 with a float32 log-decay."""
+    from mxnet_tpu.ops import lm
+    b, s, h, d = 2, 8192, 32, 128
+    qkv = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((b, s, h, d), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((b, s, h), jnp.float32, sharding=one_chip)
+
+    def fwd(*a):            # the auto pick, told its target is a TPU
+        return lm.kda(*a, chunk=chunk, platform="tpu")
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    assert _compiled_text(fwd, qkv, qkv, qkv, g, beta).count(KERNEL) == 1
+    # the forward that keeps the chunk-start states, and the backward
+    assert _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                          qkv, qkv, qkv, g, beta).count(KERNEL) == 2
+
+
 @pytest.mark.parametrize("h_kv", [8, 2])
 def test_decode_attention(one_chip, h_kv):
     from mxnet_tpu.ops.attention import decode_attention
