@@ -27,7 +27,7 @@ def main():
     args = ap.parse_args()
     ctx = mx.tpu() if args.ctx == "tpu" else mx.cpu()
 
-    # small conv net (swap in bench._resnet50_symbol for the full model)
+    # small conv net (gluon.model_zoo.vision.resnet50_v1 for the full model)
     data = mx.sym.Variable("data")
     net = mx.sym.Convolution(data, kernel=(3, 3), num_filter=16, pad=(1, 1),
                              name="c1")

@@ -28,7 +28,7 @@ Four pieces:
     self-healing phases (SIGKILL at N=3 → automatic recovery),
     `--matrix` for the full injection matrix including the
     kill-mid-cooperative-commit sha256-identity proof, `--bench` for
-    the bench.py dist_recovery lane.
+    the time from a SIGKILL to the resumed gang's first step.
 
 The runtime-hardening half lives in `mxnet_tpu.dist`: timeout barriers,
 `DistRankFailure` naming missing ranks, coordinated abort

@@ -402,8 +402,7 @@ def calibrate_weights(params, dtype=None, skip=("embed", "pos"),
 
     Returns (qparams, stats): stats maps each quantized name to its
     calibration record — per-channel |w| max, the scale range, and the
-    RMS relative dequantization error (the number docs/int8_r04.md was
-    missing when the bench lane was parked).
+    RMS relative dequantization error.
     """
     from .. import config as _config
     from ..ops.quantization import dequantize_rows, quantize_rows

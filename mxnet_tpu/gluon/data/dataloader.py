@@ -15,7 +15,7 @@ Here `num_workers>0` selects between two pools via `worker_type`:
   assemble pure-NUMPY batches (no device buffers cross the fork; the
   parent does the single wrap + transfer), samples ship back pickled.
 
-Crossover guidance (tools/dataloader_bench.py measures it):
+Crossover guidance (by construction, not measured here):
 GIL-releasing pipelines — threads win (no pickling, shared memory);
 GIL-bound python transforms — processes win roughly linearly in cores.
 `num_workers=0` runs inline.

@@ -64,9 +64,11 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
     import itertools
     import numpy as np
     from .. import symbol as sym_mod
+    from ..base import to_numpy as _np_of
     from ..context import current_context
     from ..ndarray.ndarray import NDArray, array as nd_array
     from ..parallel.dp import DataParallelTrainer
+    from ..parallel.fused_loop import FusedLoop
     from ..parallel.mesh import mesh_for_contexts
 
     contexts = contexts or [current_context()]
@@ -77,6 +79,11 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
         # (amp.init / MXNET_AMP); an explicit dtype= always wins
         from .. import amp as _amp
         dtype = _amp.get_dtype() if _amp.is_enabled() else "float32"
+    # default K comes from MXNET_FUSED_K (the planner auto-tunes it per
+    # chosen plan, "auto unless set"); 0/unset keeps the historical 8
+    if steps_per_dispatch is None:
+        from .. import config
+        steps_per_dispatch = int(config.get("MXNET_FUSED_K", 0)) or 8
 
     it = iter(train_data)
     try:
@@ -111,181 +118,67 @@ def fused_fit(net, loss, train_data, num_epoch, optimizer="sgd",
         clip_gradient=opt_params.pop("clip_gradient", None), dtype=dtype,
         **opt_params)
     pmap = {p.name: p for _, p in net.collect_params().items()}
-    params, states, aux = trainer.init_state(
-        {"data": tuple(x0.shape), "fused_label": tuple(y0.shape)},
-        arg_params={n: pmap[n].data() for n in trainer.param_names},
-        aux_params={n: pmap[n].data() for n in trainer.aux_names
-                    if n in pmap})
 
-    begin_epoch, gstep, ckpt_skip = 0, 0, 0
-    ckpt_mgr = None
-    if checkpoint_dir is not None:
-        from ..checkpoint import CheckpointManager
-        ckpt_mgr = CheckpointManager(checkpoint_dir)
-        if resume:
-            ckpt_state = ckpt_mgr.restore()
-            if ckpt_state is not None:
-                from .. import random as _random
-                from ..checkpoint.state import rescale_cursor
-                if ckpt_state.meta.get("trainer") is not None:
-                    # device_put onto THIS run's mesh — an elastic
-                    # restore at a different device count reshards here
-                    params, states, aux = trainer.import_training_state(
-                        ckpt_state.arrays, ckpt_state.meta["trainer"])
-                if ckpt_state.meta.get("rng") is not None:
-                    _random.set_state(ckpt_state.meta["rng"])
-                begin_epoch = int(ckpt_state.meta.get("epoch", 0))
-                gstep = int(ckpt_state.meta.get("step", 0))
-                ckpt_skip = rescale_cursor(ckpt_state.meta, batch)
-                saved_topo = ckpt_state.meta.get("topology") or {}
-                if saved_topo.get("device_count") is not None:
-                    import jax
-                    cur = int(jax.device_count())
-                    if int(saved_topo["device_count"]) != cur:
-                        ckpt_mgr.logger.info(
-                            "checkpoint: topology changed since save "
-                            "(%s -> %d devices); state resharded onto "
-                            "the current mesh",
-                            saved_topo["device_count"], cur)
-        ckpt_mgr.install_sigterm_hook()
-
-    def _ckpt_capture(next_epoch, next_batch):
-        # synchronous device snapshot between dispatches; serialization
-        # overlaps the following steps on the manager's saver thread
-        from ..checkpoint.state import TrainingState
-        from .. import random as _random
-        arrays, tmeta = trainer.export_training_state(params, states, aux)
-        return TrainingState(arrays=arrays, meta={
-            "kind": "gluon_fused", "epoch": int(next_epoch),
-            "batch": int(next_batch), "step": int(gstep),
-            "batch_size": int(batch),
-            "trainer": tmeta, "rng": _random.get_state(),
-            "amp_dtype": dtype if dtype != "float32" else None})
-
-    from ..base import to_numpy as _np_of
-
-    def _writeback():
-        # COPY out of the training state: step_k donates its params/states
-        # buffers, so binding the live arrays into the net would leave the
-        # net (and any epoch_callback snapshot) holding deleted buffers
-        # after the next epoch's first dispatch
-        for n, p in trainer.host_params(params).items():
-            pmap[n].set_data(nd_array(p))
-        for n, a in trainer.host_aux(aux).items():
+    def write_back(arg_np, aux_np):
+        for n, v in itertools.chain(arg_np.items(), aux_np.items()):
             if n in pmap:
-                pmap[n].set_data(nd_array(a))
+                pmap[n].set_data(nd_array(v))
 
-    from ..pipeline import feed_or_inline, close_feed, BlockStager
-    from ..telemetry import tracing as _tracing
-
-    def _blocks(stream):
-        while True:
-            block = list(itertools.islice(stream, k))
-            if not block:
-                return
-            yield block
-
-    stager = BlockStager(trainer.shard_inputs)
-
-    def _stage_block(block):
-        # stack + device commit on the feeder thread: block N+1 is staged
-        # while block N's fused scan executes (the stager copies into host
-        # buffers of its own, so loader buffer reuse is safe)
-        columns = [[_np_of(b[0]) for b in block],
-                   [_np_of(b[1]) for b in block]]
-        return stager(columns, stacked=True), len(block)
-
-    # default K comes from MXNET_FUSED_K (the planner auto-tunes it per
-    # chosen plan, "auto unless set"); 0/unset keeps the historical 8
-    if steps_per_dispatch is None:
-        from .. import config
-        steps_per_dispatch = int(config.get("MXNET_FUSED_K", 0)) or 8
-    k = int(steps_per_dispatch)
+    loop = FusedLoop("gluon_fused_fit", "gluon_fused", checkpoint_dir,
+                     checkpoint_period, resume)
     epoch_losses = []
-    from ..telemetry import maybe_step_logger
-    slog = maybe_step_logger("gluon_fused_fit", meta={
-        "optimizer": optimizer, "steps_per_dispatch": k,
-        "batch_size": batch, "num_epoch": num_epoch,
-        "amp_dtype": dtype if dtype != "float32" else None})
+    total = count = 0
+
+    def batches(epoch):
+        nonlocal total, count
+        total, count = 0.0, 0
+        return itertools.chain([first], it) if epoch == 0 \
+            else iter(train_data)
+
+    def columns(block):
+        return [[_np_of(b[0]) for b in block],
+                [_np_of(b[1]) for b in block]], None
+
+    def sum_loss(losses, outputs, extra, n_blk):
+        nonlocal total, count
+        blk_loss = float(np.sum(np.asarray(losses)))
+        total += blk_loss
+        count += n_blk * batch
+        return blk_loss
+
+    def end_epoch(epoch, arg_np, aux_np):
+        if count == 0:
+            # a single-pass generator exhausts after epoch 0 — failing
+            # loudly beats recording 0.0-loss "epochs" that trained nothing
+            raise MXNetError(
+                f"fused_fit: epoch {epoch} yielded no batches (is "
+                "train_data a single-pass generator? pass a "
+                "re-iterable like a DataLoader or list)")
+        mean_loss = total / count
+        epoch_losses.append(mean_loss)
+        write_back(arg_np, aux_np)
+        if epoch_callback is not None:
+            epoch_callback(epoch, net, mean_loss)
+        return mean_loss
+
     try:
-        for epoch in range(begin_epoch, num_epoch):
-            total, count = 0.0, 0
-            stream = itertools.chain([first], it) if epoch == 0 \
-                else iter(train_data)
-            if ckpt_skip:
-                for _ in itertools.islice(stream, ckpt_skip):
-                    pass
-            nbatch = ckpt_skip
-            ckpt_skip = 0
-            last_ckpt = gstep
-            feed = feed_or_inline(_blocks(stream), _stage_block,
-                                  name="gluon_fused_fit")
-            try:
-                for seq, (inputs, n_blk) in enumerate(feed):
-                    # "compute" span: fused dispatch + the loss sync,
-                    # each half under a span of its own (no phase: the
-                    # parent's time is the phase's)
-                    with _tracing.span("step.fused_dispatch",
-                                       phase="compute", k=n_blk, seq=seq):
-                        with _tracing.span("step.enqueue"):
-                            params, states, aux, losses, _ = \
-                                trainer.step_k(params, states, aux, inputs)
-                        with _tracing.span("step.metric_update"):
-                            blk_loss = float(np.sum(np.asarray(losses)))
-                    total += blk_loss
-                    count += n_blk * batch
-                    # the np.asarray above already synced on the block's
-                    # losses, so this wall time covers real device work
-                    with _tracing.span("step.log", seq=seq):
-                        slog.step(samples=n_blk * batch, steps=n_blk,
-                                  loss=blk_loss / max(n_blk * batch, 1),
-                                  extra={"epoch": epoch})
-                    nbatch += n_blk
-                    gstep += n_blk
-                    if ckpt_mgr is not None:
-                        if checkpoint_period and \
-                                gstep - last_ckpt >= int(checkpoint_period):
-                            with _tracing.span("step.checkpoint", seq=seq):
-                                ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
-                                              step=gstep)
-                            last_ckpt = gstep
-                        if ckpt_mgr.preempted:
-                            with _tracing.span("step.checkpoint", seq=seq):
-                                ckpt_mgr.save(_ckpt_capture(epoch, nbatch),
-                                              step=gstep, blocking=True)
-                            raise SystemExit(143)
-            finally:
-                close_feed(feed)
-            if count == 0:
-                # a single-pass generator exhausts after epoch 0 — failing
-                # loudly beats recording 0.0-loss "epochs" that trained
-                # nothing
-                raise MXNetError(
-                    f"fused_fit: epoch {epoch} yielded no batches (is "
-                    "train_data a single-pass generator? pass a "
-                    "re-iterable like a DataLoader or list)")
-            mean_loss = total / max(count, 1)
-            epoch_losses.append(mean_loss)
-            _writeback()
-            if epoch_callback is not None:
-                epoch_callback(epoch, net, mean_loss)
-            if ckpt_mgr is not None:
-                ckpt_mgr.save(_ckpt_capture(epoch + 1, 0), step=gstep,
-                              metric=mean_loss)
-                if ckpt_mgr.preempted:
-                    ckpt_mgr.wait()
-                    raise SystemExit(143)
+        if loop.restored is not None:
+            # the snapshot's parameters, whatever wrote it; its trainer
+            # state too where the loop finds it to be this front end's own
+            write_back(loop.restored.arg_params_nd(),
+                       loop.restored.aux_params_nd())
+        loop.hold(trainer, trainer.init_state(
+            {"data": tuple(x0.shape), "fused_label": tuple(y0.shape)},
+            arg_params={n: pmap[n].data() for n in trainer.param_names},
+            aux_params={n: pmap[n].data() for n in trainer.aux_names
+                        if n in pmap}))
+        loop.run(int(steps_per_dispatch), batch, loop.resume_epoch(),
+                 num_epoch, batches, columns, sum_loss, end_epoch,
+                 optimizer=optimizer,
+                 amp_dtype=dtype if dtype != "float32" else None)
     finally:
-        # run_end carries the step program's XLA cost digest (program
-        # name, FLOPs/bytes per step, the peak table the MFU used)
-        from ..telemetry import devstats as _devstats
-        try:
-            slog.close(**_devstats.fit_summary())
-        except Exception:
-            slog.close()
-        if ckpt_mgr is not None:
-            ckpt_mgr.remove_sigterm_hook()
-            ckpt_mgr.close()
+        loop.release()
+        loop.close()
     return epoch_losses
 
 

@@ -11,18 +11,28 @@ from __future__ import annotations
 
 import itertools
 import logging
+import time
 import warnings
 
-from ..base import MXNetError
+import numpy as np
+
+from ..base import MXNetError, to_numpy as _np_of
 from ..context import Context, cpu, current_context
 from ..initializer import Uniform, InitDesc
+from .. import amp as _amp
+from .. import metric as metric_mod
 from .. import optimizer as opt_mod
-from ..model import (_create_kvstore, _initialize_kvstore,
+from ..model import (BatchEndParam, _create_kvstore, _initialize_kvstore,
                      _update_params_on_kvstore, _update_params,
                      load_checkpoint, save_checkpoint)
 from ..io import DataDesc
 from ..ndarray.ndarray import NDArray, zeros
-from .base_module import BaseModule, _check_input_names
+from ..ops.registry import get_op
+from ..parallel.dp import DataParallelTrainer, _OPT_OPS
+from ..parallel.fused_loop import FusedLoop
+from ..parallel.mesh import mesh_for_contexts
+from ..telemetry import tracing as _tracing
+from .base_module import BaseModule, _as_list, _check_input_names
 
 __all__ = ["Module"]
 
@@ -355,6 +365,92 @@ class Module(BaseModule):
                 self._sync_params_from_devices()
 
     # -- fused multi-step fit (steps_per_dispatch > 1) -----------------------
+    def _fused_blockers(self, optimizer, opt_params, kvstore, monitor):
+        """Why this configuration cannot take the fused loop: [] if it can."""
+        blockers = []
+        if not (isinstance(optimizer, str) and optimizer in _OPT_OPS):
+            blockers.append(f"optimizer {optimizer!r} has no fused update "
+                            f"op (supported: {sorted(_OPT_OPS)})")
+        if not (kvstore is None or (isinstance(kvstore, str) and
+                                    "dist" not in kvstore)):
+            blockers.append(f"kvstore {kvstore!r} is distributed/custom")
+        if "lr_scheduler" in opt_params:
+            blockers.append("lr_scheduler (drive set_learning_rate "
+                            "externally instead)")
+        if monitor is not None:
+            blockers.append("monitor")
+        if self._state_names:
+            blockers.append("state_names")
+        if self._fixed_param_names:
+            blockers.append("fixed_param_names")
+        if self._group2ctxs:
+            blockers.append("group2ctxs")
+        if not blockers:
+            # hyperparams the fused update op's schema can't take (e.g.
+            # multi_precision, lazy_update) must fall back, not raise
+            op_entry = _OPT_OPS[optimizer]
+            opname = op_entry({"momentum": opt_params.get("momentum")}) \
+                if callable(op_entry) else op_entry
+            # multi_precision is handled, not a blocker: the fused path
+            # ALWAYS keeps fp32 master params (init_state seeds fp32 and
+            # the update runs fp32), so the flag is simply satisfied
+            handled = {"learning_rate", "momentum", "wd", "rescale_grad",
+                       "clip_gradient", "multi_precision"}
+            extra = [k for k in opt_params
+                     if k not in handled and k not in get_op(opname).params]
+            if extra:
+                blockers.append(
+                    f"optimizer_params {extra} not supported by the fused "
+                    f"{opname} op")
+        return blockers
+
+    def _fused_trainer(self, restored, train_data, optimizer, opt_params,
+                       initializer, arg_params, aux_params, allow_missing,
+                       force_rebind, force_init):
+        """The fused fit's set-up, each step under a span of its own
+        (`fit.bind`, `fit.init_params`, `fit.trainer_init`): a normal bind
+        and init, so that the parameter draw is identical to K=1 (or the
+        `restored` snapshot's parameters), then the trainer. Returns
+        (trainer, compute dtype)."""
+        if restored is not None:
+            arg_params = restored.arg_params_nd()
+            aux_params = restored.aux_params_nd()
+            force_init = True
+            self.logger.info(
+                "checkpoint: resuming fused fit from committed step %s "
+                "(epoch %d, batch %d)", restored.step,
+                int(restored.meta.get("epoch", 0)),
+                int(restored.meta.get("batch", 0)))
+        with _tracing.span("fit.bind"):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
+        with _tracing.span("fit.init_params"):
+            self.init_params(initializer=initializer, arg_params=arg_params,
+                             aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+        lr = float(opt_params.pop("learning_rate", 0.01))
+        opt_params.pop("multi_precision", None)   # always on (fp32 masters)
+        # amp threads the compute dtype into the fused scan: params stay
+        # fp32 masters, compute/grad-all-reduce run in the amp dtype, and
+        # for fp16 the DynamicLossScaler state rides the scan carry
+        fit_dtype = _amp.get_dtype() if _amp.is_enabled() else "float32"
+        with _tracing.span("fit.trainer_init"):
+            trainer = DataParallelTrainer(
+                self._symbol, mesh_for_contexts(self._context),
+                data_names=tuple(self._data_names),
+                label_names=tuple(self._label_names), optimizer=optimizer,
+                learning_rate=lr,
+                momentum=float(opt_params.pop("momentum", 0.0)),
+                wd=float(opt_params.pop("wd", 0.0)),
+                rescale_grad=float(opt_params.pop(
+                    "rescale_grad", 1.0 / self._data_shapes[0].shape[0])),
+                clip_gradient=opt_params.pop("clip_gradient", None),
+                dtype=fit_dtype,
+                **opt_params)
+        return trainer, fit_dtype
+
     def _fit_fused(self, train_data, eval_data, eval_metric,
                    epoch_end_callback, batch_end_callback, kvstore,
                    optimizer, optimizer_params, eval_end_callback,
@@ -374,53 +470,9 @@ class Module(BaseModule):
         callbacks, and validation scoring see exactly what K=1 would.
         Returns False (with a warning) when the config can't fuse —
         BaseModule.fit then runs the per-batch path."""
-        import time
-        import numpy as np
-        from ..parallel.dp import DataParallelTrainer, _OPT_OPS
-        from ..parallel.mesh import mesh_for_contexts
-        from ..ndarray.ndarray import NDArray
-        from .base_module import _as_list
-        from .. import metric as metric_mod
-        from ..model import BatchEndParam
-
         opt_params = dict(optimizer_params or {})
-        blockers = []
-        if not (isinstance(optimizer, str) and optimizer in _OPT_OPS):
-            blockers.append(f"optimizer {optimizer!r} has no fused update "
-                            f"op (supported: {sorted(_OPT_OPS)})")
-        if not (kvstore is None or (isinstance(kvstore, str) and
-                                    "dist" not in kvstore)):
-            blockers.append(f"kvstore {kvstore!r} is distributed/custom")
-        if "lr_scheduler" in opt_params:
-            blockers.append("lr_scheduler (drive set_learning_rate "
-                            "externally instead)")
-        if monitor is not None:
-            blockers.append("monitor")
-        if self._state_names:
-            blockers.append("state_names")
-        if self._fixed_param_names:
-            blockers.append("fixed_param_names")
-        if self._group2ctxs:
-            blockers.append("group2ctxs")
-        if not blockers and isinstance(optimizer, str) \
-                and optimizer in _OPT_OPS:
-            # hyperparams the fused update op's schema can't take (e.g.
-            # multi_precision, lazy_update) must fall back, not raise
-            from ..ops.registry import get_op
-            op_entry = _OPT_OPS[optimizer]
-            opname = op_entry({"momentum": opt_params.get("momentum")}) \
-                if callable(op_entry) else op_entry
-            # multi_precision is handled, not a blocker: the fused path
-            # ALWAYS keeps fp32 master params (init_state seeds fp32 and
-            # the update runs fp32), so the flag is simply satisfied
-            handled = {"learning_rate", "momentum", "wd", "rescale_grad",
-                       "clip_gradient", "multi_precision"}
-            extra = [k for k in opt_params
-                     if k not in handled and k not in get_op(opname).params]
-            if extra:
-                blockers.append(
-                    f"optimizer_params {extra} not supported by the fused "
-                    f"{opname} op")
+        blockers = self._fused_blockers(optimizer, opt_params, kvstore,
+                                        monitor)
         if blockers:
             self.logger.warning(
                 "steps_per_dispatch>1 unsupported for this config (%s); "
@@ -428,282 +480,93 @@ class Module(BaseModule):
             return False
 
         k = steps_per_dispatch
-
-        ckpt_mgr = None
-        ckpt_state = None
-        if checkpoint_dir is not None:
-            from ..checkpoint import CheckpointManager
-            ckpt_mgr = CheckpointManager(checkpoint_dir, logger=self.logger)
-            if resume:
-                ckpt_state = ckpt_mgr.restore()
-                if ckpt_state is not None:
-                    arg_params = ckpt_state.arg_params_nd()
-                    aux_params = ckpt_state.aux_params_nd()
-                    force_init = True
-                    begin_epoch = int(ckpt_state.meta.get("epoch",
-                                                          begin_epoch))
-                    self.logger.info(
-                        "checkpoint: resuming fused fit from committed "
-                        "step %s (epoch %d, batch %d)", ckpt_state.step,
-                        begin_epoch, int(ckpt_state.meta.get("batch", 0)))
-
-        # the four steps of set-up each under a span of their own
-        # (fit.bind, fit.init_params, fit.trainer_init, fit.init_state)
-        from ..telemetry import tracing as _tracing
-        # normal bind + init so the parameter draw is identical to K=1
-        with _tracing.span("fit.bind"):
-            self.bind(data_shapes=train_data.provide_data,
-                      label_shapes=train_data.provide_label,
-                      for_training=True, force_rebind=force_rebind)
-        with _tracing.span("fit.init_params"):
-            self.init_params(initializer=initializer, arg_params=arg_params,
-                             aux_params=aux_params,
-                             allow_missing=allow_missing,
-                             force_init=force_init)
-
-        if validation_metric is None:
-            validation_metric = eval_metric
-        if not isinstance(eval_metric, metric_mod.EvalMetric):
-            eval_metric = metric_mod.create(eval_metric)
-        batch_callbacks = _as_list(batch_end_callback)
-        epoch_callbacks = _as_list(epoch_end_callback)
-
-        batch_size = self._data_shapes[0].shape[0]
-        lr = float(opt_params.pop("learning_rate", 0.01))
-        opt_params.pop("multi_precision", None)   # always on (fp32 masters)
-        # amp threads the compute dtype into the fused scan: params stay
-        # fp32 masters, compute/grad-all-reduce run in the amp dtype, and
-        # for fp16 the DynamicLossScaler state rides the scan carry
-        from .. import amp as _amp
-        fit_dtype = _amp.get_dtype() if _amp.is_enabled() else "float32"
-        with _tracing.span("fit.trainer_init"):
-            trainer = DataParallelTrainer(
-                self._symbol, mesh_for_contexts(self._context),
-                data_names=tuple(self._data_names),
-                label_names=tuple(self._label_names), optimizer=optimizer,
-                learning_rate=lr,
-                momentum=float(opt_params.pop("momentum", 0.0)),
-                wd=float(opt_params.pop("wd", 0.0)),
-                rescale_grad=float(opt_params.pop("rescale_grad",
-                                                  1.0 / batch_size)),
-                clip_gradient=opt_params.pop("clip_gradient", None),
-                dtype=fit_dtype,
-                **opt_params)
-        shape_kwargs = {d.name: d.shape for d in
-                        self._data_shapes + (self._label_shapes or [])}
-        homes, slog = None, None
+        loop = FusedLoop("module_fit_fused", "module_fused", checkpoint_dir,
+                         checkpoint_period, resume, logger=self.logger)
+        homes = None
         try:
+            begin_epoch = loop.resume_epoch(begin_epoch)
+            trainer, fit_dtype = self._fused_trainer(
+                loop.restored, train_data, optimizer, opt_params, initializer,
+                arg_params, aux_params, allow_missing, force_rebind,
+                force_init)
+            batch_size = self._data_shapes[0].shape[0]
+
+            if validation_metric is None:
+                validation_metric = eval_metric
+            if not isinstance(eval_metric, metric_mod.EvalMetric):
+                eval_metric = metric_mod.create(eval_metric)
+            batch_callbacks = _as_list(batch_end_callback)
+            epoch_callbacks = _as_list(epoch_end_callback)
+
+            shape_kwargs = {d.name: d.shape for d in
+                            self._data_shapes + (self._label_shapes or [])}
+            # set-up's last step, under its span like the three before it
             with _tracing.span("fit.init_state"):
                 # the trainer's state does the training: what this module
                 # holds on the device for its executor (parameters, their
                 # gradient arrays, the module's own copies: three times the
                 # parameters' bytes, fp32) waits on the host meanwhile
                 homes = self._params_to_host()
-                params, states, aux = trainer.init_state(
+                loop.hold(trainer, trainer.init_state(
                     shape_kwargs, arg_params=self._arg_params,
-                    aux_params=self._aux_params)
+                    aux_params=self._aux_params))
 
-            gstep = 0
-            ckpt_skip = 0
-            if ckpt_state is not None:
-                if ckpt_state.meta.get("kind") == "module_fused" and \
-                        ckpt_state.meta.get("trainer") is not None:
-                    # full fused-loop state: opt-state arrays + device t/rng/
-                    # loss-scaler carries — the continuation is bit-identical
-                    # (import device_puts the reassembled host arrays onto
-                    # THIS run's mesh, so an elastic restore at a different
-                    # device count reshards here)
-                    params, states, aux = trainer.import_training_state(
-                        ckpt_state.arrays, ckpt_state.meta["trainer"])
-                else:
-                    self.logger.warning(
-                        "checkpoint: snapshot kind=%r has no fused-trainer "
-                        "state; params restored, optimizer state starts "
-                        "fresh", ckpt_state.meta.get("kind"))
-                from .. import random as _random
-                if ckpt_state.meta.get("rng") is not None:
-                    _random.set_state(ckpt_state.meta["rng"])
-                gstep = int(ckpt_state.meta.get("step", 0))
-                from ..checkpoint.state import rescale_cursor
-                ckpt_skip = rescale_cursor(ckpt_state.meta, batch_size)
-                saved_topo = ckpt_state.meta.get("topology") or {}
-                if saved_topo.get("device_count") is not None:
-                    import jax
-                    cur = int(jax.device_count())
-                    if int(saved_topo["device_count"]) != cur:
-                        self.logger.info(
-                            "checkpoint: topology changed since save "
-                            "(%s -> %d devices); state resharded onto the "
-                            "current mesh", saved_topo["device_count"], cur)
-            if ckpt_mgr is not None:
-                ckpt_mgr.install_sigterm_hook()
-
-            from ..base import to_numpy as _np_of
-            from ..pipeline import feed_or_inline, close_feed, BlockStager
-            from ..telemetry import maybe_step_logger
-            slog = maybe_step_logger("module_fit_fused", meta={
-                "optimizer": optimizer, "steps_per_dispatch": int(k),
-                "batch_size": int(batch_size), "begin_epoch": begin_epoch,
-                "num_epoch": num_epoch,
-                "amp_dtype": fit_dtype if fit_dtype != "float32" else None})
             data_idx = {n: i for i, n in enumerate(self._data_names)}
             label_idx = {n: i for i, n in enumerate(self._label_names)}
+            epoch_start = None
 
-            def _blocks(data_iter):
-                while True:
-                    block = list(itertools.islice(data_iter, k))
-                    if not block:
-                        return
-                    yield block
+            def batches(epoch):
+                nonlocal epoch_start
+                epoch_start = time.time()
+                eval_metric.reset()
+                return iter(train_data)
 
-            stager = BlockStager(trainer.shard_inputs)
-
-            def _stage_block(block):
-                # host stack + device commit run on the feeder thread: block
-                # N+1 is staged while block N's fused scan executes. The
-                # stager copies into host buffers of its own before it
-                # returns, so iterator buffer reuse is safe; a short tail
-                # block compiles its own (cached) k'-step scan
-                columns = [
+            def columns(block):
+                cols = [
                     [_np_of(b.data[data_idx[name]]) if name in data_idx
                      else _np_of(b.label[label_idx[name]]) for b in block]
                     for name in trainer.input_names]
-                inputs = stager(columns, stacked=True)
+                # the labels ride beside the staged block to the metric
                 labels = {
                     name: np.concatenate([_np_of(b.label[i]) for b in block])
                     for name, i in label_idx.items()}
-                return inputs, labels, len(block)
+                return cols, labels
 
-            def _ckpt_capture(next_epoch, next_batch):
-                # synchronous snapshot of the (donated) device tuples — must
-                # happen between dispatches; the atomic write itself still
-                # overlaps the following steps on the saver thread
-                from ..checkpoint.state import TrainingState
-                from .. import random as _random
-                arrays, tmeta = trainer.export_training_state(params, states,
-                                                              aux)
-                return TrainingState(arrays=arrays, meta={
-                    "kind": "module_fused", "epoch": int(next_epoch),
-                    "batch": int(next_batch), "step": int(gstep),
-                    "batch_size": int(batch_size),
-                    "trainer": tmeta, "rng": _random.get_state(),
-                    "amp_dtype": fit_dtype if fit_dtype != "float32"
-                    else None})
+            def update_metric(losses, outputs, label_np, n_blk):
+                # metric over ALL K batches at once: flatten the scan axis
+                # into the batch axis (same samples K=1 would feed one by
+                # one, one update call instead of K)
+                pred_dict = {
+                    name: NDArray(o.reshape((-1,) + o.shape[2:]))
+                    for name, o in zip(self._output_names, outputs)}
+                label_dict = {name: NDArray(v)
+                              for name, v in label_np.items()}
+                eval_metric.update_dict(label_dict, pred_dict)
 
-            for epoch in range(begin_epoch, num_epoch):
-                epoch_start = time.time()
-                eval_metric.reset()
-                src = iter(train_data)
-                if ckpt_skip:
-                    self.logger.info(
-                        "checkpoint: fast-forwarding %d batches to the "
-                        "saved cursor", ckpt_skip)
-                    for _ in itertools.islice(src, ckpt_skip):
-                        pass
-                nbatch = ckpt_skip
-                ckpt_skip = 0
-                last_ckpt = gstep
-                feed = feed_or_inline(_blocks(src), _stage_block,
-                                      name="module_fit_fused")
-                try:
-                    for seq, (inputs, label_np, n_blk) in enumerate(feed):
-                        # "compute" span: the fused dispatch plus the
-                        # metric update that syncs on its outputs — i.e.
-                        # the device-bound slice of the loop body. Its
-                        # two halves have spans of their own (no phase:
-                        # the parent's time is the phase's)
-                        with _tracing.span("step.fused_dispatch",
-                                           phase="compute", k=n_blk,
-                                           seq=seq):
-                            # returns once the scan is enqueued, before
-                            # the device ends
-                            with _tracing.span("step.enqueue"):
-                                params, states, aux, losses, outputs = \
-                                    trainer.step_k(params, states, aux,
-                                                   inputs,
-                                                   outputs_mode="all")
-                            # metric over ALL K batches at once: flatten
-                            # the scan axis into the batch axis (same
-                            # samples K=1 would feed one by one, one
-                            # update call instead of K)
-                            with _tracing.span("step.metric_update"):
-                                pred_dict = {
-                                    name: NDArray(
-                                        o.reshape((-1,) + o.shape[2:]))
-                                    for name, o in zip(self._output_names,
-                                                       outputs)}
-                                label_dict = {
-                                    name: NDArray(v)
-                                    for name, v in label_np.items()}
-                                eval_metric.update_dict(label_dict,
-                                                        pred_dict)
-                        # one record per fused dispatch (K steps); the
-                        # metric update above already synced on outputs,
-                        # so the wall time covers real device work
-                        with _tracing.span("step.log", seq=seq):
-                            slog.step(samples=n_blk * batch_size,
-                                      steps=n_blk, extra={"epoch": epoch})
-                        nbatch += n_blk
-                        gstep += n_blk
-                        if batch_callbacks:
-                            with _tracing.span("step.callbacks", seq=seq):
-                                cb_param = BatchEndParam(
-                                    epoch=epoch, nbatch=nbatch - 1,
-                                    eval_metric=eval_metric,
-                                    locals=locals())
-                                for callback in batch_callbacks:
-                                    callback(cb_param)
-                        if ckpt_mgr is not None:
-                            if checkpoint_period and \
-                                    gstep - last_ckpt >= \
-                                    int(checkpoint_period):
-                                with _tracing.span("step.checkpoint",
-                                                   seq=seq):
-                                    ckpt_mgr.save(
-                                        _ckpt_capture(epoch, nbatch),
-                                        step=gstep)
-                                last_ckpt = gstep
-                            if ckpt_mgr.preempted:
-                                with _tracing.span("step.checkpoint",
-                                                   seq=seq):
-                                    ckpt_mgr.save(
-                                        _ckpt_capture(epoch, nbatch),
-                                        step=gstep, blocking=True)
-                                raise SystemExit(143)
-                finally:
-                    close_feed(feed)
+            def call_back(view):
+                cb_param = BatchEndParam(
+                    epoch=view["epoch"], nbatch=view["nbatch"] - 1,
+                    eval_metric=eval_metric, locals=view)
+                for callback in batch_callbacks:
+                    callback(cb_param)
 
+            def end_epoch(epoch, arg_np, aux_np):
+                nonlocal homes
                 for name, val in eval_metric.get_name_value():
                     self.logger.info("Epoch[%d] Train-%s=%f", epoch, name,
                                      val)
                 self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
                                  time.time() - epoch_start)
-
                 # write the device-carried state back so checkpoints/
                 # callbacks/validation see the trained params exactly as
-                # K=1 would. COPIES (np.asarray), not the live buffers:
-                # step_k donates its params, so aliasing them into the
-                # executor would leave it holding deleted arrays after the
-                # next epoch's first dispatch
-                self.set_params(
-                    {n: NDArray(v)
-                     for n, v in trainer.host_params(params).items()},
-                    {n: NDArray(v)
-                     for n, v in trainer.host_aux(aux).items()})
+                # K=1 would
+                self.set_params({n: NDArray(v) for n, v in arg_np.items()},
+                                {n: NDArray(v) for n, v in aux_np.items()})
                 snapshot_args, snapshot_aux = self.get_params()
                 for callback in epoch_callbacks:
                     callback(epoch, self.symbol, snapshot_args,
                              snapshot_aux)
-
-                if ckpt_mgr is not None:
-                    vals = eval_metric.get_name_value()
-                    ckpt_mgr.save(_ckpt_capture(epoch + 1, 0), step=gstep,
-                                  metric=float(vals[0][1]) if vals
-                                  else None)
-                    if ckpt_mgr.preempted:
-                        ckpt_mgr.wait()
-                        raise SystemExit(143)
-
+                vals = eval_metric.get_name_value()
                 if eval_data is not None:
                     # score runs the executor: its arrays go back for it
                     self._params_to_devices(homes)
@@ -716,26 +579,22 @@ class Module(BaseModule):
                                          epoch, name, val)
                     homes = self._params_to_host()
                 train_data.reset()
+                return float(vals[0][1]) if vals else None
+
+            loop.run(k, batch_size, begin_epoch, num_epoch, batches, columns,
+                     update_metric, end_epoch,
+                     after_block=call_back if batch_callbacks else None,
+                     outputs_mode="all", optimizer=optimizer,
+                     amp_dtype=fit_dtype if fit_dtype != "float32" else None)
         finally:
             # whatever ended the fit, the module holds its arrays where it
             # held them before, with the last values written back; the
             # trainer's state goes first, so that the two need not fit the
             # device together (a callback that kept `locals` keeps it)
-            params = states = aux = cb_param = None
+            loop.release()
             if homes is not None:
                 self._params_to_devices(homes)
-            # run_end carries the step program's XLA cost digest (which
-            # program the per-step MFU was measured against, its
-            # FLOPs/bytes per step, the peak table in force)
-            from ..telemetry import devstats as _devstats
-            if slog is not None:
-                try:
-                    slog.close(**_devstats.fit_summary())
-                except Exception:
-                    slog.close()
-            if ckpt_mgr is not None:
-                ckpt_mgr.remove_sigterm_hook()
-                ckpt_mgr.close()
+            loop.close()
         return True
 
     # -- optimizer -----------------------------------------------------------

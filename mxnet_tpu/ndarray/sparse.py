@@ -9,8 +9,8 @@ components (ops/sparse_ops.py), and `sparse.dot` / the optimizers'
 row_sparse lazy path dispatch to gather/scatter kernels whose work
 scales with nnz instead of the dense shape (reference kernels:
 src/operator/tensor/dot-inl.h, src/operator/optimizer_op.cc sparse
-variants). Measured crossover vs dense on the real chip:
-tools/sparse_bench.py + PARITY.md.
+variants). The crossover against dense on the chip is not measured on
+this runtime.
 """
 from __future__ import annotations
 

@@ -27,8 +27,8 @@ _LSE_LANES = 8    # minor replication of the per-row lse (TPU block tiling)
 def _auto_block(s):
     """Default block size: the LARGEST of 512/256/128 dividing S —
     bigger tiles amortize the per-block softmax bookkeeping and keep the
-    MXU busier (tools/attention_sweep.py sweeps the curve; its gain on
-    this runtime is a claim to re-measure, ROADMAP S4). Sequences not
+    MXU busier (its gain on this runtime is a claim to re-measure,
+    ROADMAP S4). Sequences not
     divisible by 128 fall back to a single block (small-S case)."""
     for blk in (512, 256, 128):
         if s % blk == 0:
@@ -565,7 +565,7 @@ def flash_attention(q, k, v, causal=False, scale=None, force=None,
     GQA/MQA: k/v may carry fewer heads than q (H % H_kv == 0) — the
     kernels stream the SHARED kv blocks (no repeated copy; dK/dV group
     partials reduce outside the kernel). block_q/block_k override the
-    default 128 tiling (tools/attention_sweep.py measures the curve).
+    default 128 tiling.
     """
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)

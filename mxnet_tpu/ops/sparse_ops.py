@@ -9,8 +9,8 @@ the TPU-first realization is the ELL (padded-row) layout: a CSR matrix
 static-shaped gathers/scatters XLA lowers to its native dynamic-gather/
 scatter HLOs — compute and memory scale with nnz (R*K), not with the
 dense (R, F) / (F, M) sizes. NDArray-level dispatch lives in
-ndarray/sparse.py; the measured dense-vs-sparse crossover on the real
-chip is recorded in tools/sparse_bench.py + PARITY.md.
+ndarray/sparse.py; where sparse beats dense on the chip has not been
+measured on this runtime (no benchmark cell runs these kernels).
 """
 from __future__ import annotations
 

@@ -40,8 +40,9 @@ class DataParallelTrainer:
     Parameters are replicated; `data_names`/`label_names` inputs are sharded
     on axis 0 over the mesh's `data` axis. The optimizer update (any op in
     _OPT_OPS) is fused into the step; the learning rate and step count ride
-    as traced scalars so schedules never retrace. This is the fully-fused
-    engine behind bench.py and the dryrun_multichip driver hook.
+    as traced scalars so schedules never retrace. This is the engine of
+    the fused training loop (parallel/fused_loop.py) and of the
+    dryrun_multichip driver hook.
     """
 
     def __new__(cls, *args, **kwargs):
@@ -93,11 +94,11 @@ class DataParallelTrainer:
         # half precision = multi-precision training (reference optimizer
         # multi_precision, SURVEY §7 hard-part 5): fp32 master params/aux,
         # compute + activations + the gradient all-reduce in the half
-        # dtype, grads upcast into the fused fp32 update. ~1.7x step
-        # throughput on v5e for ResNet-50, and the half-width all-reduce
-        # halves the wire bytes of the collective-bound dp step
-        # (MULTICHIP_r05: 5.9ms -> 28.3ms from 1 -> 8 devices was one
-        # sync fp32 all-reduce).
+        # dtype, grads upcast into the fused fp32 update. The half-width
+        # all-reduce halves the wire bytes of the dp step (asserted by
+        # `python -m mxnet_tpu.amp --hlo-check`); every cell of the
+        # benchmark trains in bf16, so PERF_LEDGER.jsonl has no fp32 rate
+        # to compare with.
         self._compute_bf16 = dtype == "bfloat16"
         self._dtype = dtype
         compute_dtype = {"float32": None, "bfloat16": jnp.bfloat16,
@@ -296,7 +297,7 @@ class DataParallelTrainer:
 
         # the loss-scaler state rides the step signature ONLY for fp16:
         # fp32/bf16 keep the 7-arg step so existing lower()/cost-analysis
-        # call sites (bench.py, __graft_entry__) stay valid
+        # call sites (__graft_entry__, chip_smoke.py) stay valid
         if has_ls:
             def step(params, states, aux, inputs, rng, lr, t, ls):
                 return _step_impl(params, states, aux, inputs, rng, lr,
@@ -338,7 +339,7 @@ class DataParallelTrainer:
             + ls_extra,
             donate_argnums=(0, 1))
 
-    def _multi_step_fn(self, k, outputs_mode, unroll=False):
+    def _multi_step_fn(self, k, outputs_mode):
         """K training steps fused into ONE compiled dispatch (a lax.scan
         over the single-step body). This is the op-bulking concern of the
         reference engine (graph_executor.cc:1343-1369) applied at step
@@ -348,15 +349,11 @@ class DataParallelTrainer:
         rng, the step counter and (fp16) the loss-scaler state are carried
         on-device across the scan, so K fused steps are bit-identical to K
         python-dispatched steps — including grow/backoff/skip decisions."""
-        # True==1 as a dict key but lax.scan treats them differently
-        # (True = full unroll, 1 = rolled): normalize True to "full"
-        key = (int(k), outputs_mode,
-               "full" if unroll is True else max(1, int(unroll)))
+        key = (int(k), outputs_mode)
         fn = self._multi.get(key)
         if fn is not None:
             return fn
         step = self._step_py
-        unroll_arg = True if key[2] == "full" else key[2]
 
         if self._has_ls:
             def multi(params, states, aux, inputs, rng, lr, t, ls):
@@ -369,7 +366,7 @@ class DataParallelTrainer:
 
                 (params, states, aux, rng, t, ls), ys = jax.lax.scan(
                     body, (params, states, aux, rng, t, ls), inputs,
-                    length=key[0], unroll=unroll_arg)
+                    length=key[0])
                 if outputs_mode == "all":
                     losses, outputs = ys
                 else:
@@ -386,7 +383,7 @@ class DataParallelTrainer:
 
                 (params, states, aux, rng, t), ys = jax.lax.scan(
                     body, (params, states, aux, rng, t), inputs,
-                    length=key[0], unroll=unroll_arg)
+                    length=key[0])
                 if outputs_mode == "all":
                     losses, outputs = ys
                 else:
@@ -652,7 +649,7 @@ class DataParallelTrainer:
         return out[:5]
 
     def step_k(self, params, states, aux, inputs, rng=None,
-               outputs_mode="none", unroll=False):
+               outputs_mode="none"):
         """Run K fused training steps in ONE dispatch (steps_per_dispatch).
 
         `inputs` are (K, batch, ...) stacked blocks (shard_inputs with
@@ -666,17 +663,10 @@ class DataParallelTrainer:
             training metric).
         Bit-identical to K step() calls from the same rng key: the scan
         body IS the single-step body and the key chain is the same splits.
-
-        `unroll=True` unrolls the K-step scan into straight-line code:
-        K x compile time, but programs whose step itself contains
-        lax.while/scan loops (RNNs) avoid the nested-loop overhead XLA
-        adds around inner loops (measured on v5e: the LSTM LM step's
-        inner whiles run 3x slower under an outer rolled scan; unrolled
-        they run at single-step device speed).
         """
         self._ensure_dev_state(rng)
         k = int(inputs[0].shape[0])
-        fn = self._multi_step_fn(k, outputs_mode, unroll)
+        fn = self._multi_step_fn(k, outputs_mode)
         from ..telemetry import devstats
         if self._has_ls:
             args = (params, states, aux, inputs, self._rng_dev,

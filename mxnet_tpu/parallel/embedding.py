@@ -54,8 +54,8 @@ CLI: ``python -m mxnet_tpu.parallel.embedding --selftest`` (tiny-DLRM
 convergence, dense-vs-sparse bit-identity when every row is touched,
 checkpoint resume across sharding changes, wire proof), ``--hlo-check``
 (post-SPMD collective/wire report at a given vocab), ``--bench``
-(bench.py's `dlrm` lane: sparse vs dense steps/s + wire bytes at ≤5%
-touched rows).
+(8 virtual CPU devices: sparse vs dense wire bytes at ≤5% touched rows;
+its steps/s are a CPU mesh's, not the chip's).
 """
 from __future__ import annotations
 
@@ -687,7 +687,7 @@ class EmbeddingTrainer:
 
 
 # ============================================================================
-# CLI: --selftest / --hlo-check / --bench  (tools/ci.sh quick + bench.py)
+# CLI: --selftest / --hlo-check / --bench  (tools/ci.sh quick)
 # ============================================================================
 
 def _click_data(vocab, batch, slots, dense_dim, seed=0, structured=True):
@@ -916,7 +916,7 @@ def hlo_check(exchange, compress="none", vocab=2048, devices=2,
 
 
 def bench(devices=8, steps=10, vocab=65536, dim=48, batch=256, slots=8):
-    """bench.py's `dlrm` lane body: sparse vs dense gradient exchange
+    """`--bench`: sparse vs dense gradient exchange
     on an N-virtual-device cpu mesh at a ≤5% touched-row fraction (the
     regime the row-sparse exchange exists for). Reports steps/s A/B,
     HLO-measured wire bytes per step for both arms, and the touched-row

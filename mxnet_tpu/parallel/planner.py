@@ -51,7 +51,8 @@ single-mode paths (tests/test_planner.py asserts this).
 
 CLI: ``--selftest`` (determinism, pruning-before-compile, degenerate
 parity, ZeRO-over-dp×tp trajectory — tools/ci.sh quick), ``--explain``
-(the per-candidate score table), ``--bench`` (bench.py's `plan` lane),
+(the per-candidate score table), ``--bench`` (auto vs hand-picked plans
+on 8 virtual CPU devices),
 ``--hlo-audit`` (hloaudit's fit_step_plan subprocess body).
 """
 from __future__ import annotations
@@ -820,7 +821,7 @@ def explain(plan_spec="auto", devices=8):
 
 
 def bench(devices=8, steps=8):
-    """bench.py's `plan` lane body: MXNET_PLAN=auto vs hand-picked dp
+    """`--bench`: MXNET_PLAN=auto vs hand-picked dp
     and zero2 on the transformer-scale arm (wide FC stack, small batch,
     adam — parameter gather/reduce wire and de-replicated update work
     dominate). Reports measured steps/s per arm, the planner's decision
@@ -960,7 +961,7 @@ def main(argv=None):
     ap.add_argument("--explain", action="store_true",
                     help="per-candidate score table for the auto plan")
     ap.add_argument("--bench", action="store_true",
-                    help="auto vs hand dp/zero2 steps/s (bench.py)")
+                    help="auto vs hand dp/zero2 on a virtual CPU mesh")
     ap.add_argument("--hlo-audit", action="store_true",
                     help="fit_step_plan subprocess body (hloaudit)")
     ap.add_argument("--plan", default="auto")

@@ -48,9 +48,9 @@ Env surface: ``MXNET_ZERO_STAGE=0|1|2`` (0 = plain dp; >0 reroutes
 
 CLI: ``python -m mxnet_tpu.parallel.zero --selftest`` (2-device A/B:
 bitwise stage-1 parity, fp8 convergence, HLO wire-byte reduction),
-``--hlo-check`` (post-SPMD collective report), ``--bench`` (8-device
-dp vs ZeRO-1 vs ZeRO-2 vs +fp8 steps/s + wire bytes — bench.py's
-``zero`` lane).
+``--hlo-check`` (post-SPMD collective report), ``--bench`` (8 virtual
+CPU devices: dp vs ZeRO-1 vs ZeRO-2 vs +fp8, wire bytes a step; its
+steps/s are a CPU mesh's, not the chip's).
 """
 from __future__ import annotations
 
@@ -635,9 +635,8 @@ class ZeroTrainer(DataParallelTrainer):
             out_shardings=tuple(ns(s) for s in out_specs),
             donate_argnums=(0, 1, 2))
 
-    def _zero_multi_fn(self, k, outputs_mode, unroll=False):
-        key = (int(k), outputs_mode,
-               "full" if unroll is True else max(1, int(unroll)))
+    def _zero_multi_fn(self, k, outputs_mode):
+        key = (int(k), outputs_mode)
         fn = self._zero_multi.get(key)
         if fn is not None:
             return fn
@@ -646,7 +645,6 @@ class ZeroTrainer(DataParallelTrainer):
         has_ls = self._has_ls
         ax = self._data_axis
         mesh = self._mesh
-        unroll_arg = True if key[2] == "full" else key[2]
 
         if has_ls:
             def multi(masters, states, resid, aux, inputs, rng, lr, t,
@@ -663,8 +661,7 @@ class ZeroTrainer(DataParallelTrainer):
                 (masters, states, resid, aux, rng, t, ls), ys = \
                     jax.lax.scan(body,
                                  (masters, states, resid, aux, rng, t,
-                                  ls), inputs, length=key[0],
-                                 unroll=unroll_arg)
+                                  ls), inputs, length=key[0])
                 losses, outputs = ys if outputs_mode == "all" \
                     else (ys, ())
                 return (masters, states, resid, aux, losses, outputs,
@@ -682,7 +679,7 @@ class ZeroTrainer(DataParallelTrainer):
 
                 (masters, states, resid, aux, rng, t), ys = jax.lax.scan(
                     body, (masters, states, resid, aux, rng, t), inputs,
-                    length=key[0], unroll=unroll_arg)
+                    length=key[0])
                 losses, outputs = ys if outputs_mode == "all" \
                     else (ys, ())
                 return (masters, states, resid, aux, losses, outputs,
@@ -753,13 +750,13 @@ class ZeroTrainer(DataParallelTrainer):
         return out[0], out[1], out[3], out[4], out[5]
 
     def step_k(self, params, states, aux, inputs, rng=None,
-               outputs_mode="none", unroll=False):
+               outputs_mode="none"):
         if self._zstep is None:
             raise MXNetError("ZeroTrainer.step_k before init_state/"
                              "import_training_state")
         self._ensure_dev_state(rng)
         k = int(inputs[0].shape[0])
-        fn = self._zero_multi_fn(k, outputs_mode, unroll)
+        fn = self._zero_multi_fn(k, outputs_mode)
         from ..telemetry import devstats
         name = "zero%d.step_k%d" % (self._zero_stage, k)
         if self._has_ls:
@@ -853,7 +850,7 @@ class ZeroTrainer(DataParallelTrainer):
 
 
 # ============================================================================
-# CLI: --selftest / --hlo-check / --bench  (tools/ci.sh quick + bench.py)
+# CLI: --selftest / --hlo-check / --bench  (tools/ci.sh quick)
 # ============================================================================
 
 def _wide_sym(dim=64, hidden=256, nclass=16):
@@ -1079,7 +1076,7 @@ def hlo_check(stage, compress="none", dtype="float32", devices=2):
 
 
 def bench(devices=8, steps=12, hidden=1024, batch=16):
-    """bench.py's `zero` lane body: dp fp32 vs ZeRO-1 vs ZeRO-2 vs
+    """`--bench`: dp fp32 vs ZeRO-1 vs ZeRO-2 vs
     ZeRO-2+fp8 on an N-virtual-device cpu mesh, one big-parameter Adam
     MLP (optimizer-update work dominates, which is exactly the work
     ZeRO de-replicates: dp updates ALL params on EVERY device; ZeRO

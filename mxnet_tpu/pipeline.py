@@ -46,10 +46,10 @@ Mechanics:
     `feed.wait`. Each counter is fed from its span's own clock reads.
 
 The loops threaded through it: Module/BaseModule.fit, the fused K-step
-drivers (Module._fit_fused, gluon.trainer.fused_fit), BaseModule.score /
-predict, and ServingEngine.warmup. `MXNET_DEVICE_FEED=0` restores the
-fully synchronous path everywhere (the bench.py `pipeline` lane measures
-the two against each other).
+loop (parallel/fused_loop.py, behind Module._fit_fused and
+gluon.trainer.fused_fit), BaseModule.score / predict, and
+ServingEngine.warmup. `MXNET_DEVICE_FEED=0` restores the fully synchronous
+path everywhere.
 """
 from __future__ import annotations
 

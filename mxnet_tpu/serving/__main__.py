@@ -110,7 +110,7 @@ def _batched_qps(batcher, sample, requests, concurrency):
 def selftest(path=None, requests=256, concurrency=8, max_wait_us=2000,
              queue_depth=256, min_speedup=2.0):
     """Run the sequential-vs-batched comparison; returns the result
-    dict (also usable programmatically — tools/serving_bench.py)."""
+    dict (also usable programmatically: tests/test_serving.py)."""
     from . import DynamicBatcher, ServingEngine
     if path is None:
         path = _export_tiny_convnet()

@@ -600,7 +600,7 @@ def selftest(requests=512, concurrency=64, replicas=2,
 
 def bench(requests=768, concurrency=64, replicas=2, batch_frac=0.25,
           deadline_ms=15000):
-    """One mixed-priority closed loop for bench.py's serving_net lane:
+    """`--bench`: one mixed-priority closed loop;
     prints QPS / p50 / p99 / shed fraction at `concurrency`."""
     tmp = tempfile.mkdtemp(prefix="mxa_frontend_bench_")
     try:

@@ -19,7 +19,7 @@ Beyond-reference subsystem (docs/TELEMETRY.md). Four pieces:
   - **hang diagnostics** (watchdog.py): stall watchdog
     (`MXNET_TELEMETRY_STALL_S`) dumping all-thread stacks when a step
     stalls, SIGUSR1 on-demand dumps, and deadline dumps for budgeted
-    harnesses (bench.py). Stall dumps append the flight-recorder tail.
+    harnesses. Stall dumps append the flight-recorder tail.
   - **span tracing** (tracing.py): `MXNET_TRACE=1` host-side spans over
     feed/compute/comm/ckpt/serve phases, per-rank `trace-rank-K.json`
     chrome-trace shards with clock metadata, and `--merge` fusing a

@@ -80,8 +80,8 @@ _WORKER = None
 
 # Published peaks of one chip, keyed by jax's `device_kind`: (bf16
 # FLOP/s, HBM bytes/s). Source: Google Cloud documentation, "TPU v5e"
-# (197 TFLOP/s bf16, 819 GB/s). The ONE peaks table of the repo — bench.py
-# and the planner read it. A TPU kind that is not here is an error, not a
+# (197 TFLOP/s bf16, 819 GB/s). The package's one peaks table (the planner
+# reads it; the benchmark keeps its own, benchmarks/peaks.json). A TPU kind that is not here is an error, not a
 # default; the CPU has no row, so a CPU run reports no MFU. Override with
 # MXNET_DEVSTATS_PEAK_TFLOPS / MXNET_DEVSTATS_PEAK_GBPS.
 PEAKS = {

@@ -17,7 +17,7 @@ Two sinks, both cheap:
 
 Hot-path discipline: no device syncs originate here. Loss is only
 recorded when the loop passes an already-host-side float; amp counters
-are sampled only while amp is enabled (the fused loops have already
+are sampled only while amp is enabled (the fused loop has already
 synchronized on the loss/metric by the time step() runs); DeviceFeed and
 checkpoint counters are plain host dicts. Every step() also beats the
 stall watchdog, so an armed watchdog learns liveness for free.

@@ -293,3 +293,66 @@ def test_gluon_fused_fit_rejects_exhausted_generator():
     with pytest.raises(mx.MXNetError, match="no batches"):
         gluon.trainer.fused_fit(net, loss, gen, num_epoch=2,
                                 steps_per_dispatch=2)
+
+
+@pytest.mark.parametrize("front", ["module", "gluon"])
+def test_fused_loop_view_contract_and_release(front, monkeypatch):
+    """What a batch_end_callback finds in `param.locals` under
+    Module.fit(steps_per_dispatch=K) is parallel.fused_loop's view: the
+    names the benchmark's runners and chip_smoke index, with their shapes.
+    And for either front end: once the fit has returned and a callback's
+    own view is dropped, nothing holds the trainer's state."""
+    import gc
+    import weakref
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import nn
+    k, batch = 2, 32
+    last = []          # a weak reference to a parameter of the last state
+    real_step_k = DataParallelTrainer.step_k
+
+    def step_k(self, params, states, aux, inputs, **kwargs):
+        out = real_step_k(self, params, states, aux, inputs, **kwargs)
+        last[:] = [weakref.ref(out[0][0])]
+        return out
+    monkeypatch.setattr(DataParallelTrainer, "step_k", step_k)
+
+    views = []
+    if front == "module":
+        mod = mx.mod.Module(_mlp(), context=mx.cpu(0))
+        mod.fit(_digits_iter(batch=batch, n=4 * batch), num_epoch=1,
+                optimizer="sgd", optimizer_params={"learning_rate": 0.1},
+                initializer=mx.init.Xavier(), steps_per_dispatch=k,
+                batch_end_callback=lambda p: views.append(p.locals))
+        assert len(views) == 2
+        view = views[-1]
+        assert {"trainer", "params", "states", "aux", "inputs", "outputs",
+                "losses", "epoch", "nbatch", "n_blk"} <= set(view)
+        trainer = view["trainer"]
+        assert isinstance(trainer, DataParallelTrainer)
+        assert [tuple(x.shape) for x in view["inputs"]] == \
+            [(k, batch, 8), (k, batch)]
+        assert [tuple(o.shape) for o in view["outputs"]] == [(k, batch, 3)]
+        assert tuple(view["losses"].shape) == (k,)
+        assert (view["epoch"], view["nbatch"], view["n_blk"]) == (0, 4, k)
+        assert len(view["params"]) == len(view["states"]) == \
+            len(trainer.param_names)
+        host = trainer.host_params(view["params"])
+        trained = mod.get_params()[0]
+        assert set(host) == set(trained)
+        for n in host:       # the last view's state is what was written back
+            np.testing.assert_array_equal(host[n], trained[n].asnumpy())
+        assert view["params"][0] is last[0]()
+        del trainer, host, trained
+    else:
+        net = nn.HybridSequential()
+        with net.name_scope():
+            net.add(nn.Dense(3))
+        net.initialize(mx.init.Xavier())
+        data = [(mx.nd.array(x), mx.nd.array(y))
+                for x, y in _batches(4, batch)]
+        gluon.trainer.fused_fit(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                                data, num_epoch=1, steps_per_dispatch=k)
+    view = None
+    views.clear()
+    gc.collect()
+    assert last and last[0]() is None

@@ -3,9 +3,8 @@
 The group2ctx path must (a) compile once per stage — not retrace per
 step, (b) place each stage's compute on its group's device, (c) match
 the single-program executor numerically for forward, backward, and aux
-updates, and (d) beat the old eager per-op walk by a wide margin (the
-microbench lives in tools/mp_bench.py; here we pin the compile counts
-that make the speedup structural).
+updates, and (d) not fall back to the old eager per-op walk (here we pin
+the compile counts that make the difference structural).
 """
 import numpy as np
 import pytest

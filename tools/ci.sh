@@ -5,9 +5,8 @@
 #   tools/ci.sh quick    — install + 30s cross-subsystem smoke tier
 #   tools/ci.sh full     — install + full CPU-mesh suite (~15 min)
 #   tools/ci.sh tpu      — real-chip lane (needs a TPU backend)
-#   tools/ci.sh bench    — canonical perf JSON line (needs a TPU)
 #
-# All stages run on the 8-device virtual CPU mesh except tpu/bench.
+# All stages run on the 8-device virtual CPU mesh except tpu.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -82,9 +81,7 @@ case "$stage" in
     # needs the chip: each is its own process, one after the other
     python chip_smoke.py
     python -m pytest tests_tpu/ -q ;;
-  bench)
-    python bench.py ;;
   *)
-    echo "unknown stage: $stage (quick|full|tpu|bench)" >&2; exit 2 ;;
+    echo "unknown stage: $stage (quick|full|tpu)" >&2; exit 2 ;;
 esac
 echo "== ci stage '$stage' green"
