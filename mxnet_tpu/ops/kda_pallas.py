@@ -408,12 +408,12 @@ def _specs(h, chunk, dk, dv, reverse, n):
     return cells, (head(dk), head(dv), beta, state), per
 
 
-def _compiler_params(interpret):
+def _compiler_params(interpret, grid=("parallel", "arbitrary")):
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))}
+        dimension_semantics=grid)}
 
 
 # Both calls are jitted by themselves: a model's layers then share one trace
@@ -422,18 +422,20 @@ def _compiler_params(interpret):
 _STATIC = ("chunk", "sub", "interpret", "save_states")
 
 
-def _under_scope(f):
-    """Run f under the scope `mx.kda.core`, where the benchmark's readers
-    look for the kernels, forward and backward."""
-    @functools.wraps(f)
-    def scoped(*args, **kwargs):
-        with jax.named_scope("mx.kda.core"):
-            return f(*args, **kwargs)
-    return scoped
+def _under_scope(name):
+    """Run a function under the scope `name`, where the benchmark's readers
+    look for its kernels, forward and backward."""
+    def wrap(f):
+        @functools.wraps(f)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-@_under_scope
+@_under_scope("mx.kda.core")
 def _forward(q, k, v, g, beta, chunk, sub, interpret, save_states):
     """o (B, S, H, dv) in q's dtype and, if asked, the chunk-start states
     (B*H, N, dv, dk) float32."""
@@ -467,7 +469,7 @@ def _forward(q, k, v, g, beta, chunk, sub, interpret, save_states):
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC[:3])
-@_under_scope
+@_under_scope("mx.kda.core")
 def _backward(q, k, v, g, beta, states, do, chunk, sub, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -504,12 +506,13 @@ def _backward(q, k, v, g, beta, states, do, chunk, sub, interpret):
             db[:, :s].astype(beta.dtype))
 
 
-def eligible(dtype, dk, dv, chunk, platform=None):
+def eligible(dtype, dk, dv, chunk, platform=None, kernel=4):
     """The kernels take a program for a TPU whose operands are bf16, whose
-    head widths are multiples of 128 and whose chunk is one of CHUNKS."""
+    head widths are multiples of 128, whose chunk is one of CHUNKS and whose
+    short convolution reaches back no further than the staged HALO."""
     return ((platform or jax.default_backend()) == "tpu"
             and dtype == jnp.bfloat16 and dk % 128 == 0 and dv % 128 == 0
-            and chunk in CHUNKS)
+            and chunk in CHUNKS and kernel - 1 <= HALO)
 
 
 def kda_kernels(q, k, v, g, beta, chunk=64, interpret=False):
@@ -530,3 +533,296 @@ def kda_kernels(q, k, v, g, beta, chunk=64, interpret=False):
 
     fn.defvjp(fwd, bwd)
     return fn(q, k, v, g, beta)
+
+
+# -- prepare: the core's operands from the mixer's projections ------------
+#
+# `ops/lm.py::kda_prepare` is the algorithm and the reference: a causal short
+# convolution and SiLU on q, k and v, the L2 normalisation of q and k over each
+# head, and the log-decay g = -exp(A_log[h]) * softplus(f + dt_bias). Here it
+# is ONE pass over the (B, S, H*d) arrays as they arrive, forward, and one
+# pass backward: a grid cell is PREP_ROWS tokens of one head's columns (the
+# convolution is per channel, the normalisation per head, so heads are
+# independent), staged as float32 in VMEM under the last rows of the block
+# before it (an overlapping read), which the taps then reach with shifted
+# loads. Everything between the bf16 operands and the bf16 results is float32
+# (the XLA path multiplies and adds the convolution in the operands' dtype);
+# g is float32 as there. The backward keeps nothing but the inputs: it
+# rebuilds a block's forward, walks the row blocks last to first with the
+# first rows of the later block's convolution gradient carried in VMEM, and
+# leaves the gradients of the small parameters as per-(batch, channel) sums
+# that XLA adds.
+
+# rows a grid cell and rows worked on at a time (what the registers hold). On
+# a v5e at 2 x 8,192 x 32 x 128 one head a cell beat two, four and eight, 512
+# rows beat 128 and 256 (fewer halos and carries), and a tile of 64 beat 32
+# (PERF.md, PR 29)
+PREP_ROWS = 512
+PREP_TILE = 64
+HALO = 8                # rows staged before a block: a tap reaches back kw - 1
+_HALO_BLOCK = 16        # rows of the overlapping read: a tile of bf16
+L2_EPS = 1e-6           # lm._l2_normalize's
+
+
+def _softplus(x):
+    return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+
+
+def _stage(stage, x_ref, halo_ref, first, kept=lambda x, t0: x):
+    """A block as float32 rows [HALO, HALO + rows) of its staging buffer,
+    under the last HALO rows of the block before it: zeros before the
+    sequence's start (`first`). `kept` selects rows past its end away."""
+    import jax.experimental.pallas as pl
+    rows, cols = x_ref.shape
+
+    @pl.when(first)
+    def _():
+        stage[0:HALO, 0:cols] = jnp.zeros((HALO, cols), _F32)
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        stage[0:HALO, 0:cols] = halo_ref[...].astype(_F32)[-HALO:]
+
+    stage[HALO:HALO + rows, 0:cols] = kept(x_ref[...].astype(_F32), 0)
+
+
+def _conv_gate(stage, t0, tile, w_ref):
+    """For `tile` rows from t0 of a staged block: the rows each tap
+    multiplies, the convolution y and sigmoid(y)."""
+    kw, cols = w_ref.shape
+    first = t0 + HALO - (kw - 1)
+    taps = [stage[first + j:first + j + tile, 0:cols] for j in range(kw)]
+    y = sum(w_ref[j:j + 1, :] * x for j, x in enumerate(taps))
+    return taps, y, jax.nn.sigmoid(y)
+
+
+def _tiles(rows):
+    """(first row, rows, slice) of the tiles a block is worked in. They
+    unroll: a shifted load needs an offset that Mosaic can see. A short
+    sequence's one block may end in a shorter tile."""
+    return [(t0, min(PREP_TILE, rows - t0), slice(t0, min(t0 + PREP_TILE,
+                                                         rows)))
+            for t0 in range(0, rows, PREP_TILE)]
+
+
+def _prep_fwd_kernel(q_ref, k_ref, v_ref, f_ref, hq_ref, hk_ref, hv_ref,
+                     wq_ref, wk_ref, wv_ref, a_ref, dt_ref,
+                     qo_ref, ko_ref, vo_ref, g_ref, stage):
+    import jax.experimental.pallas as pl
+    first = pl.program_id(2) == 0
+    rows, dk = q_ref.shape
+    convs = ((q_ref, hq_ref, wq_ref, qo_ref, dk ** -0.5),
+             (k_ref, hk_ref, wk_ref, ko_ref, 1.0),
+             (v_ref, hv_ref, wv_ref, vo_ref, None))
+    for i, (x_ref, h_ref, _, _, _) in enumerate(convs):
+        _stage(stage.at[i], x_ref, h_ref, first)
+    for t0, tile, at in _tiles(rows):
+        for i, (_, _, w_ref, o_ref, norm) in enumerate(convs):
+            _, y, gate = _conv_gate(stage.at[i], t0, tile, w_ref)
+            s = y * gate
+            if norm is not None:
+                s = s * (jax.lax.rsqrt(jnp.sum(
+                    s * s, axis=1, keepdims=True) + L2_EPS) * norm)
+            o_ref[at, :] = s.astype(o_ref.dtype)
+        x = f_ref[at, :].astype(_F32) + dt_ref[...]
+        g_ref[at, :] = a_ref[...] * _softplus(x)
+
+
+def _fold(x):
+    """(tile, d) -> (8, d): the sum over groups of 8 rows, adds of whole
+    registers; XLA adds the 8 that are left."""
+    return functools.reduce(
+        lambda a, b: a + b, [x[i:i + 8] for i in range(0, x.shape[0], 8)])
+
+
+def _prep_bwd_kernel(dq_ref, dk_ref, dv_ref, dg_ref, q_ref, k_ref, v_ref,
+                     f_ref, hq_ref, hk_ref, hv_ref, wq_ref, wk_ref, wv_ref,
+                     a_ref, dt_ref, xq_ref, xk_ref, xv_ref, df_ref, gwq_ref,
+                     gwk_ref, gwv_ref, gdt_ref, ga_ref, stage, dys, *, seq):
+    import jax.experimental.pallas as pl
+    cell = pl.program_id(2)                      # row blocks, last to first
+    block = pl.num_programs(2) - 1 - cell
+    rows, dk = q_ref.shape
+    kw = wq_ref.shape[0]
+
+    @pl.when(cell == 0)
+    def _():
+        for ref in (gwq_ref, gwk_ref, gwv_ref, gdt_ref, ga_ref):
+            ref[...] = jnp.zeros(ref.shape, _F32)
+        dys[:, rows:rows + HALO, :] = jnp.zeros((3, HALO, dys.shape[2]), _F32)
+
+    # a last block that reaches past the sequence holds whatever the memory
+    # held there: selected away wherever a sum over rows could take it in
+    ragged = seq % rows != 0
+
+    def kept(x, t0):
+        if not ragged:
+            return x
+        row = block * rows + t0 + jax.lax.broadcasted_iota(
+            jnp.int32, x.shape, 0)
+        return jnp.where(row < seq, x, 0.0)
+
+    convs = ((q_ref, hq_ref, wq_ref, dq_ref, xq_ref, gwq_ref, dk ** -0.5),
+             (k_ref, hk_ref, wk_ref, dk_ref, xk_ref, gwk_ref, 1.0),
+             (v_ref, hv_ref, wv_ref, dv_ref, xv_ref, gwv_ref, None))
+    for i, (x_ref, h_ref, *_) in enumerate(convs):
+        _stage(stage.at[i], x_ref, h_ref, block == 0, kept)
+    # dy, the gradient of every convolution's result, and with it the sums
+    # for the taps; the decay's whole backward
+    for t0, tile, at in _tiles(rows):
+        for i, (_, _, w_ref, do_ref, _, gw_ref, norm) in enumerate(convs):
+            cols = w_ref.shape[1]
+            taps, y, gate = _conv_gate(stage.at[i], t0, tile, w_ref)
+            ds = kept(do_ref[at, :].astype(_F32), t0)
+            if norm is not None:
+                s = y * gate
+                r = jax.lax.rsqrt(jnp.sum(s * s, axis=1, keepdims=True)
+                                  + L2_EPS)
+                m = jnp.sum(ds * s, axis=1, keepdims=True)
+                ds = (r * norm) * (ds - s * (r * r * m))
+            dy = ds * (gate * (1.0 + y * (1.0 - gate)))
+            dys[i, at, 0:cols] = dy
+            for j, x in enumerate(taps):
+                gw_ref[8 * j:8 * j + 8, :] += _fold(dy * x)
+        x = kept(f_ref[at, :].astype(_F32), t0) + dt_ref[...]
+        scaled = kept(dg_ref[at, :], t0) * a_ref[...]
+        dx = scaled * jax.nn.sigmoid(x)
+        df_ref[at, :] = dx.astype(df_ref.dtype)
+        gdt_ref[...] += _fold(dx)
+        ga_ref[...] += _fold(scaled * _softplus(x))
+    # dx[t] = sum_j w_j dy[t + kw - 1 - j]: the rows after a block's last
+    # are the first of the block after it
+    for t0, tile, at in _tiles(rows):
+        for i, (_, _, w_ref, _, dx_ref, _, _) in enumerate(convs):
+            cols = w_ref.shape[1]
+            dx = sum(w_ref[j:j + 1, :] *
+                     dys[i, t0 + kw - 1 - j:t0 + kw - 1 - j + tile, 0:cols]
+                     for j in range(kw))
+            dx_ref[at, :] = dx.astype(dx_ref.dtype)
+    dys[:, rows:rows + HALO, :] = dys[:, 0:HALO, :]
+
+
+def _prep_specs(s, kw, reverse):
+    """(rows a cell, row blocks, block specs by head width) over a grid
+    (B, H, row blocks), the row blocks last to first where `reverse`."""
+    import jax.experimental.pallas as pl
+    rows = min(PREP_ROWS, -(-s // _HALO_BLOCK) * _HALO_BLOCK)
+    cells = -(-s // rows)
+
+    def at(r):
+        return cells - 1 - r if reverse else r
+
+    def block(d):
+        return pl.BlockSpec((None, rows, d), lambda b, h, r: (b, at(r), h))
+
+    def halo(d):        # the _HALO_BLOCK rows that end where the block starts
+        per = rows // _HALO_BLOCK
+        return pl.BlockSpec(
+            (None, _HALO_BLOCK, d),
+            lambda b, h, r: (b, jnp.maximum(at(r) * per - 1, 0), h))
+
+    def shared(n, d):   # a parameter's rows: taps (kw, C), a and dt (1, C)
+        return pl.BlockSpec((n, d), lambda b, h, r: (0, h))
+
+    def sums(n, d):     # per (batch, channel) sums, resident over the rows
+        return pl.BlockSpec((None, n, d), lambda b, h, r: (b, 0, h))
+
+    return rows, cells, (block, halo, functools.partial(shared, kw), shared,
+                         sums)
+
+
+def _prep_params(dtype, convs, a_log, dt_bias, dk):
+    """The parameters as the kernels read them: taps (kw, C) float32 of the
+    values the XLA path multiplies with (rounded to the operands' dtype),
+    a = -exp(A_log) spread over its head's channels and dt_bias, (1, C)."""
+    taps = tuple(w.astype(dtype).astype(_F32).T for w in convs)
+    a = jnp.repeat(-jnp.exp(a_log.astype(_F32)), dk)[None, :]
+    return taps, a, dt_bias.astype(_F32)[None, :]
+
+
+_PREP_GRID = ("parallel", "parallel", "arbitrary")   # batch, head, rows
+
+
+# jitted by themselves for the core kernels' reason: the tiles unroll
+@functools.partial(jax.jit, static_argnames=("num_heads", "interpret"))
+@_under_scope("mx.kda.prepare")
+def _prepare_forward(q, k, v, f, conv_q, conv_k, conv_v, a_log, dt_bias,
+                     num_heads, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, c = q.shape
+    dk, dv = c // num_heads, v.shape[-1] // num_heads
+    kw = conv_q.shape[1]
+    taps, a, dt = _prep_params(q.dtype, (conv_q, conv_k, conv_v), a_log,
+                               dt_bias, dk)
+    rows, cells, (block, halo, tap, shared, _) = _prep_specs(s, kw, False)
+    return pl.pallas_call(
+        _prep_fwd_kernel,
+        grid=(b, num_heads, cells),
+        in_specs=[block(dk), block(dk), block(dv), block(dk),
+                  halo(dk), halo(dk), halo(dv), tap(dk), tap(dk), tap(dv),
+                  shared(1, dk), shared(1, dk)],
+        out_specs=[block(dk), block(dk), block(dv), block(dk)],
+        out_shape=[_sds(q.shape, q.dtype, q), _sds(k.shape, k.dtype, q),
+                   _sds(v.shape, v.dtype, q), _sds(q.shape, _F32, q)],
+        scratch_shapes=[pltpu.VMEM((3, HALO + rows, max(dk, dv)), _F32)],
+        interpret=interpret, name="mx_kdaprep_fwd",
+        **_compiler_params(interpret, _PREP_GRID),
+    )(q, k, v, f, q, k, v, *taps, a, dt)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "interpret"))
+@_under_scope("mx.kda.prepare")
+def _prepare_backward(q, k, v, f, conv_q, conv_k, conv_v, a_log, dt_bias,
+                      dq, dk_, dv_, dg, num_heads, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, c = q.shape
+    dk, dv = c // num_heads, v.shape[-1] // num_heads
+    kw = conv_q.shape[1]
+    convs = (conv_q, conv_k, conv_v)
+    taps, a, dt = _prep_params(q.dtype, convs, a_log, dt_bias, dk)
+    rows, cells, (block, halo, tap, shared, sums) = _prep_specs(s, kw, True)
+    *dx, gwq, gwk, gwv, gdt, ga = pl.pallas_call(
+        functools.partial(_prep_bwd_kernel, seq=s),
+        grid=(b, num_heads, cells),
+        in_specs=[block(dk), block(dk), block(dv), block(dk),
+                  block(dk), block(dk), block(dv), block(dk),
+                  halo(dk), halo(dk), halo(dv), tap(dk), tap(dk), tap(dv),
+                  shared(1, dk), shared(1, dk)],
+        out_specs=[block(dk), block(dk), block(dv), block(dk),
+                   sums(8 * kw, dk), sums(8 * kw, dk), sums(8 * kw, dv),
+                   sums(8, dk), sums(8, dk)],
+        out_shape=[_sds(q.shape, q.dtype, q), _sds(k.shape, k.dtype, q),
+                   _sds(v.shape, v.dtype, q), _sds(f.shape, f.dtype, q)] +
+        [_sds((b, 8 * kw, x.shape[-1]), _F32, q) for x in (q, k, v)] +
+        [_sds((b, 8, c), _F32, q)] * 2,
+        scratch_shapes=[pltpu.VMEM((3, HALO + rows, max(dk, dv)), _F32)] * 2,
+        interpret=interpret, name="mx_kdaprep_bwd",
+        **_compiler_params(interpret, _PREP_GRID),
+    )(dq.astype(q.dtype), dk_.astype(k.dtype), dv_.astype(v.dtype),
+      dg.astype(_F32), q, k, v, f, q, k, v, *taps, a, dt)
+    gw = tuple(g.reshape(b, kw, 8, -1).sum((0, 2)).T.astype(w.dtype)
+               for g, w in zip((gwq, gwk, gwv), convs))
+    ga = ga.sum((0, 1)).reshape(num_heads, dk).sum(-1).astype(a_log.dtype)
+    return (*dx, *gw, ga, gdt.sum((0, 1)).astype(dt_bias.dtype))
+
+
+def prepare_kernels(q, k, v, f, conv_q, conv_k, conv_v, a_log, dt_bias,
+                    num_heads, interpret=False):
+    """(q, k, v, g) as the core takes them, each (B, S, H*d): `lm.kda_prepare`
+    through the kernels, under one custom VJP that keeps its inputs."""
+    @jax.custom_vjp
+    def fn(*args):
+        return tuple(_prepare_forward(*args, num_heads, interpret))
+
+    def fwd(*args):
+        return fn(*args), args
+
+    def bwd(args, grads):
+        return _prepare_backward(*args, *grads, num_heads, interpret)
+
+    fn.defvjp(fwd, bwd)
+    return fn(q, k, v, f, conv_q, conv_k, conv_v, a_log, dt_bias)

@@ -29,6 +29,9 @@ widths that are multiples of 128 through the Pallas kernels of
 `ops/kda_pallas.py` (forward and backward under one custom VJP, the same
 work held in VMEM); a CPU program, float32 operands and every other shape
 take `kda_chunked`, which is also what the kernels are tested against.
+`_contrib_kda` makes the same choice for what comes before the rule (short
+convolutions, normalisations, decay): `kda_pallas.prepare_kernels`, one
+pass over the operands each way, or `kda_prepare`, its reference.
 """
 from __future__ import annotations
 
@@ -269,36 +272,64 @@ def kda_log_decay(f, a_log, dt_bias, num_heads):
     return g
 
 
+def kda_prepare(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias,
+                num_heads):
+    """The core's operands from the mixer's projections (B, S, H*d): short
+    convolutions and SiLU, q and k L2-normalised per head (q scaled by
+    dk^-0.5), the log-decay in float32 and beta's sigmoid; heads split."""
+    h = num_heads
+    b, s, c = q.shape
+    dk = c // h
+    q, k, v = (short_conv_silu(x, w).reshape(b, s, h, -1)
+               for x, w in ((q, conv_q), (k, conv_k), (v, conv_v)))
+    dtype = q.dtype
+    q = (_l2_normalize(q) * dk ** -0.5).astype(dtype)
+    k = _l2_normalize(k).astype(dtype)
+    g = kda_log_decay(f, a_log, dt_bias, h)
+    return q, k, v, g, jax.nn.sigmoid(beta.astype(_F32))
+
+
+KDA_PREPARE_KERNEL_COUNTER = "kda_prepare_kernel_calls_total"
+KDA_PREPARE_FALLBACK_COUNTER = "kda_prepare_xla_fallback_total"
+
+
 def _kda_op(attrs, octx, q, k, v, f, beta, conv_q, conv_k, conv_v, a_log,
             dt_bias):
     h = attrs["num_heads"]
     b, s, c = q.shape
-    dk = c // h
-
-    def prepare(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias):
-        q, k, v = (short_conv_silu(x, w).reshape(b, s, h, -1)
-                   for x, w in ((q, conv_q), (k, conv_k), (v, conv_v)))
-        dtype = q.dtype
-        q = (_l2_normalize(q) * dk ** -0.5).astype(dtype)
-        k = _l2_normalize(k).astype(dtype)
-        g = kda_log_decay(f, a_log, dt_bias, h)
-        return q, k, v, g, jax.nn.sigmoid(beta.astype(_F32))
 
     def core(*args):
         with jax.named_scope("mx.kda.core"):
             o = kda(*args, chunk=attrs["chunk"], platform=octx.platform)
         return o.reshape(b, s, -1)
 
-    inputs = (q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias)
     from . import kda_pallas
-    if kda_pallas.eligible(q.dtype, dk, v.shape[-1] // h, attrs["chunk"],
-                           octx.platform):
-        # the kernels' custom VJP keeps the core's operands and its
-        # chunk-start states, and nothing of their insides: no checkpoint
-        # (the layer's own rematerialisation bounds how long they live)
-        return _t(core(*prepare(*inputs)))
+    if kda_pallas.eligible(q.dtype, c // h, v.shape[-1] // h, attrs["chunk"],
+                           octx.platform, conv_q.shape[1]):
+        # one pass over the operands as they arrive, whose custom VJP keeps
+        # its inputs; the core's keeps its operands and its chunk-start
+        # states, and nothing of their insides: no checkpoint (the layer's
+        # own rematerialisation bounds how long they live)
+        _count_kda(KDA_PREPARE_KERNEL_COUNTER, "KDA operand preparations "
+                   "traced for a TPU that went through the Pallas kernels")
+        flat = kda_pallas.prepare_kernels(q, k, v, f, conv_q, conv_k, conv_v,
+                                          a_log, dt_bias, num_heads=h)
+        return _t(core(*(a.reshape(b, s, h, -1) for a in flat),
+                       jax.nn.sigmoid(beta.astype(_F32))))
+    if q.dtype == jnp.bfloat16 and \
+            (octx.platform or jax.default_backend()) == "tpu":
+        import logging
+        _count_kda(KDA_PREPARE_FALLBACK_COUNTER, "bf16 KDA operand "
+                   "preparations traced for a TPU whose shapes the Pallas "
+                   "kernels do not take")
+        logging.getLogger(__name__).warning(
+            "_contrib_kda: q %s v %s kernel %d chunk %d not eligible for the "
+            "TPU kernels; the XLA path", q.shape, v.shape, conv_q.shape[1],
+            attrs["chunk"])
     # the XLA path's backward recomputes the chunks from the inputs
-    return _t(jax.checkpoint(lambda *a: core(*prepare(*a)))(*inputs))
+    return _t(jax.checkpoint(
+        lambda *a: core(*kda_prepare(*a, num_heads=h)))(
+            q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias))
 
 
 def _kda_infer(attrs, in_shapes):

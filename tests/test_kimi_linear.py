@@ -172,6 +172,125 @@ def test_kda_fallback_on_tpu_is_counted():
     assert traced(128, "tpu") == (1, 0)
 
 
+def _prepare_inputs(b, s, h, d, dtype, dt_bias=0.0, seed=0):
+    """The mixer's projections (B, S, H*d), three taps (C, 4), A_log and
+    dt_bias, and a cotangent for each of q, k, v, g."""
+    rng = np.random.default_rng(seed)
+    c = h * d
+    args = [jnp.asarray(rng.standard_normal((b, s, c)), dtype)
+            for _ in range(4)] + \
+        [jnp.asarray(0.5 * rng.standard_normal((c, 4)), dtype)
+         for _ in range(3)] + \
+        [jnp.asarray(0.3 * rng.standard_normal((h,)), jnp.float32),
+         jnp.asarray(0.3 * rng.standard_normal((c,)) + dt_bias, jnp.float32)]
+    cots = tuple(jnp.asarray(rng.standard_normal((b, s, c)), t)
+                 for t in (dtype, dtype, dtype, "float32"))
+    return tuple(args), cots
+
+
+# rows a grid cell (None: the kernels' own), then the shape; the tolerance
+# on every output and gradient, relative to the largest entry (float32:
+# A_log's gradient is one sum of S * d terms of either sign, 6e-6 apart)
+PREPARE_CASES = {
+    "one_block": dict(shape=(1, 64, 1, 128)),
+    # the halo crosses a boundary twice, forward and backward
+    "three_row_blocks": dict(rows=32, shape=(1, 96, 1, 128)),
+    # the last block reaches 16 rows past the sequence
+    "ragged": dict(rows=32, shape=(1, 80, 1, 128)),
+    # one block of 80 rows, worked as a tile of 64 and one of 16
+    "short_last_tile": dict(shape=(1, 80, 1, 128)),
+    "two_column_blocks": dict(shape=(1, 64, 2, 128)),
+    "two_batches": dict(rows=32, shape=(2, 64, 2, 128)),
+    # decays of exp(-8) a token and steeper
+    "steep_decay": dict(shape=(1, 64, 1, 128), dt_bias=8.0),
+    # bf16 operands: the XLA path rounds the convolution and every
+    # cotangent to bf16, the kernels keep float32 between their operands
+    # and their results: each within bf16 of the other, and the kernels no
+    # further from float32 than the XLA path
+    "bf16": dict(shape=(1, 64, 2, 128), dtype="bfloat16", tol=2e-2),
+}
+
+
+@pytest.mark.parametrize("case", PREPARE_CASES.values(),
+                         ids=PREPARE_CASES.keys())
+def test_kda_prepare_kernels_match_the_xla_path(case, monkeypatch):
+    """`mx_kdaprep_fwd` and `mx_kdaprep_bwd` under the Pallas interpreter
+    against `lm.kda_prepare` and its autodiff: q, k, v, g and all nine
+    gradients (the three taps, A_log and dt_bias among them)."""
+    from mxnet_tpu.ops import kda_pallas
+    if case.get("rows"):
+        monkeypatch.setattr(kda_pallas, "PREP_ROWS", case["rows"])
+        jax.clear_caches()
+    b, s, h, d = case["shape"]
+    dtype = case.get("dtype", "float32")
+    args, cots = _prepare_inputs(b, s, h, d, dtype, case.get("dt_bias", 0.0))
+
+    def xla(q, k, v, f, *params):
+        out = lm.kda_prepare(q, k, v, f, jnp.zeros((b, s, h), q.dtype),
+                             *params, num_heads=h)[:4]
+        return tuple(o.reshape(b, s, -1) for o in out)
+
+    def kernels(*a):
+        return kda_pallas.prepare_kernels(*a, num_heads=h, interpret=True)
+
+    def run(f, args, cots):
+        out, vjp = jax.vjp(f, *args)
+        return tuple(out) + tuple(vjp(cots))
+
+    got, want = run(kernels, args, cots), run(xla, args, cots)
+    assert len(got) == len(want) == 13
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        _close(a, w, case.get("tol", 2e-5))
+    if dtype != "float32":
+        exact = run(xla, tuple(a.astype("float32") for a in args),
+                    tuple(c.astype("float32") for c in cots))
+
+        def err(a, e):
+            a, e = (np.asarray(x, np.float64) for x in (a, e))
+            return np.max(np.abs(a - e)) / np.max(np.abs(e))
+
+        for a, w, e in zip(got, want, exact):
+            # (g's path is float32 on both: there the float32 cases' room)
+            assert err(a, e) <= max(1.1 * err(w, e), 2e-5)
+    if case.get("rows"):
+        jax.clear_caches()
+
+
+def test_contrib_kda_counts_its_prepare_path(caplog):
+    """`_contrib_kda` through the executor's runner, traced for a TPU: a
+    prepare-kernel call at head width 128, a logged fall-back at 64, and
+    neither on a program for the CPU."""
+    from mxnet_tpu.executor import _build_runner
+    from mxnet_tpu.telemetry import registry
+    names = (lm.KDA_PREPARE_KERNEL_COUNTER, lm.KDA_PREPARE_FALLBACK_COUNTER)
+    h, s = 2, 64
+    inputs = ("query", "key", "value", "decay", "beta", "conv_query",
+              "conv_key", "conv_value", "A_log", "dt_bias")
+    sym = mx.sym._contrib_kda(*(mx.sym.Variable(n) for n in inputs),
+                              num_heads=h)
+
+    def traced(d, platform):
+        c = h * d
+        shapes = [(1, s, c)] * 4 + [(1, s, h)] + [(c, 4)] * 3 + [(h,), (c,)]
+        dtypes = ["bfloat16"] * 8 + ["float32"] * 2
+        run = _build_runner(sym, True, platform=platform)
+        before = [registry.counter(n).value() for n in names]
+        jax.eval_shape(
+            lambda args: run(args, (), jax.random.PRNGKey(0)),
+            tuple(jax.ShapeDtypeStruct(shp, t)
+                  for shp, t in zip(shapes, dtypes)))
+        return tuple(registry.counter(n).value() - b
+                     for n, b in zip(names, before))
+
+    assert traced(128, "cpu") == (0, 0)
+    assert traced(128, "tpu") == (1, 0)
+    with caplog.at_level("WARNING", logger="mxnet_tpu.ops.lm"):
+        assert traced(64, "tpu") == (0, 1)
+    assert any("_contrib_kda" in r.getMessage() and "(1, 64, 128)"
+               in r.getMessage() for r in caplog.records)
+
+
 def test_flash_kernels_unequal_head_widths():
     """Latent attention's 192/128 through the Pallas kernels (interpret
     mode), forward and both backward kernels, against explicit softmax."""

@@ -11,6 +11,8 @@ inside a module-scoped fixture of THIS file (never at import, never in a
 skipif/parametrize argument, never in conftest.py, not autouse), every
 test of it lives here, and nothing compiles in a child process.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -108,6 +110,116 @@ def test_kda_kernels_forward_and_gradient(one_chip, chunk):
     # the forward that keeps the chunk-start states, and the backward
     assert _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
                           qkv, qkv, qkv, g, beta).count(KERNEL) == 2
+
+
+def _kda_operands(one_chip, b=2, s=8192, h=32, d=128):
+    """`_contrib_kda`'s ten operands at `kimi_linear.train`'s shape."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    c = h * d
+    return (sds((b, s, c)),) * 4 + (sds((b, s, h)),) + (sds((c, 4)),) * 3 + \
+        (sds((h,), jnp.float32), sds((c,), jnp.float32))
+
+
+def test_kda_prepare_kernels_forward_and_gradient(one_chip):
+    """The short convolutions, normalisations and decay in one kernel each
+    way at the cell's shape: 2 x 8,192 x 4,096 bf16 in, g float32 out."""
+    from mxnet_tpu.ops import kda_pallas
+    q, k, v, f, _, *params = _kda_operands(one_chip)
+
+    def fwd(*a):
+        return kda_pallas.prepare_kernels(*a, num_heads=32)
+
+    def loss(*a):
+        return sum(o.astype(jnp.float32).sum() for o in fwd(*a))
+
+    text = _compiled_text(fwd, q, k, v, f, *params)
+    assert text.count(KERNEL) == 1 and "mx_kdaprep_fwd" in text
+    # a sum of the outputs needs none of them: the backward kernel alone
+    text = _compiled_text(jax.grad(loss, argnums=tuple(range(9))),
+                          q, k, v, f, *params)
+    assert text.count(KERNEL) == 1 and "mx_kdaprep_bwd" in text
+
+
+_ITEM = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
+         "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+_ARRAY = re.compile(r"\b(%s)\[([\d,]*)\]" % "|".join(_ITEM))
+_NO_TRAFFIC = {"parameter", "get-tuple-element", "bitcast", "tuple",
+               "constant"}
+
+
+def _entry_instructions(text):
+    """[(name, opcode, [(elements, bytes)] of the result's arrays, operand
+    names)] of a compiled program's entry computation."""
+    def balanced(s):            # length of the parenthesised prefix of s
+        depth = 0
+        for i, ch in enumerate(s):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                return i + 1
+        raise ValueError(s)
+
+    out = []
+    for line in text[text.index("\nENTRY "):].split("\n")[2:]:
+        if line.startswith("}"):
+            break
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)$", line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        cut = balanced(rest) if rest.startswith("(") else rest.index(" ")
+        kind, rest = rest[:cut], rest[cut + 1:]
+        opcode, rest = rest.split("(", 1)
+        arrays = []
+        for dtype, dims in _ARRAY.findall(kind):
+            n = 1
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            arrays.append((n, n * _ITEM[dtype]))
+        out.append((name, opcode, arrays, re.findall(
+            r"%([\w.\-]+)", rest[:balanced("(" + rest) - 2])))
+    return out
+
+
+def _traffic(instructions):
+    """Operand + result bytes summed over the instructions that move any."""
+    size = {name: sum(b for _, b in arrays)
+            for name, _, arrays, _ in instructions}
+    return sum(size[name] + sum(size.get(o, 0) for o in operands)
+               for name, opcode, _, operands in instructions
+               if opcode not in _NO_TRAFFIC)
+
+
+def test_contrib_kda_moves_its_operands_once(one_chip):
+    """The compiled `_contrib_kda` at the cell's shape: what its entry
+    computation's instructions read and write, summed. The XLA `prepare`
+    moved 6.9 GB forward and 22.1 GB for the gradient around kernel calls
+    of 0.8 and 3.4 (float32 copies, 4-D reshapes that are physical,
+    materialised broadcasts: PERF.md, PR 29); one pass each way moves 2.4
+    and 7.3, and nothing but a kernel writes an array of the operands' size
+    on the way forward."""
+    from mxnet_tpu.ops.registry import OpCtx, get_op
+    operands = _kda_operands(one_chip)
+    op = get_op("_contrib_kda")
+    attrs = op.parse_attrs({"num_heads": "32"})
+
+    def fwd(*a):
+        return op.fcompute(attrs, OpCtx(is_train=True, platform="tpu"), *a)[0]
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    forward = _entry_instructions(_compiled_text(fwd, *operands))
+    assert [opcode for _, opcode, _, _ in forward].count("custom-call") == 2
+    assert _traffic(forward) <= 2.8e9
+    full = operands[0].size
+    large = [(name, opcode) for name, opcode, arrays, _ in forward
+             if opcode not in _NO_TRAFFIC | {"custom-call"}
+             and any(n >= full for n, _ in arrays)]
+    assert not large, large
+    gradient = _entry_instructions(_compiled_text(
+        jax.grad(loss, argnums=tuple(range(10))), *operands))
+    assert _traffic(gradient) <= 8.5e9
 
 
 @pytest.mark.parametrize("h_kv", [8, 2])
