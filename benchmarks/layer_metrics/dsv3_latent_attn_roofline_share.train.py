@@ -1,0 +1,15 @@
+"""Trace: the latent-attention cores' share of their roofline, forward and
+backward, in percent: the least time for flops_deepseek_v3.mla_core_step's
+operations and bytes (every layer's core) over the time under the scope
+`mx.flash_attention` (the three flash kernels)."""
+import flops_deepseek_v3
+from reduce import op_scopes
+
+
+def compute(ctx):
+    if "sequences_per_step" not in ctx.host:
+        return None
+    tokens = ctx.host["sequences_per_step"] * ctx.config["sequence_length"]
+    return op_scopes.roofline_share(
+        ctx, "mx.flash_attention",
+        *flops_deepseek_v3.mla_core_step(ctx.config, tokens))

@@ -26,7 +26,8 @@ OUR op registry names (ops/registry.py). Three buckets:
            the dtype the inputs arrive in (the amp dtype, from the ALLOW
            ops before them) and accumulate in fp32; decays, cumulative
            sums, recurrent state, norm statistics, router scores with
-           their top-k and the loss are fp32 inside. The funnel casts
+           their top-k, a rotation's angles and sines and the loss are
+           fp32 inside. The funnel casts
            nothing for them.
 
 Two tables name inputs that no trainer may narrow on the way in
@@ -98,6 +99,7 @@ MIXED = frozenset({
     "RMSNorm",
     "_contrib_kda",
     "_contrib_moe_experts",
+    "_contrib_rope",
     "_contrib_lm_head_ce",
 })
 

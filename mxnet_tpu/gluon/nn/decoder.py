@@ -2,12 +2,18 @@
 
 One pre-norm block, `x <- x + mixer(RMSNorm(x))`, `x <- x + mlp(RMSNorm(x))`,
 whose mixer and MLP kinds are read per layer from the published keys of a
-hybrid model (ROADMAP R0): `linear_attn_config.kda_layers` /
+model's config.json (ROADMAP R0): `linear_attn_config.kda_layers` /
 `full_attn_layers` (1-based, as config.json counts them) pick Kimi Delta
-Attention or multi-head latent attention, `first_k_dense_replace` picks the
-dense SwiGLU or the mixture of experts. First user: Kimi-Linear-48B-A3B
-(arXiv:2510.26692). `TransformerEncoder` (transformer.py) is the older,
-hard-wired block and stays as it is.
+Attention or multi-head latent attention, and a config without
+`linear_attn_config` is latent attention in every layer;
+`first_k_dense_replace` picks the dense SwiGLU or the mixture of experts.
+Latent attention rotates its `qk_rope_head_dim` dims (`rope_theta`,
+`rope_interleave`) unless the config says `mla_use_nope`. The mixture's
+keys are read in either of two spellings (`MIXTURE_KEYS`). Users:
+Kimi-Linear-48B-A3B (arXiv:2510.26692) and `deepseek_v3` configs
+(Kanana-2-30B-A3B). What a config asks for and no layer here computes
+raises NotImplementedError with the key's name. `TransformerEncoder`
+(transformer.py) is the older, hard-wired block and stays as it is.
 
 A chip's share of a deployment: `experts_held = (first, n)` makes every
 mixture layer route over all `num_experts` and compute the terms of the n
@@ -54,6 +60,39 @@ def _scope(name):
     return AttrScope(profiler_scope=name)
 
 
+# the mixture's settings: (Kimi-Linear's key, DeepSeek-V3's key)
+MIXTURE_KEYS = {
+    "num_experts": ("num_experts", "n_routed_experts"),
+    "top_k": ("num_experts_per_token", "num_experts_per_tok"),
+    "num_shared": ("num_shared_experts", "n_shared_experts"),
+    "renormalize": ("moe_renormalize", "norm_topk_prob"),
+    "scoring": ("moe_router_activation_func", "scoring_func"),
+    "groups": ("num_expert_group", "n_group"),
+    "method": ("topk_method", "topk_method"),
+}
+# what `ops/lm.py::moe_route` computes: sigmoid scores, one group, the top
+# k of score + bias; also what a config that is silent on them gets
+_ROUTER_COMPUTES = {"scoring": "sigmoid", "groups": 1, "method": "noaux_tc"}
+
+
+def mixture_settings(cfg):
+    """{setting: value} of the mixture layers, from whichever spelling the
+    config uses; a router other than the one computed here raises by the
+    key's name."""
+    out = {}
+    for name, keys in MIXTURE_KEYS.items():
+        key = next((k for k in keys if k in cfg), None)
+        if key is None and name not in _ROUTER_COMPUTES:
+            raise KeyError(f"mixture of experts: the config has neither "
+                           f"{keys[0]!r} nor {keys[1]!r}")
+        out[name] = cfg[key] if key else _ROUTER_COMPUTES[name]
+        if out[name] != _ROUTER_COMPUTES.get(name, out[name]):
+            raise NotImplementedError(
+                f"mixture of experts: {key} = {out[name]!r} (computed "
+                f"here: {_ROUTER_COMPUTES[name]!r})")
+    return out
+
+
 class KDAMixer(HybridBlock):
     """Kimi Delta Attention: gated delta-rule linear attention with short
     convolutions, a low-rank per-channel decay and a low-rank output gate."""
@@ -98,15 +137,21 @@ class KDAMixer(HybridBlock):
 
 
 class MLAMixer(HybridBlock):
-    """Multi-head latent attention without positional rotation
-    (`mla_use_nope`): keys and values come up from a shared latent of
-    `kv_lora_rank`; the `qk_rope_head_dim` extra key dims are shared by all
-    heads. Query/key heads of nope + rope dims, value heads of v dims."""
+    """Multi-head latent attention: keys and values come up from a shared
+    latent of `kv_lora_rank`; the `qk_rope_head_dim` extra key dims are
+    shared by all heads. Query/key heads of nope + rope dims, value heads
+    of v dims. The rope dims of every query head, and of the one key
+    before the heads share it, are rotated by position (`_contrib_rope`)
+    unless the config says `mla_use_nope`."""
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
-        if cfg.get("q_lora_rank") is not None:
-            raise NotImplementedError("MLAMixer: q_lora_rank is not null")
+        for key in ("q_lora_rank", "rope_scaling", "attention_bias"):
+            if cfg.get(key):
+                raise NotImplementedError(f"MLAMixer: {key} = {cfg[key]!r}")
+        self._rope = None if cfg.get("mla_use_nope") else dict(
+            rotary_dim=cfg["qk_rope_head_dim"], theta=cfg["rope_theta"],
+            interleave=bool(cfg.get("rope_interleave", True)))
         d = cfg["hidden_size"]
         self._h = cfg["num_attention_heads"]
         self._dn, self._dp, self._dv = (cfg["qk_nope_head_dim"],
@@ -135,6 +180,10 @@ class MLAMixer(HybridBlock):
             k_pe = F.slice_axis(kva, axis=-1, begin=r, end=r + dp)
             kvb = heads(_dense(F, F.RMSNorm(c_kv, kv_norm, eps=self._eps),
                                w_kvb, h * (dn + dv)), dn + dv)
+            if self._rope:
+                with _scope("mx.mla.rope"):
+                    q = F._contrib_rope(q, offset=dn, **self._rope)
+                    k_pe = F._contrib_rope(k_pe, **self._rope)
             k_pe = F.broadcast_axis(F.expand_dims(k_pe, axis=1), axis=1,
                                     size=h)
             k = F.concat(F.slice_axis(kvb, axis=-1, begin=0, end=dn), k_pe,
@@ -174,19 +223,20 @@ class MoEMLP(HybridBlock):
         super().__init__(**kwargs)
         d, w = cfg["hidden_size"], cfg["moe_intermediate_size"]
         first, n = cfg["experts_held"]
+        moe = mixture_settings(cfg)
         self._attrs = dict(
-            num_experts=cfg["num_experts"], num_held=n, first_expert=first,
-            hidden_size=w, top_k=cfg["num_experts_per_token"],
+            num_experts=moe["num_experts"], num_held=n, first_expert=first,
+            hidden_size=w, top_k=moe["top_k"],
             scaling=cfg["routed_scaling_factor"],
-            renormalize=bool(cfg["moe_renormalize"]))
+            renormalize=bool(moe["renormalize"]))
         get = _getter(self)
-        self.w_r = get("w_r", shape=(cfg["num_experts"], d))
-        self.r_bias = get("r_bias", shape=(cfg["num_experts"],), init="zeros")
+        self.w_r = get("w_r", shape=(moe["num_experts"], d))
+        self.r_bias = get("r_bias", shape=(moe["num_experts"],), init="zeros")
         self.e_gate = get("e_gate", shape=(n, d, w))
         self.e_up = get("e_up", shape=(n, d, w))
         self.e_down = get("e_down", shape=(n, w, d))
         with self.name_scope():
-            self.shared = SwiGLU(d, w * cfg["num_shared_experts"],
+            self.shared = SwiGLU(d, w * moe["num_shared"],
                                  names=("s_gate", "s_up", "s_down"),
                                  prefix="")
 
@@ -204,11 +254,11 @@ class DecoderBlock(HybridBlock):
 
     def __init__(self, cfg, layer, **kwargs):
         super().__init__(**kwargs)
-        lin = cfg["linear_attn_config"]
-        if layer in lin["kda_layers"]:
-            mixer = KDAMixer
-        elif layer in lin["full_attn_layers"]:
+        lin = cfg.get("linear_attn_config")
+        if lin is None or layer in lin["full_attn_layers"]:
             mixer = MLAMixer
+        elif layer in lin["kda_layers"]:
+            mixer = KDAMixer
         else:
             raise ValueError(f"layer {layer} is in neither kda_layers nor "
                              "full_attn_layers")

@@ -3,11 +3,13 @@
 TPU-first new surface (the reference has none of these): `RMSNorm`, the
 Kimi Delta Attention mixer core `_contrib_kda` (short convolutions, decay
 and a chunkwise-parallel gated delta rule), the held-experts mixture
-`_contrib_moe_experts` and the fused head `_contrib_lm_head_ce` whose
-output is the per-token loss. Each manages its own precision (amp/policy.py
-lists them under MIXED): matrix products take the dtype their inputs
-arrive in and accumulate in float32; the decay, the chunk state, the
-norms' statistics, the router and the loss are float32 whatever arrives.
+`_contrib_moe_experts`, the rotary position embedding `_contrib_rope` (a
+part of a head's dims turned in place, in one pass) and the fused head
+`_contrib_lm_head_ce` whose output is the per-token loss. Each manages its
+own precision (amp/policy.py lists them under MIXED): matrix products take
+the dtype their inputs arrive in and accumulate in float32; the decay, the
+chunk state, the norms' statistics, the router, the rotation's angles and
+sines and the loss are float32 whatever arrives.
 
 The delta rule, per head, with S in R^(dk x dv) and g the log-decay:
 
@@ -227,7 +229,8 @@ KDA_KERNEL_COUNTER = "kda_kernel_calls_total"
 KDA_FALLBACK_COUNTER = "kda_xla_fallback_total"
 
 
-def _count_kda(name, help_text):
+def _count(name, help_text):
+    """One more of a path taken, counted once a trace."""
     from ..telemetry import registry
     registry.counter(name, help=help_text).inc()
 
@@ -247,15 +250,15 @@ def kda(q, k, v, g, beta, chunk=64, force=None, platform=None):
             force is None and kda_pallas.eligible(
                 q.dtype, q.shape[-1], v.shape[-1], chunk, platform)):
         if force is None:
-            _count_kda(KDA_KERNEL_COUNTER, "KDA cores traced for a TPU that "
-                       "went through the Pallas kernels")
+            _count(KDA_KERNEL_COUNTER, "KDA cores traced for a TPU that "
+                   "went through the Pallas kernels")
         return kda_pallas.kda_kernels(q, k, v, g, beta, chunk=chunk,
                                       interpret=force == "interpret")
     if force is None and q.dtype == jnp.bfloat16 and \
             (platform or jax.default_backend()) == "tpu":
         import logging
-        _count_kda(KDA_FALLBACK_COUNTER, "bf16 KDA cores traced for a TPU "
-                   "whose shapes the Pallas kernels do not take")
+        _count(KDA_FALLBACK_COUNTER, "bf16 KDA cores traced for a TPU "
+               "whose shapes the Pallas kernels do not take")
         logging.getLogger(__name__).warning(
             "kda: q %s v %s chunk %d not eligible for the TPU kernels; "
             "the XLA path", q.shape, v.shape, chunk)
@@ -310,8 +313,8 @@ def _kda_op(attrs, octx, q, k, v, f, beta, conv_q, conv_k, conv_v, a_log,
         # its inputs; the core's keeps its operands and its chunk-start
         # states, and nothing of their insides: no checkpoint (the layer's
         # own rematerialisation bounds how long they live)
-        _count_kda(KDA_PREPARE_KERNEL_COUNTER, "KDA operand preparations "
-                   "traced for a TPU that went through the Pallas kernels")
+        _count(KDA_PREPARE_KERNEL_COUNTER, "KDA operand preparations "
+               "traced for a TPU that went through the Pallas kernels")
         flat = kda_pallas.prepare_kernels(q, k, v, f, conv_q, conv_k, conv_v,
                                           a_log, dt_bias, num_heads=h)
         return _t(core(*(a.reshape(b, s, h, -1) for a in flat),
@@ -319,9 +322,9 @@ def _kda_op(attrs, octx, q, k, v, f, beta, conv_q, conv_k, conv_v, a_log,
     if q.dtype == jnp.bfloat16 and \
             (octx.platform or jax.default_backend()) == "tpu":
         import logging
-        _count_kda(KDA_PREPARE_FALLBACK_COUNTER, "bf16 KDA operand "
-                   "preparations traced for a TPU whose shapes the Pallas "
-                   "kernels do not take")
+        _count(KDA_PREPARE_FALLBACK_COUNTER, "bf16 KDA operand "
+               "preparations traced for a TPU whose shapes the Pallas "
+               "kernels do not take")
         logging.getLogger(__name__).warning(
             "_contrib_kda: q %s v %s kernel %d chunk %d not eligible for the "
             "TPU kernels; the XLA path", q.shape, v.shape, conv_q.shape[1],
@@ -353,6 +356,93 @@ register("_contrib_kda", _kda_op,
          inputs=("query", "key", "value", "decay", "beta", "conv_query",
                  "conv_key", "conv_value", "A_log", "dt_bias"),
          infer_shape=_kda_infer)
+
+
+# -- rotary position embedding ----------------------------------------------------
+
+ROPE_COUNTER = "rope_calls_total"
+
+
+def _rope_tables(seq, width, rotary_dim, offset, theta, interleave):
+    """(cos (seq, width), sin (seq, width), swap (width, width)). Pair i of
+    position p turns by a = p * theta^(-2i / rotary_dim): the frequencies
+    are rounded to float32 once, on the host; the product, its cosine and
+    its sine are float32 in the program; outside the rotated dims the
+    cosine is 1 and the sine 0. `t @ swap` puts at each rotated dim its
+    pair's other half, negated at the pair's first: a signed permutation,
+    so the product is exact in any dtype."""
+    import numpy as np
+    half = rotary_dim // 2
+    freq = jnp.asarray(np.float32(np.power(
+        np.float64(theta), -np.arange(0, rotary_dim, 2) / rotary_dim)))
+    angle = jnp.arange(seq, dtype=_F32)[:, None] * freq          # (S, R/2)
+    first = offset + (2 * np.arange(half) if interleave else np.arange(half))
+    second = first + (1 if interleave else half)
+    spread = functools.partial(jnp.repeat, repeats=2, axis=-1) \
+        if interleave else functools.partial(jnp.tile, reps=(1, 2))
+    pad = ((0, 0), (offset, width - offset - rotary_dim))
+    swap = np.zeros((width, width), np.float32)
+    swap[second, first] = -1.0
+    swap[first, second] = 1.0
+    return (jnp.pad(spread(jnp.cos(angle)), pad, constant_values=1.0),
+            jnp.pad(spread(jnp.sin(angle)), pad), swap)
+
+
+def _rotate(x, rotary_dim, offset, theta, interleave, sign):
+    cos, sin, swap = _rope_tables(*x.shape[-2:], rotary_dim, offset, theta,
+                                  interleave)
+    # one pass over x: the TPU compiler fuses the multiply-adds into the
+    # product (a lane shift by one, the same thing written as slices, costs
+    # it float32 copies of x: compile, PR 30)
+    partner = jnp.einsum(
+        "...i,ij->...j", x, jnp.asarray(swap, x.dtype),
+        precision=_HIGHEST if x.dtype == _F32 else None,
+        preferred_element_type=x.dtype)
+    return (x.astype(_F32) * cos
+            + partner.astype(_F32) * (sign * sin)).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def rope(x, rotary_dim, offset=0, theta=10000.0, interleave=True):
+    """Rotary embedding of positions 0..S-1 over dims offset..offset +
+    rotary_dim of x (..., S, W), the others passed through: pair i turns
+    by a = p * theta^(-2i / rotary_dim), (t0, t1) -> (t0 cos a - t1 sin a,
+    t0 sin a + t1 cos a). `interleave`: pairs are (2i, 2i + 1), else
+    (i, i + rotary_dim / 2). Angles, sines and the products are float32
+    whatever x's dtype, which the result takes. The backward pass is the
+    rotation by -a of the cotangent: nothing is kept."""
+    return _rotate(x, rotary_dim, offset, theta, interleave, 1.0)
+
+
+def _rope_fwd(x, rotary_dim, offset, theta, interleave):
+    return _rotate(x, rotary_dim, offset, theta, interleave, 1.0), None
+
+
+def _rope_bwd(rotary_dim, offset, theta, interleave, _, dy):
+    return (_rotate(dy, rotary_dim, offset, theta, interleave, -1.0),)
+
+
+rope.defvjp(_rope_fwd, _rope_bwd)
+
+
+def _rope_op(attrs, octx, data):
+    rotary, offset = attrs["rotary_dim"], attrs["offset"]
+    if rotary % 2 or rotary <= 0 or offset < 0 or \
+            offset + rotary > data.shape[-1]:
+        raise ValueError(
+            f"_contrib_rope: rotary_dim {rotary} at offset {offset} does not "
+            f"fit an even number of dims into a width of {data.shape[-1]}")
+    _count(ROPE_COUNTER, "rotary embeddings traced into a program")
+    return _t(rope(data, rotary, offset, attrs["theta"],
+                   attrs["interleave"]))
+
+
+register("_contrib_rope", _rope_op,
+         params={"rotary_dim": Param("int", required=True),
+                 "offset": Param("int", 0),
+                 "theta": Param("float", 10000.0),
+                 "interleave": Param("bool", True)},
+         inputs=("data",))
 
 
 # -- held-experts mixture -------------------------------------------------------

@@ -39,7 +39,7 @@ CFG = {
                            "head_dim": 16, "short_conv_kernel_size": 4},
     "num_attention_heads": 2, "qk_nope_head_dim": 16,
     "qk_rope_head_dim": 8, "v_head_dim": 16, "kv_lora_rank": 24,
-    "q_lora_rank": None, "num_experts": 16, "num_experts_per_token": 4,
+    "q_lora_rank": None, "mla_use_nope": True, "num_experts": 16, "num_experts_per_token": 4,
     "num_shared_experts": 1, "moe_intermediate_size": 24,
     "moe_renormalize": True, "routed_scaling_factor": 2.446,
     "experts_held": [4, 4], "vocab_size": 300,

@@ -222,6 +222,33 @@ def test_contrib_kda_moves_its_operands_once(one_chip):
     assert _traffic(gradient) <= 8.5e9
 
 
+@pytest.mark.parametrize("way", ["forward", "gradient"])
+def test_rope_moves_its_operand_once(one_chip, way):
+    """`lm.rope` at `kanana2.train`'s shape (2 x 32 heads x 8,192 x 192, the
+    last 64 dims of a head rotated, bf16): one read and one write of q,
+    0.40 GB, each way. The pair's other half comes from a product with a
+    signed permutation, into which the compiler fuses the multiply-adds;
+    fetched by a lane shift (`jnp.roll`) the same rotation compiled to
+    float32 slices and copies of q, 2.6 GB a call (PERF.md, PR 30)."""
+    from mxnet_tpu.ops import lm
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 192), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd(x):
+        return lm.rope(x, 64, 128, 1e6, True)
+
+    fn = fwd if way == "forward" else \
+        (lambda x, dy: jax.vjp(fwd, x)[1](dy)[0])
+    ins = _entry_instructions(_compiled_text(fn, *(q,) * (
+        1 if way == "forward" else 2)))
+    assert _traffic(ins) <= 0.46e9
+    full = 2 * 32 * 8192 * 192
+    large = [name for name, opcode, arrays, _ in ins
+             if opcode not in _NO_TRAFFIC and any(n >= full
+                                                  for n, _ in arrays)]
+    assert len(large) == 1, large
+
+
 @pytest.mark.parametrize("h_kv", [8, 2])
 def test_decode_attention(one_chip, h_kv):
     from mxnet_tpu.ops.attention import decode_attention

@@ -53,6 +53,15 @@ SKIP = {
     "_contrib_count_sketch": "hash-table h/s inputs; tests/test_contrib.py",
     "_contrib_flash_attention": "covered by test_family_sweep_consistency"
                                 " ('flash_attention_op' case)",
+    "_contrib_kda": "ten coupled operands at head widths the kernels "
+                    "take; covered by test_kda_kernels.py and "
+                    "test_kda_prepare.py",
+    "_contrib_moe_experts": "router, ids and grouped weights coupled; "
+                            "covered on the chip by check (b) of the "
+                            "benchmark's language-model cells",
+    "_contrib_lm_head_ce": "integer labels coupled to the head's rows; "
+                           "covered on the chip by check (b) of the "
+                           "benchmark's language-model cells",
     "RNN": "packed-parameter layout; covered by test_family_sweep_"
            "consistency ('fused_rnn_lstm') and tests/test_rnn.py",
     "ROIPooling": "covered by test_family_sweep_consistency ('roipooling')",
@@ -141,6 +150,9 @@ CASES = {
     "SwapAxis": dict(attrs={"dim1": 0, "dim2": 1}),
     "Cast": dict(attrs={"dtype": "float32"}),
     "_contrib_div_sqrt_dim": dict(shapes=[(2, 8)]),
+    "_contrib_rope": dict(attrs={"rotary_dim": 4, "offset": 2},
+                          shapes=[(2, 3, 8)]),
+    "RMSNorm": dict(shapes=[(2, 6), (6,)]),
     "_contrib_AdaptiveAvgPooling2D": dict(attrs={"output_size": (2, 2)},
                                           shapes=[(1, 3, 6, 6)]),
     "_contrib_BilinearResize2D": dict(attrs={"height": 6, "width": 6},
