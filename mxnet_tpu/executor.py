@@ -22,9 +22,42 @@ import numpy as _np
 
 from .base import MXNetError
 from .context import Context, current_context
-from .ops.registry import OpCtx
+from .ops.registry import OpCtx, kept_residuals
 
 __all__ = ["Executor"]
+
+MIRROR_KEPT_COUNTER = "mirror_kept_residuals_total"
+MIRROR_KEPT_BYTES_COUNTER = "mirror_kept_bytes_total"
+
+
+def _rematerialised(fn):
+    """`fn` under the `jax.checkpoint` of everything that
+    MXNET_BACKWARD_DO_MIRROR rematerialises: its backward keeps what enters
+    `fn` and every value that an op inside tagged under a name it declared
+    (`ops.registry.kept_residual`), and reruns the rest. Where no such
+    value occurs the policy is `nothing_saveable`, a bare `jax.checkpoint`'s.
+    A kept array and its bytes are counted once a trace in the telemetry
+    registry."""
+    declared = jax.checkpoint_policies.save_only_these_names(
+        *kept_residuals())
+
+    def keeps_declared(prim, *avals, **params):
+        if not declared(prim, *avals, **params):
+            return False
+        from .telemetry import registry
+        kept, = avals               # the tag's one operand
+        registry.counter(
+            MIRROR_KEPT_COUNTER,
+            help="arrays that ops declared as residuals of their own "
+                 "backward and rematerialised stages traced so far keep"
+        ).inc()
+        registry.counter(
+            MIRROR_KEPT_BYTES_COUNTER,
+            help="bytes of the arrays counted by " + MIRROR_KEPT_COUNTER
+        ).inc(kept.size * kept.dtype.itemsize)
+        return True
+
+    return jax.checkpoint(fn, policy=keeps_declared)
 
 
 def _node_group_dev(node, group2dev):
@@ -167,8 +200,10 @@ def _build_runner(symbol, is_train, platform=None):
 
     # `mirror_stage` (AttrScope): under MXNET_BACKWARD_DO_MIRROR the nodes
     # that share a stage are rematerialised as ONE unit: the backward keeps
-    # what enters the stage and recomputes the rest (a decoder layer keeps
-    # its residual stream, not its mixer's activations)
+    # what enters the stage and what an op inside declared as the residuals
+    # of its own backward (`_rematerialised`), and recomputes the rest (a
+    # decoder layer keeps its residual stream and its flash kernel's out
+    # and lse, not its mixer's activations)
     units = _mirror_units(topo, node_pos, out_entries) if do_mirror else None
 
     def run(arg_values, aux_values, rng):
@@ -199,7 +234,7 @@ def _build_runner(symbol, is_train, platform=None):
                           False)
                 return [local[p][i] for (p, i) in _produced], aux_out
 
-            outs, aux_out = jax.checkpoint(stage)(
+            outs, aux_out = _rematerialised(stage)(
                 [vals[p][i] for (p, i) in ext], list(new_aux), keys)
             new_aux[:] = aux_out
             _fill(vals, produced, outs)
@@ -245,7 +280,7 @@ def _build_runner(symbol, is_train, platform=None):
                         return _op.fcompute(
                             _p, OpCtx(is_train=True, rng=k, platform=_pf),
                             *a)
-                    res = jax.checkpoint(_call)(key, *ins)
+                    res = _rematerialised(_call)(key, *ins)
                 else:
                     res = node.op.fcompute(parsed, octx, *ins)
             if not isinstance(res, tuple):

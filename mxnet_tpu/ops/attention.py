@@ -16,19 +16,25 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from .registry import Param, register
+from .registry import Param, kept_residual, register
 
 _BLOCK_Q = 128    # floor tile; _auto_block picks larger when S allows
 _BLOCK_K = 128
 _LSE_LANES = 8    # minor replication of the per-row lse (TPU block tiling)
+# what the flash backward needs beyond q, k and v, which a rematerialised
+# stage's rerun rebuilds from its products: computing these two again is
+# the whole forward kernel, keeping them is 2*B*H*S*Dv + 4*B*H*S bytes
+_KEPT_OUT = kept_residual("mx_flash_attention_out")
+_KEPT_LSE = kept_residual("mx_flash_attention_lse")
 
 
 def _auto_block(s):
     """Default block size: the LARGEST of 512/256/128 dividing S —
     bigger tiles amortize the per-block softmax bookkeeping and keep the
     MXU busier (its gain on this runtime is a claim to re-measure,
-    ROADMAP S4). Sequences not
+    ROADMAP S10 (e)). Sequences not
     divisible by 128 fall back to a single block (small-S case)."""
     for blk in (512, 256, 128):
         if s % blk == 0:
@@ -344,6 +350,25 @@ def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
     return out.reshape(b, h, s, d_v), lse
 
 
+def _flash_pallas_kept(*args, **kwargs):
+    """`_flash_pallas` for the `fwd` of a custom VJP: (out, lse (B*H, S)),
+    both tagged as this op's kept residuals. The caller hands the TAGGED
+    values to its primal output and to its residuals alike: a stage that
+    keeps them then reads nothing of the rerun's kernel call, which is
+    dead code. Of the lane-replicated lse the first lane alone is kept
+    (`_lse_lanes` replicates it again for the backward kernels): a minor
+    dim of 8 is tiled out to 128 lanes in HBM, 16 times the row
+    statistic's bytes for as long as the array lives."""
+    out, lse = _flash_pallas(*args, **kwargs)
+    return (checkpoint_name(out, _KEPT_OUT),
+            checkpoint_name(lse[:, :, 0], _KEPT_LSE))
+
+
+def _lse_lanes(lse):
+    """(B*H, S) -> the kernels' lane-replicated (B*H, S, 8)."""
+    return jnp.broadcast_to(lse[:, :, None], (*lse.shape, _LSE_LANES))
+
+
 def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
                       g_lse=None, block_q=None, block_k=None):
     """Recompute-based flash backward: two single-HBM-pass kernels (dQ
@@ -507,16 +532,15 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
         return out, lse[:, :, 0].reshape(b, h, s)
 
     def fwd(q, k, v):
-        out, lse = _flash_pallas(q, k, v, causal, scale,
-                                 interpret=interpret)
-        return ((out, lse[:, :, 0].reshape(b, h, s)),
-                (q, k, v, out, lse))
+        out, lse = _flash_pallas_kept(q, k, v, causal, scale,
+                                      interpret=interpret)
+        return (out, lse.reshape(b, h, s)), (q, k, v, out, lse)
 
     def bwd(res, cotangents):
         g_o, g_lse = cotangents
         q, k, v, out, lse = res
-        return _flash_pallas_bwd(q, k, v, out, lse, g_o, causal, scale,
-                                 interpret=interpret, g_lse=g_lse)
+        return _flash_pallas_bwd(q, k, v, out, _lse_lanes(lse), g_o, causal,
+                                 scale, interpret=interpret, g_lse=g_lse)
 
     fn.defvjp(fwd, bwd)
     return fn(q, k, v)
@@ -528,7 +552,8 @@ def _flash_pallas_trainable(q, k, v, causal, scale, interpret=False,
     style): the forward saves only O and the per-row logsumexp; the
     backward re-materializes softmax blocks from them in VMEM. Activation
     memory is O(B*H*S*D + B*H*S), never O(S^2) — the long-context
-    training path."""
+    training path. The two are declared as kept (`_flash_pallas_kept`): a
+    rematerialised stage around this call runs the forward kernel once."""
 
     @jax.custom_vjp
     def fn(q, k, v):
@@ -537,15 +562,15 @@ def _flash_pallas_trainable(q, k, v, causal, scale, interpret=False,
         return out
 
     def fwd(q, k, v):
-        out, lse = _flash_pallas(q, k, v, causal, scale,
-                                 interpret=interpret, block_q=block_q,
-                                 block_k=block_k)
+        out, lse = _flash_pallas_kept(q, k, v, causal, scale,
+                                      interpret=interpret, block_q=block_q,
+                                      block_k=block_k)
         return out, (q, k, v, out, lse)
 
     def bwd(res, g):
         q, k, v, out, lse = res
-        return _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale,
-                                 interpret=interpret, block_q=block_q,
+        return _flash_pallas_bwd(q, k, v, out, _lse_lanes(lse), g, causal,
+                                 scale, interpret=interpret, block_q=block_q,
                                  block_k=block_k)
 
     fn.defvjp(fwd, bwd)

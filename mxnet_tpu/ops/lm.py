@@ -558,8 +558,11 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
         rows = jnp.where(valid, jnp.take(x, token, axis=0), 0)
         with jax.named_scope("mx.moe.experts.matmul"):
             out = _swiglu_experts(rows, w_gate, w_up, w_down, load)
+        # selected BEFORE the product with the pair's weight: the weight's
+        # gradient is a sum over out, and 0 x whatever the memory held is
+        # NaN wherever that is no number
         return jnp.zeros((t, d), _F32).at[token].add(
-            jnp.where(valid, out * weight, 0.0))
+            jnp.where(valid, out, 0.0) * weight)
 
     def dense(_):
         per_expert = jnp.stack(

@@ -28,7 +28,8 @@ from ..base import (MXNetError, parse_bool, parse_float, parse_int,
                     parse_shape)
 
 __all__ = ["Param", "OpSchema", "OpCtx", "register", "register_alias",
-           "get_op", "list_ops", "AttrDict"]
+           "get_op", "list_ops", "AttrDict", "kept_residual",
+           "kept_residuals"]
 
 
 def _parse_floats(v):
@@ -216,6 +217,26 @@ def get_op(name) -> OpSchema:
 
 def list_ops():
     return sorted(set(s.name for s in _REGISTRY.values()))
+
+
+_KEPT_RESIDUALS: set = set()
+
+
+def kept_residual(name):
+    """Declare `name`, and return it, as a name under which an op tags
+    (`jax.ad_checkpoint.checkpoint_name`, in the `fwd` of its custom VJP)
+    a value that its backward needs and that the rerun of a rematerialised
+    stage could only get from a kernel of the op's own: the executor's
+    stages (MXNET_BACKWARD_DO_MIRROR) keep the values so tagged and
+    recompute everything else. Outside a checkpoint the tag is the
+    identity."""
+    _KEPT_RESIDUALS.add(name)
+    return name
+
+
+def kept_residuals():
+    """The names declared so far, every op's."""
+    return frozenset(_KEPT_RESIDUALS)
 
 
 def canonical_names():

@@ -202,3 +202,76 @@ def test_forced_indivisible_blocks_error():
     q, k, v = _qkv(b=1, h=1, s=384, d=128, seed=40)
     with pytest.raises(ValueError, match="not divisible"):
         at.flash_attention(q, k, v, force="interpret", block_q=256)
+
+
+def _stage_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations,
+    a kernel's own body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _stage_eqns(sub)
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["out", "out_lse"])
+def test_mirror_stage_keeps_flash_residuals(with_lse):
+    """A rematerialised stage (the executor's checkpoint, as under
+    MXNET_BACKWARD_DO_MIRROR) around product -> flash attention at 192/128
+    -> product: the kernels' custom VJP declares out and lse as kept, so
+    the stage's rerun holds no second forward kernel; its products ARE run
+    again, and the gradients are the unmirrored function's to the bit."""
+    from mxnet_tpu import executor
+    from mxnet_tpu.telemetry import registry
+    b, h, s, d, dv, width = 1, 2, 256, 192, 128, 64
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.normal(size=(b, h, s, width)).astype(np.float32))
+    w_in = jnp.asarray(rng.normal(size=(width, 2 * d + dv))
+                       .astype(np.float32) * 0.2)
+    w_out = jnp.asarray(rng.normal(size=(dv, width)).astype(np.float32) * 0.2)
+
+    def stage(x, w_in, w_out):
+        qkv = x @ w_in
+        q, k, v = qkv[..., :d], qkv[..., d:2 * d], qkv[..., 2 * d:]
+        if with_lse:
+            o, lse = at.flash_attention_with_lse(q, k, v, causal=True,
+                                                 force="interpret")
+        else:
+            o = at.flash_attention(q, k, v, causal=True, force="interpret")
+            lse = jnp.zeros(())
+        return jnp.tanh(o @ w_out), lse
+
+    def gradient(fn):
+        def loss(*a):
+            y, lse = fn(*a)
+            return jnp.sum(y ** 2) + jnp.sum(jnp.sin(lse))
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    def count(fn):
+        eqns = list(_stage_eqns(jax.make_jaxpr(gradient(fn))(
+            x, w_in, w_out).jaxpr))
+        return (sum(e.primitive.name == "pallas_call" and
+                    e.params["name"] == "mx_flash_attention_fwd"
+                    for e in eqns),
+                sum(e.primitive.name == "dot_general" for e in eqns))
+
+    def kept():
+        return (registry.counter(executor.MIRROR_KEPT_COUNTER).value(),
+                registry.counter(executor.MIRROR_KEPT_BYTES_COUNTER).value())
+
+    forwards, products = count(stage)
+    assert forwards == 1
+    # a bare checkpoint runs the whole stage again, the kernel with it
+    assert count(jax.checkpoint(stage)) == (2, products + 2)
+    before = kept()
+    assert count(executor._rematerialised(stage)) == (1, products + 2)
+    # out (b, h, s, dv) and one lane of lse (b*h, s), float32
+    assert tuple(np.subtract(kept(), before)) == (
+        2, 4 * b * h * s * dv + 4 * b * h * s)
+
+    want = gradient(stage)(x, w_in, w_out)
+    got = gradient(executor._rematerialised(stage))(x, w_in, w_out)
+    for name, a, g in zip(("x", "w_in", "w_out"), want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(a),
+                                      err_msg=f"d{name}")
+

@@ -207,6 +207,37 @@ def test_eight_shares_of_sixteen_add_up_to_the_uncut_layer():
     _close(total, ref.moe_mlp(whole, p, jnp.asarray(x)), 2e-5)
 
 
+def test_rows_of_no_group_hold_anything(monkeypatch):
+    """On the TPU a grouped product leaves the rows that belong to no group
+    unwritten: whatever the memory held. Planted here as NaN, they may
+    reach neither the layer's output nor a gradient, the routing weight's
+    included: its gradient is a sum over the experts' output (the float32
+    check of `kanana2.train` read NaN there: PERF.md section 6, PR 31)."""
+    share = dict(CFG, n_routed_experts=128, num_experts_per_tok=6,
+                 experts_held=[16, 16])
+    p = _layer(ref.init_params(dict(share, experts_held=[0, 128]), 7), 1)
+    p = dict(p, **{k: p[k][16:32] for k in ("e_gate", "e_up", "e_down")})
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((2, 24, 32)),
+                    jnp.float32)
+    grouped = lm._swiglu_experts
+
+    def unwritten(rows, w_gate, w_up, w_down, group_sizes):
+        out = grouped(rows, w_gate, w_up, w_down, group_sizes)
+        in_a_group = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(in_a_group[:, None], out, jnp.nan)
+
+    def loss(x, w_r):
+        y, stats = _moe_system(share, dict(p, w_r=w_r), x)
+        assert stats[18] == 0                   # the grouped path
+        return jnp.sum(y ** 2)
+
+    want = jax.grad(loss, argnums=(0, 1))(x, p["w_r"])
+    monkeypatch.setattr(lm, "_swiglu_experts", unwritten)
+    got = jax.grad(loss, argnums=(0, 1))(x, p["w_r"])
+    for a, g in zip(want, got):
+        _close(g, a, 1e-6)
+
+
 def test_model_logits_and_loss_match_reference():
     params = ref.init_params(CFG, 3)
     tokens, labels = _batch(1)

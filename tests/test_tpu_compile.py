@@ -299,3 +299,47 @@ def test_ring_attention_over_four_chips(topo):
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                           x, x, x)
     assert KERNEL in text and "collective-permute" in text
+
+
+def test_mirror_stage_keeps_flash_residuals(one_chip):
+    """One decoder-layer-shaped stage at the language-model cells' shape
+    (2 sequences of 8,192, 32 heads of 192 / 128, bf16) under the
+    executor's rematerialisation: the flash kernels declare out and lse as
+    kept, so the loss-and-gradient program runs the forward kernel ONCE
+    (twice under a bare checkpoint) and pays for it with out and one lane
+    of lse, 0.136 GB. Kept as the kernel writes it, lane-replicated to a
+    minor dim of 8, lse alone would be 0.27 GB: tiled out to 128 lanes."""
+    from mxnet_tpu import executor
+    from mxnet_tpu.ops.attention import flash_attention
+    b, h, s, d, dv, hidden = 2, 32, 8192, 192, 128, 2048
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def stage(x, wq, wk, wv, wo):
+        def heads(w, width):
+            return (x @ w).reshape(b, s, h, width).transpose(0, 2, 1, 3)
+        o = flash_attention(heads(wq, d), heads(wk, d), heads(wv, dv),
+                            causal=True, scale=d ** -0.5, platform="tpu")
+        return x + o.transpose(0, 2, 1, 3).reshape(b, s, h * dv) @ wo
+
+    def compiled(fn):
+        def loss(*a):
+            return fn(*a).astype(jnp.float32).sum()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))) \
+            .lower(sds(b, s, hidden), sds(hidden, h * d), sds(hidden, h * d),
+                   sds(hidden, h * dv), sds(h * dv, hidden)).compile()
+
+    def kernels(program):
+        calls = [line for line in program.as_text().split("\n")
+                 if "custom-call(" in line and KERNEL in line]
+        return [sum(name in line for line in calls) for name in (
+            "mx_flash_attention_fwd", "mx_flash_attention_bwd_dq",
+            "mx_flash_attention_bwd_dkv")]
+
+    bare = compiled(jax.checkpoint(stage))
+    kept = compiled(executor._rematerialised(stage))
+    assert kernels(bare) == [2, 1, 1]
+    assert kernels(kept) == [1, 1, 1]
+    assert kept.memory_analysis().temp_size_in_bytes <= \
+        bare.memory_analysis().temp_size_in_bytes + 0.16e9
