@@ -26,9 +26,9 @@ OUR op registry names (ops/registry.py). Three buckets:
            the dtype the inputs arrive in (the amp dtype, from the ALLOW
            ops before them) and accumulate in fp32; decays, cumulative
            sums, recurrent state, norm statistics, router scores with
-           their top-k, a rotation's angles and sines and the loss are
-           fp32 inside. The funnel casts
-           nothing for them.
+           their top-k, a rotation's angles and sines, a short
+           convolution's taps and sums and the loss are fp32 inside. The
+           funnel casts nothing for them.
 
 Two tables name inputs that no trainer may narrow on the way in
 (parallel/dp.py casts parameters and float data to the compute dtype
@@ -100,16 +100,19 @@ MIXED = frozenset({
     "_contrib_kda",
     "_contrib_moe_experts",
     "_contrib_rope",
+    "_contrib_gated_short_conv",
     "_contrib_lm_head_ce",
 })
 
 # op -> inputs whose PARAMETER stays fp32 all the way into the op: the
 # decay's rate and bias (exp of a bf16 A_log is off by up to 0.4% a step,
 # compounded over the sequence), the router and its selection bias (a
-# rounded score changes which experts are chosen)
+# rounded score changes which experts are chosen), the gated short
+# convolution's taps (3 a channel: their gradient is a sum over every token)
 KEEP_FP32 = {
     "_contrib_kda": ("A_log", "dt_bias"),
     "_contrib_moe_experts": ("router_weight", "router_bias"),
+    "_contrib_gated_short_conv": ("weight",),
 }
 
 # op -> inputs that carry integers in a float array (MXNet's convention

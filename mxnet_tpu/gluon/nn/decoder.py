@@ -2,16 +2,22 @@
 
 One pre-norm block, `x <- x + mixer(RMSNorm(x))`, `x <- x + mlp(RMSNorm(x))`,
 whose mixer and MLP kinds are read per layer from the published keys of a
-model's config.json (ROADMAP R0): `linear_attn_config.kda_layers` /
-`full_attn_layers` (1-based, as config.json counts them) pick Kimi Delta
-Attention or multi-head latent attention, and a config without
-`linear_attn_config` is latent attention in every layer;
-`first_k_dense_replace` picks the dense SwiGLU or the mixture of experts.
-Latent attention rotates its `qk_rope_head_dim` dims (`rope_theta`,
-`rope_interleave`) unless the config says `mla_use_nope`. The mixture's
-keys are read in either of two spellings (`MIXTURE_KEYS`). Users:
-Kimi-Linear-48B-A3B (arXiv:2510.26692) and `deepseek_v3` configs
-(Kanana-2-30B-A3B). What a config asks for and no layer here computes
+model's config.json (ROADMAP R0). A config with `layer_types` names each
+layer's mixer there (0-based, as that list counts): `"conv"` is the gated
+short convolution, `"full_attention"` grouped-query attention with per-head
+q/k norms and a rotation of the whole head. Otherwise
+`linear_attn_config.kda_layers` / `full_attn_layers` (1-based, as
+config.json counts them) pick Kimi Delta Attention or multi-head latent
+attention, and a config without `linear_attn_config` is latent attention in
+every layer. `num_dense_layers` or `first_k_dense_replace` picks the dense
+SwiGLU or the mixture of experts. Latent attention rotates its
+`qk_rope_head_dim` dims (`rope_theta`, `rope_interleave`) unless the config
+says `mla_use_nope`. The mixture's keys are read in any of three spellings
+(`MIXTURE_KEYS`), the norms' epsilon in two (`rms_norm_eps`, `norm_eps`).
+`tie_word_embeddings` makes the head the embedding matrix: one parameter
+with two uses, whose gradient is the sum of both. Users: Kimi-Linear-48B-A3B
+(arXiv:2510.26692), `deepseek_v3` configs (Kanana-2-30B-A3B) and `lfm2_moe`
+configs (LFM2-8B-A1B). What a config asks for and no layer here computes
 raises NotImplementedError with the key's name. `TransformerEncoder`
 (transformer.py) is the older, hard-wired block and stays as it is.
 
@@ -34,8 +40,8 @@ from ... import initializer
 from ...base import AttrScope
 from ..block import HybridBlock
 
-__all__ = ["KDAMixer", "MLAMixer", "SwiGLU", "MoEMLP", "DecoderBlock",
-           "DecoderLM"]
+__all__ = ["KDAMixer", "MLAMixer", "ShortConvMixer", "GQAMixer", "SwiGLU",
+           "MoEMLP", "DecoderBlock", "DecoderLM"]
 
 
 def _dense(F, x, weight, units):
@@ -60,7 +66,10 @@ def _scope(name):
     return AttrScope(profiler_scope=name)
 
 
-# the mixture's settings: (Kimi-Linear's key, DeepSeek-V3's key)
+# the mixture's settings: (Kimi-Linear's key, DeepSeek-V3's key); LFM2's
+# third spelling takes a key from either (`num_experts`,
+# `num_experts_per_tok`, `norm_topk_prob`), has none for shared experts and
+# one of its own for the selection bias
 MIXTURE_KEYS = {
     "num_experts": ("num_experts", "n_routed_experts"),
     "top_k": ("num_experts_per_token", "num_experts_per_tok"),
@@ -69,28 +78,46 @@ MIXTURE_KEYS = {
     "scoring": ("moe_router_activation_func", "scoring_func"),
     "groups": ("num_expert_group", "n_group"),
     "method": ("topk_method", "topk_method"),
+    "bias": ("use_expert_bias", "use_expert_bias"),
 }
 # what `ops/lm.py::moe_route` computes: sigmoid scores, one group, the top
-# k of score + bias; also what a config that is silent on them gets
-_ROUTER_COMPUTES = {"scoring": "sigmoid", "groups": 1, "method": "noaux_tc"}
+# k of score + bias (a buffer that takes no gradient)
+_ROUTER_COMPUTES = {"scoring": "sigmoid", "groups": 1, "method": "noaux_tc",
+                    "bias": True}
 
 
 def mixture_settings(cfg):
     """{setting: value} of the mixture layers, from whichever spelling the
     config uses; a router other than the one computed here raises by the
-    key's name."""
+    key's name. A config silent on the router gets the one computed here;
+    one silent on its shared experts has none only in LFM2's spelling (the
+    family with `use_expert_bias` has no key for them), and a KeyError in
+    Kimi's or DeepSeek-V3's."""
+    silent = dict(_ROUTER_COMPUTES)
+    if "use_expert_bias" in cfg:
+        silent["num_shared"] = 0
     out = {}
     for name, keys in MIXTURE_KEYS.items():
         key = next((k for k in keys if k in cfg), None)
-        if key is None and name not in _ROUTER_COMPUTES:
+        if key is None and name not in silent:
             raise KeyError(f"mixture of experts: the config has neither "
                            f"{keys[0]!r} nor {keys[1]!r}")
-        out[name] = cfg[key] if key else _ROUTER_COMPUTES[name]
+        out[name] = cfg[key] if key else silent[name]
         if out[name] != _ROUTER_COMPUTES.get(name, out[name]):
             raise NotImplementedError(
                 f"mixture of experts: {key} = {out[name]!r} (computed "
                 f"here: {_ROUTER_COMPUTES[name]!r})")
     return out
+
+
+def _either(cfg, *keys):
+    """The value under the first of `keys` that the config has (one
+    setting, spelled differently by family); the last one's KeyError."""
+    return cfg[next((k for k in keys if k in cfg), keys[-1])]
+
+
+def _eps(cfg):
+    return _either(cfg, "rms_norm_eps", "norm_eps")
 
 
 class KDAMixer(HybridBlock):
@@ -196,6 +223,92 @@ class MLAMixer(HybridBlock):
             return _dense(F, o, wo, self._d)
 
 
+class ShortConvMixer(HybridBlock):
+    """LFM2's gated short convolution: `[B, C, u] = W_in x`, a depthwise
+    causal convolution of `conv_L_cache` taps over `B * u`, gated by `C`,
+    then `W_out`. No bias, no activation."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        from ...ops.lm import SHORT_CONV_MAX_TAPS
+        if cfg.get("conv_bias"):
+            raise NotImplementedError(
+                f"ShortConvMixer: conv_bias = {cfg['conv_bias']!r}")
+        self._kw = cfg["conv_L_cache"]
+        if not 1 <= self._kw <= SHORT_CONV_MAX_TAPS:
+            raise NotImplementedError(
+                f"ShortConvMixer: conv_L_cache = {self._kw!r} "
+                f"(_contrib_gated_short_conv takes 1 to "
+                f"{SHORT_CONV_MAX_TAPS} taps)")
+        self._d = d = cfg["hidden_size"]
+        get = _getter(self)
+        self.w_in = get("w_in", shape=(3 * d, d))
+        self.taps = get("taps", shape=(d, self._kw))
+        self.w_out = get("w_out", shape=(d, d))
+
+    def hybrid_forward(self, F, x, w_in, taps, w_out):
+        d = self._d
+        with _scope("mx.sconv"):
+            y = F._contrib_gated_short_conv(_dense(F, x, w_in, 3 * d), taps,
+                                            kernel=self._kw)
+            return _dense(F, y, w_out, d)
+
+
+class GQAMixer(HybridBlock):
+    """Grouped-query attention as LFM2 has it: `num_attention_heads` query
+    heads over `num_key_value_heads` key/value heads of hidden / heads dims
+    (query head h attends to k/v head h // group), an RMSNorm with a learned
+    scale over each query and key head, then the rotation of the whole head
+    by position, halves paired (`_contrib_rope`, `interleave` false). k and
+    v reach `_contrib_flash_attention` at their own head count: a group's
+    shared head is never repeated."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        for key in ("rope_scaling", "attention_bias", "sliding_window"):
+            if cfg.get(key):
+                raise NotImplementedError(f"GQAMixer: {key} = {cfg[key]!r}")
+        d = cfg["hidden_size"]
+        self._h, self._hkv = (cfg["num_attention_heads"],
+                              cfg["num_key_value_heads"])
+        if d % self._h or self._h % self._hkv:
+            raise ValueError(
+                f"GQAMixer: hidden_size {d}, num_attention_heads {self._h}, "
+                f"num_key_value_heads {self._hkv} do not divide")
+        self._dh, self._d, self._eps = d // self._h, d, _eps(cfg)
+        self._theta = cfg["rope_theta"]
+        get = _getter(self)
+        self.wq = get("wq", shape=(self._h * self._dh, d))
+        self.wk = get("wk", shape=(self._hkv * self._dh, d))
+        self.wv = get("wv", shape=(self._hkv * self._dh, d))
+        self.q_norm = get("q_norm", shape=(self._dh,), init="ones")
+        self.k_norm = get("k_norm", shape=(self._dh,), init="ones")
+        self.wo = get("wo", shape=(d, self._h * self._dh))
+
+    def hybrid_forward(self, F, x, wq, wk, wv, q_norm, k_norm, wo):
+        h, hkv, dh = self._h, self._hkv, self._dh
+
+        def heads(t, n):                # (B, S, n*dh) -> (B, n, S, dh)
+            return F.transpose(F.reshape(t, shape=(0, 0, n, dh)),
+                               axes=(0, 2, 1, 3))
+
+        with _scope("mx.gqa"):
+            q = F.RMSNorm(heads(_dense(F, x, wq, h * dh), h), q_norm,
+                          eps=self._eps)
+            k = F.RMSNorm(heads(_dense(F, x, wk, hkv * dh), hkv), k_norm,
+                          eps=self._eps)
+            v = heads(_dense(F, x, wv, hkv * dh), hkv)
+            with _scope("mx.gqa.rope"):
+                q, k = (F._contrib_rope(t, rotary_dim=dh, offset=0,
+                                        theta=self._theta, interleave=False)
+                        for t in (q, k))
+            o = F._contrib_flash_attention(q, k, v, causal=True,
+                                           scale=dh ** -0.5)
+            o = F.reshape(F.transpose(o, axes=(0, 2, 1, 3)),
+                          shape=(0, 0, -1))
+            return _dense(F, o, wo, self._d)
+
+
 class SwiGLU(HybridBlock):
     """down(SiLU(gate x) * up x); `names` are the three parameters' names."""
 
@@ -216,7 +329,8 @@ class SwiGLU(HybridBlock):
 
 class MoEMLP(HybridBlock):
     """Sigmoid-routed mixture: the experts held here (grouped products over
-    the token-expert pairs that fall on them) plus the shared expert, whole.
+    the token-expert pairs that fall on them) plus the shared expert, whole,
+    where the config has one (no branch is built at 0 shared experts).
     Returns (y, stats); stats as `ops/lm.py::moe_experts` gives them."""
 
     def __init__(self, cfg, **kwargs):
@@ -235,15 +349,19 @@ class MoEMLP(HybridBlock):
         self.e_gate = get("e_gate", shape=(n, d, w))
         self.e_up = get("e_up", shape=(n, d, w))
         self.e_down = get("e_down", shape=(n, w, d))
-        with self.name_scope():
-            self.shared = SwiGLU(d, w * moe["num_shared"],
-                                 names=("s_gate", "s_up", "s_down"),
-                                 prefix="")
+        self.shared = None
+        if moe["num_shared"]:
+            with self.name_scope():
+                self.shared = SwiGLU(d, w * moe["num_shared"],
+                                     names=("s_gate", "s_up", "s_down"),
+                                     prefix="")
 
     def hybrid_forward(self, F, x, w_r, r_bias, e_gate, e_up, e_down):
         # the op names its two halves mx.moe.route and mx.moe.experts
         routed = F._contrib_moe_experts(x, w_r, r_bias, e_gate, e_up,
                                         e_down, **self._attrs)
+        if self.shared is None:
+            return routed[0], routed[1]
         with _scope("mx.moe.shared"):
             y = routed[0] + self.shared(x)
         return y, routed[1]
@@ -255,7 +373,14 @@ class DecoderBlock(HybridBlock):
     def __init__(self, cfg, layer, **kwargs):
         super().__init__(**kwargs)
         lin = cfg.get("linear_attn_config")
-        if lin is None or layer in lin["full_attn_layers"]:
+        if "layer_types" in cfg:
+            kind = cfg["layer_types"][layer - 1]
+            mixer = {"conv": ShortConvMixer,
+                     "full_attention": GQAMixer}.get(kind)
+            if mixer is None:
+                raise NotImplementedError(
+                    f"DecoderBlock: layer_types[{layer - 1}] = {kind!r}")
+        elif lin is None or layer in lin["full_attn_layers"]:
             mixer = MLAMixer
         elif layer in lin["kda_layers"]:
             mixer = KDAMixer
@@ -263,8 +388,9 @@ class DecoderBlock(HybridBlock):
             raise ValueError(f"layer {layer} is in neither kda_layers nor "
                              "full_attn_layers")
         d = cfg["hidden_size"]
-        self._eps = cfg["rms_norm_eps"]
-        self._moe = layer > cfg["first_k_dense_replace"]
+        self._eps = _eps(cfg)
+        self._moe = layer > _either(cfg, "num_dense_layers",
+                                    "first_k_dense_replace")
         get = _getter(self)
         self.norm1 = get("norm1", shape=(d,), init="ones")
         self.norm2 = get("norm2", shape=(d,), init="ones")
@@ -286,20 +412,24 @@ class DecoderBlock(HybridBlock):
 
 
 class DecoderLM(HybridBlock):
-    """Embedding, `num_hidden_layers` blocks (published layers 1..n), final
-    RMSNorm and an untied head. `net(tokens)` gives the logits;
-    `net(tokens, labels)` gives the (batch, sequence) cross-entropy of each
-    position and, where there are mixture layers, their stacked stats
-    (layers, experts held + 3) as a second output."""
+    """Embedding, `num_hidden_layers` blocks (the config's first n layers),
+    final RMSNorm and a head: a parameter of its own (Kimi-Linear,
+    `deepseek_v3`) or, under `tie_word_embeddings` (`lfm2_moe`), the
+    embedding matrix itself, whose gradient is then the sum of its two
+    uses'. `net(tokens)` gives the logits; `net(tokens, labels)` gives the
+    (batch, sequence) cross-entropy of each position and, where there are
+    mixture layers, their stacked stats (layers, experts held + 3) as a
+    second output."""
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
         d, v = cfg["hidden_size"], cfg["vocab_size"]
-        self._d, self._v, self._eps = d, v, cfg["rms_norm_eps"]
+        self._d, self._v, self._eps = d, v, _eps(cfg)
         get = _getter(self)
         self.embed = get("embed", shape=(v, d))
         self.norm_f = get("norm_f", shape=(d,), init="ones")
-        self.head = get("head", shape=(v, d))
+        if not cfg.get("tie_word_embeddings"):
+            self.head = get("head", shape=(v, d))
         self.blocks = []
         with self.name_scope():
             for i in range(cfg["num_hidden_layers"]):
@@ -310,6 +440,8 @@ class DecoderLM(HybridBlock):
     def hybrid_forward(self, F, tokens, labels=None, embed=None,
                        norm_f=None, head=None):
         x = F.Embedding(tokens, embed, input_dim=self._v, output_dim=self._d)
+        if head is None:        # tied: the embedding matrix is the head
+            head = embed
         stats = []
         for block in self.blocks:
             x, s = block(x)

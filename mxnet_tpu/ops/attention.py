@@ -279,7 +279,10 @@ def _vmem_params(s, d, n_full_streams, interpret, itemsize=2):
     budget is a compiler default, not the hardware bound."""
     if interpret:
         return {}
-    need = n_full_streams * s * d * itemsize * 2   # x2 double buffering
+    # a minor dim under 128 is tiled out to a whole lane row in VMEM (heads
+    # of 64: the dK/dV kernel's three streams took 20.75M of the default
+    # 16M at 8,192 tokens; compile, PR 32)
+    need = n_full_streams * s * max(d, 128) * itemsize * 2   # x2 buffers
     if need <= 8 * 2 ** 20:
         # q/out blocks + lse + scratch ride within the default budget
         return {}
@@ -777,7 +780,15 @@ def decode_attention(q, k, v, lengths, scale=None, force=None,
 
 # -- registry surface -------------------------------------------------------
 
+GQA_COUNTER = "gqa_attention_calls_total"
+
+
 def _flash_attention_op(attrs, octx, q, k, v):
+    if k.shape[1] != q.shape[1]:
+        from ..telemetry import registry
+        registry.counter(
+            GQA_COUNTER, help="attention calls traced with fewer k/v heads "
+            "than query heads (a group of query heads shares one)").inc()
     with jax.named_scope("mx.flash_attention"):
         return _t(flash_attention(q, k, v, causal=attrs["causal"],
                                   scale=attrs["scale"],

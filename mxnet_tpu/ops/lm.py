@@ -4,12 +4,15 @@ TPU-first new surface (the reference has none of these): `RMSNorm`, the
 Kimi Delta Attention mixer core `_contrib_kda` (short convolutions, decay
 and a chunkwise-parallel gated delta rule), the held-experts mixture
 `_contrib_moe_experts`, the rotary position embedding `_contrib_rope` (a
-part of a head's dims turned in place, in one pass) and the fused head
+part of a head's dims turned in place, in one pass), the gated short
+convolution `_contrib_gated_short_conv` (LFM2's operator between its two
+products) and the fused head
 `_contrib_lm_head_ce` whose output is the per-token loss. Each manages its
 own precision (amp/policy.py lists them under MIXED): matrix products take
 the dtype their inputs arrive in and accumulate in float32; the decay, the
 chunk state, the norms' statistics, the router, the rotation's angles and
-sines and the loss are float32 whatever arrives.
+sines, the short convolution's taps and sums and the loss are float32
+whatever arrives.
 
 The delta rule, per head, with S in R^(dk x dv) and g the log-decay:
 
@@ -443,6 +446,104 @@ register("_contrib_rope", _rope_op,
                  "theta": Param("float", 10000.0),
                  "interleave": Param("bool", True)},
          inputs=("data",))
+
+
+# -- gated short convolution --------------------------------------------------------
+
+SHORT_CONV_COUNTER = "short_conv_calls_total"
+SHORT_CONV_MAX_TAPS = 8     # the shifted sum below is unrolled over the taps
+
+
+def _chunks(x):
+    c = x.shape[-1] // 3
+    return x[..., :c], x[..., c:2 * c], x[..., 2 * c:]
+
+
+def _gated_products(x, kw):
+    """[z_(t - (kw - 1) + j) for j in range(kw)], z = B * u in float32, zeros
+    before the start. Written as slices of ONE zero-padded copy of x in its
+    own dtype: the TPU compiler then fuses pad, slices, converts and
+    products into the consumer's single pass over x (shifted copies of the
+    float32 product cost it a float32 copy of x and of z: compile, PR 32)."""
+    s = x.shape[1]
+    b_gate, _, u = _chunks(jnp.pad(x, ((0, 0), (kw - 1, 0), (0, 0))))
+    return [b_gate[:, j:j + s].astype(_F32) * u[:, j:j + s].astype(_F32)
+            for j in range(kw)]
+
+
+def _tap_sum(z, w):
+    """c_t = sum_j w[:, j] * z_(t - (kw - 1) + j) from `_gated_products`."""
+    return sum(z_j * w[:, j] for j, z_j in enumerate(z))
+
+
+@jax.custom_vjp
+def gated_short_conv(x, w):
+    """LFM2's operator between its two products: x (B, S, 3C) holds three
+    chunks [B, C, u] of C channels, w (C, kw) the taps of a depthwise causal
+    convolution over time: c_t = sum_j w[:, j] * (B * u)_(t - (kw - 1) + j),
+    zeros before the start, no activation; y = C * c. Products and sums are
+    float32 whatever x's dtype, which the result takes. The backward pass
+    keeps x and w and nothing else, and reads x and the cotangent for all
+    three chunks' gradients and the taps'."""
+    w = w.astype(_F32)
+    conv = _tap_sum(_gated_products(x, w.shape[1]), w)
+    return (_chunks(x)[1].astype(_F32) * conv).astype(x.dtype)
+
+
+def _gated_short_conv_fwd(x, w):
+    return gated_short_conv(x, w), (x, w)
+
+
+def _gated_short_conv_bwd(res, dy):
+    x, w_in = res
+    w = w_in.astype(_F32)
+    kw, s = w.shape[1], x.shape[1]
+    b_gate, c_gate, u = (t.astype(_F32) for t in _chunks(x))
+    z = _gated_products(x, kw)
+    # z_t reaches c_(t + k) through tap kw - 1 - k: the gated cotangent at
+    # later rows, as slices of copies zero-padded at the end
+    later = ((0, 0), (0, kw - 1), (0, 0))
+    c_later, dy_later = _chunks(jnp.pad(x, later))[1], jnp.pad(dy, later)
+    dz = sum(dy_later[:, k:k + s].astype(_F32)
+             * c_later[:, k:k + s].astype(_F32) * w[:, kw - 1 - k]
+             for k in range(kw))
+    dy = dy.astype(_F32)
+    dc = dy * c_gate
+    dw = jnp.stack([jnp.sum(z[j] * dc, axis=(0, 1)) for j in range(kw)], -1)
+    dx = jnp.concatenate([dz * u, dy * _tap_sum(z, w), dz * b_gate], -1)
+    return dx.astype(x.dtype), dw.astype(w_in.dtype)
+
+
+gated_short_conv.defvjp(_gated_short_conv_fwd, _gated_short_conv_bwd)
+
+
+def _gated_short_conv_op(attrs, octx, data, weight):
+    kw = attrs["kernel"]
+    if data.shape[-1] % 3 or weight.shape != (data.shape[-1] // 3, kw) \
+            or not 1 <= kw <= SHORT_CONV_MAX_TAPS:
+        raise ValueError(
+            f"_contrib_gated_short_conv: data {data.shape} is three chunks "
+            f"of C channels and weight (C, kernel) with 1 <= kernel <= "
+            f"{SHORT_CONV_MAX_TAPS}; got weight {weight.shape}, kernel {kw}")
+    _count(SHORT_CONV_COUNTER, "gated short convolutions traced into a "
+           "program")
+    with jax.named_scope("mx.sconv.conv"):
+        return _t(gated_short_conv(data, weight))
+
+
+def _gated_short_conv_infer(attrs, in_shapes):
+    in_shapes = list(in_shapes)
+    ds = in_shapes[0]
+    if ds is None:
+        return in_shapes, [None]
+    if in_shapes[1] is None:
+        in_shapes[1] = (ds[-1] // 3, attrs["kernel"])
+    return in_shapes, [tuple(ds[:-1]) + (ds[-1] // 3,)]
+
+
+register("_contrib_gated_short_conv", _gated_short_conv_op,
+         params={"kernel": Param("int", 3)},
+         inputs=("data", "weight"), infer_shape=_gated_short_conv_infer)
 
 
 # -- held-experts mixture -------------------------------------------------------

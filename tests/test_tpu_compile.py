@@ -112,6 +112,33 @@ def test_kda_kernels_forward_and_gradient(one_chip, chunk):
                           qkv, qkv, qkv, g, beta).count(KERNEL) == 2
 
 
+def test_flash_attention_grouped_heads_of_64(one_chip):
+    """Grouped-query attention at LFM2-8B-A1B's published widths: 32 query
+    heads over 8 k/v heads of 64, two sequences of 8,192. A minor dim of 64
+    is tiled out to 128 lanes in VMEM: the dK/dV kernel's three full
+    streams took 20.75M of the default 16M of scoped VMEM until
+    `_vmem_params` counted that (PERF.md, PR 32). k and v enter at their
+    own 8 heads: nothing of 32 heads' size but q, the output and their
+    gradients exists."""
+    from mxnet_tpu.ops.attention import flash_attention
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=64 ** -0.5,
+                               platform="tpu")
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    assert _compiled_text(fwd, q, kv, kv).count(KERNEL) == 1
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count(KERNEL) == 3
+    assert "bf16[16,8192,64]" in text and "repeat" not in text
+
+
 def _kda_operands(one_chip, b=2, s=8192, h=32, d=128):
     """`_contrib_kda`'s ten operands at `kimi_linear.train`'s shape."""
     def sds(shape, dtype=jnp.bfloat16):
@@ -247,6 +274,36 @@ def test_rope_moves_its_operand_once(one_chip, way):
              if opcode not in _NO_TRAFFIC and any(n >= full
                                                   for n, _ in arrays)]
     assert len(large) == 1, large
+
+
+@pytest.mark.parametrize("way,limit", [("forward", 0.30e9),
+                                       ("gradient", 1.5e9)])
+def test_gated_short_conv_traffic(one_chip, way, limit):
+    """`lm.gated_short_conv` at `lfm2_moe.train`'s shape (2 x 8,192 tokens,
+    three chunks of 2,048 channels, bf16, float32 taps). Forward: ONE
+    fusion that reads the three chunks and writes one, 0.27 GB (pad,
+    slices, converts and products fused; written with shifted copies of the
+    float32 product it moved 2.2 GB: a float32 copy of x and of B * u).
+    The gradient is not one pass yet: a multiply-reduce fusion (the taps'
+    gradient, two chunks, float32 dz), a fusion for the third chunk and a
+    concatenate move 1.34 GB where 0.47 would do (PERF.md section 7)."""
+    from mxnet_tpu.ops import lm
+    x = jax.ShapeDtypeStruct((2, 8192, 3 * 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((2048, 3), jnp.float32, sharding=one_chip)
+    dy = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16,
+                              sharding=one_chip)
+    if way == "forward":
+        ins = _entry_instructions(_compiled_text(lm.gated_short_conv, x, w))
+        large = [name for name, opcode, arrays, _ in ins
+                 if opcode not in _NO_TRAFFIC
+                 and any(n >= 2 * 8192 * 2048 for n, _ in arrays)]
+        assert len(large) == 1, large
+    else:
+        ins = _entry_instructions(_compiled_text(
+            lambda x, w, dy: jax.vjp(lm.gated_short_conv, x, w)[1](dy),
+            x, w, dy))
+    assert _traffic(ins) <= limit
 
 
 @pytest.mark.parametrize("h_kv", [8, 2])

@@ -152,6 +152,7 @@ CASES = {
     "_contrib_div_sqrt_dim": dict(shapes=[(2, 8)]),
     "_contrib_rope": dict(attrs={"rotary_dim": 4, "offset": 2},
                           shapes=[(2, 3, 8)]),
+    "_contrib_gated_short_conv": dict(shapes=[(2, 5, 12), (4, 3)]),
     "RMSNorm": dict(shapes=[(2, 6), (6,)]),
     "_contrib_AdaptiveAvgPooling2D": dict(attrs={"output_size": (2, 2)},
                                           shapes=[(1, 3, 6, 6)]),
