@@ -5,7 +5,12 @@ The reference has no attention op (its transformer support is the helper
 TPU-first new surface: a blockwise online-softmax kernel written in Pallas
 (per /opt/skills/guides/pallas_guide.md) that keeps the (S, S) score
 matrix out of HBM, gridded over (batch*heads, q-blocks) with the K/V
-stream resident in VMEM. Dispatch picks the kernel on TPU for
+stream resident in VMEM, and ONE backward kernel that rebuilds each block
+pair's softmax once from the saved row statistic and feeds dQ, dK and dV
+from it. Forward and backward share the causal loop bounds
+(`_causal_bounds`: no mask below the diagonal, a mask on the blocks that
+straddle it, nothing above it), and the row statistics (lse, delta) travel
+along the lanes, (B*H, 1, S). Dispatch picks the kernels on TPU for
 tile-friendly shapes and falls back to a fused XLA implementation
 elsewhere (including the CPU test mesh). The sequence-parallel versions
 live in mxnet_tpu.parallel.sp.
@@ -21,8 +26,6 @@ from jax.ad_checkpoint import checkpoint_name
 from .registry import Param, kept_residual, register
 
 _BLOCK_Q = 128    # floor tile; _auto_block picks larger when S allows
-_BLOCK_K = 128
-_LSE_LANES = 8    # minor replication of the per-row lse (TPU block tiling)
 # what the flash backward needs beyond q, k and v, which a rematerialised
 # stage's rerun rebuilds from its products: computing these two again is
 # the whole forward kernel, keeping them is 2*B*H*S*Dv + 4*B*H*S bytes
@@ -33,13 +36,33 @@ _KEPT_LSE = kept_residual("mx_flash_attention_lse")
 def _auto_block(s):
     """Default block size: the LARGEST of 512/256/128 dividing S —
     bigger tiles amortize the per-block softmax bookkeeping and keep the
-    MXU busier (its gain on this runtime is a claim to re-measure,
-    ROADMAP S10 (e)). Sequences not
+    MXU busier. Timed on a v5e at 8,192 tokens with 256, 512 and 1,024 on
+    each side (`tests_tpu/test_flash_kernels.py`; PERF.md §6, PR 33): 512 x
+    512 is the fastest or within 1% of it for the forward and the backward
+    at 64 heads of 192 / 128, and for the backward at 64 over 16 heads of
+    64, whose forward alone prefers 1,024 rows of q, by 5%; 256 on either
+    side loses 4 to 90%. Sequences not
     divisible by 128 fall back to a single block (small-S case)."""
     for blk in (512, 256, 128):
         if s % blk == 0:
             return blk
     return min(_BLOCK_Q, s)
+
+
+def _blocks(s, block_q, block_k):
+    """The (q, k) blocks of a call: the override or `_auto_block`'s, each
+    tiling S. Both kernels slice rows of S along the LANES (the transposed
+    blocks, the (1, S) row statistics), so a block that is not the whole
+    sequence is a multiple of 128."""
+    blocks = tuple(min(blk or _auto_block(s), s) for blk in (block_q, block_k))
+    for blk in blocks:
+        # forced/explicit blocks that don't tile S would silently leave
+        # grid-truncated output rows unwritten
+        if s % blk or (blk != s and blk % 128):
+            raise ValueError(
+                f"flash attention: seq {s} is not divisible by blocks "
+                f"{blocks}, or a block is no multiple of 128")
+    return blocks
 
 
 def _t(*o):
@@ -82,178 +105,192 @@ def reference_attention_with_lse(q, k, v, causal=False, scale=None):
     return out.astype(q.dtype), lse
 
 
+def _causal_bounds(q_start, block_q, block_k, n_blocks, causal):
+    """(n_full, n_visited) of the k-blocks a q-block starting at `q_start`
+    meets, shared by the forward and the backward so that both rebuild the
+    same softmax: blocks [0, n_full) lie wholly on or below the diagonal
+    (their last column is at most the q-block's first row) and need no
+    mask; [n_full, n_visited) straddle it and are masked element by
+    element; the rest lie wholly above it and are skipped."""
+    if not causal:
+        return n_blocks, n_blocks
+    return ((q_start + 1) // block_k,
+            jnp.minimum(n_blocks,
+                        (q_start + block_q + block_k - 1) // block_k))
+
+
+def _k_rows(i, block_k, seq_len):
+    """(first row, slice) of k-block `i` inside a kernel. A sequence of one
+    block (the only case of a block under 128) is sliced statically: the
+    backward slices these rows along the lanes of dK^T too, where Mosaic
+    takes a dynamic start only if it can see a multiple of 128."""
+    import jax.experimental.pallas as pl
+    if block_k == seq_len:
+        return 0, slice(None)
+    start = pl.multiple_of(i * block_k, block_k)
+    return start, pl.dslice(start, block_k)
+
+
+def _scores_t(k_blk, q, scale, k_start, q_start, masked):
+    """One pair's scaled scores, TRANSPOSED: (Bk, Bq) float32 from operands
+    in their storage dtype, with the causal mask where the pair straddles
+    the diagonal. The forward's and the backward's alike: a backward that
+    rebuilt p from other scores than the forward's would be a bug to
+    chase. The scale is applied to the float32 scores (numerically at
+    least as good as scaling q)."""
+    s = jax.lax.dot_general(
+        k_blk, q, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if masked:
+        k_pos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (k_blk.shape[0], 1), 0)
+        q_pos = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (1, q.shape[0]), 1)
+        s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+    return s
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_len,
                   causal, scale):
     """One (bh, q-block) grid cell: stream K/V blocks with online softmax.
-    Also writes the per-row logsumexp — the backward's saved statistic."""
+    Each score block is computed TRANSPOSED, (Bk, Bq): the running max and
+    sum are ROWS along the lanes (a reduction over sublanes is elementwise
+    work where one over lanes goes through the cross-lane unit, and a row
+    of 512 is 4 registers where a column is 64), the output accumulates as
+    (Dv, Bq) and is transposed once, and the per-row logsumexp — the
+    backward's saved statistic — leaves as a row of the (1, S) statistic."""
     import jax.experimental.pallas as pl
 
-    q_block = q_ref.shape[0]
     # keep q in its storage dtype: the MXU runs bf16 matmuls at full rate
     # while an fp32 upcast would halve+ throughput; accumulation happens
-    # in fp32 via preferred_element_type, and the scale is applied to the
-    # fp32 scores (numerically at least as good as scaling q)
-    q = q_ref[:]                                        # (Bq, D)
-    q_start = pl.program_id(1) * q_block
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (q_block, 1), 0)
+    # in fp32 via preferred_element_type
+    q = q_ref[...]                                      # (Bq, D)
+    block_q = q.shape[0]
+    q_start = pl.program_id(1) * block_q
+    n_full, n_visited = _causal_bounds(q_start, block_q, block_k,
+                                       seq_len // block_k, causal)
 
-    acc0 = jnp.zeros((q_block, v_ref.shape[1]), jnp.float32)
-    m0 = jnp.full((q_block, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((q_block, 1), jnp.float32)
-    n_blocks = seq_len // block_k
-    if causal:
-        # flash-attention causal skip: blocks fully above the diagonal
-        # contribute nothing — bound the scan at the q-block's last row
-        n_blocks = jnp.minimum(
-            n_blocks, (q_start + q_block + block_k - 1) // block_k)
-
-    def body(i, carry):
+    def pair(masked, i, carry):
         acc, m, l = carry
-        start = i * block_k
-        k_blk = k_ref[pl.dslice(start, block_k), :]
-        v_blk = v_ref[pl.dslice(start, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (Bq, Bk)
-        if causal:
-            k_pos = start + jax.lax.broadcasted_iota(jnp.int32,
-                                                     (1, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        m_blk = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m, m_blk)
-        safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.exp(s - safe)
-        p = jnp.where(jnp.isneginf(s), 0.0, p)
-        corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - safe))
-        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * corr + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
+        start, rows = _k_rows(i, block_k, seq_len)
+        k_blk = k_ref[rows, :]
+        v_blk = v_ref[rows, :]
+        s = _scores_t(k_blk, q, scale, start, q_start, masked)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        if masked:
+            safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+            p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - safe))
+            corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - safe))
+        else:
+            # every score is finite, so the running max is, and
+            # exp(-inf - m_new) of the first block's m is the 0 it needs
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+        acc = acc * corr + jax.lax.dot_general(
+            v_blk, p.astype(v_blk.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (Dv, Bq)
+        return acc, m_new, l * corr + jnp.sum(p, axis=0, keepdims=True)
 
-    acc, m, l = jax.lax.fori_loop(0, n_blocks, body, (acc0, m0, l0))
+    carry = (jnp.zeros((v_ref.shape[1], block_q), jnp.float32),
+             jnp.full((1, block_q), -jnp.inf, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32))
+    carry = jax.lax.fori_loop(0, n_full, functools.partial(pair, False),
+                              carry)
+    if causal:
+        carry = jax.lax.fori_loop(n_full, n_visited,
+                                  functools.partial(pair, True), carry)
+    acc, m, l = carry
     l_safe = jnp.where(l == 0, 1.0, l)
-    o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
+    o_ref[...] = (acc / l_safe).T.astype(o_ref.dtype)
     # rows with no valid key (UNREACHABLE for kernel-eligible shapes:
     # self-attention with s_q == s_k always has the diagonal key): the
     # +inf sentinel makes every backward p = exp(s - lse) collapse to 0,
     # matching the zero forward output. NOTE the dense with-lse oracle
     # uses -inf for empty rows (the merge-correct logsumexp-of-empty
     # convention) — the two only disagree on rows that cannot exist here.
-    # The row statistic is replicated across a minor dim of 8 — the
-    # smallest lane count the TPU lowering accepts for a blocked store
-    lse = jnp.where(l == 0, jnp.inf, m + jnp.log(l_safe))
-    lse_ref[:] = jnp.broadcast_to(lse, (q_block, _LSE_LANES))
+    lse_ref[...] = jnp.where(l == 0, jnp.inf, m + jnp.log(l_safe))
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                         glse_ref, dq_ref, *, block_k, seq_len, causal,
-                         scale):
-    """dQ for one (bh, q-block): stream K/V. With the saved lse the
-    softmax re-materializes blockwise (p = exp(s - lse)) — no (S, S)
-    tensor ever exists; delta = rowsum(dO * O) is recomputed in-VMEM from
-    the O/dO blocks (cheaper than a third saved row array). glse is the
-    lse OUTPUT's cotangent (ring/blockwise merging differentiates
-    through lse): dlse_i/ds_ij = p_ij, so it simply subtracts from the
-    row term — zeros when lse is not a differentiated output."""
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc, *, block_k,
+                      seq_len, causal, scale):
+    """The whole backward for one (kv head, query head of its group,
+    q-block) grid cell: every (q-block, k-block) pair's softmax is rebuilt
+    ONCE from the saved lse (p = exp(s - lse); never an (S, S) tensor) and
+    feeds all three gradients, five products a pair. Each block is computed
+    TRANSPOSED, (Bk, Bq): the row statistics lse and delta arrive as rows
+    along the lanes and broadcast over sublanes. The three gradients are
+    taken transposed too, dQ^T = k^T ds, dK^T = q^T ds^T and
+    dV^T = dO^T p^T, (D, B): the MXU pads a contraction or an output width
+    to its 128 but streams any number of rows, so there a width of 192
+    costs 192, and one of 64 costs 64, where elsewhere they cost 256 and
+    128. dQ^T is the loop's carry; dK^T and dV^T of the kv head accumulate
+    in float32 scratch that stays in VMEM across the group and q-block
+    axes (every query head of a group adds into the same one) and are
+    cast to the storage dtype once, at the head's last cell.
+    delta = rowsum(dO * O) - g_lse is computed once a row outside."""
     import jax.experimental.pallas as pl
 
-    q_block = q_ref.shape[0]
-    q = q_ref[:]
-    do = do_ref[:].astype(jnp.float32)                  # (Bq, D)
-    lse = lse_ref[:, 0:1]                               # (Bq, 1)
-    delta = jnp.sum(do * o_ref[:].astype(jnp.float32), axis=1,
-                    keepdims=True)                      # (Bq, 1)
-    if glse_ref is not None:
-        delta = delta - glse_ref[:, 0:1]
-    q_start = pl.program_id(1) * q_block
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (q_block, 1), 0)
-
+    block_q = q_ref.shape[0]
+    g, qi = pl.program_id(1), pl.program_id(2)
     n_blocks = seq_len // block_k
+
+    @pl.when(jnp.logical_and(g == 0, qi == 0))
+    def _():
+        dkt_acc[...] = jnp.zeros_like(dkt_acc)
+        dvt_acc[...] = jnp.zeros_like(dvt_acc)
+
+    # operands in their storage dtype, float32 accumulation, as the forward
+    q = q_ref[...]                                      # (Bq, D)
+    q_t = q.T                                           # (D, Bq)
+    do = do_ref[...]                                    # (Bq, Dv)
+    do_t = do.T                                         # (Dv, Bq)
+    lse = lse_ref[...]                                  # (1, Bq)
+    delta = delta_ref[...]                              # (1, Bq)
+    q_start = qi * block_q
+    n_full, n_visited = _causal_bounds(q_start, block_q, block_k, n_blocks,
+                                       causal)
+
+    def pair(masked, j, dq_t):
+        # (the writes below are to Pallas refs: they are the kernel's
+        # stores, traced into it, not Python state of the trace)
+        start, rows = _k_rows(j, block_k, seq_len)
+        k_blk = k_ref[rows, :]                          # (Bk, D)
+        v_blk = v_ref[rows, :]                          # (Bk, Dv)
+        s = _scores_t(k_blk, q, scale, start, q_start, masked)
+        p = jnp.exp(s - lse)                            # masked -> 0
+        dp = jax.lax.dot_general(
+            v_blk, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (Bk, Bq)
+        # the scale of ds goes onto the (d, rows) sums, once at the end
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dvt_acc[:, rows] += jax.lax.dot_general(  # analysis: allow=trace-state-mutation
+            do_t, p.astype(do.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (Dv, Bk)
+        dkt_acc[:, rows] += jax.lax.dot_general(  # analysis: allow=trace-state-mutation
+            q_t, ds, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (D, Bk)
+        return dq_t + jax.lax.dot_general(
+            k_blk, ds, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (D, Bq)
+
+    dq_t = jax.lax.fori_loop(0, n_full, functools.partial(pair, False),
+                             jnp.zeros(q_t.shape, jnp.float32))
     if causal:
-        n_blocks = jnp.minimum(
-            n_blocks, (q_start + q_block + block_k - 1) // block_k)
+        dq_t = jax.lax.fori_loop(n_full, n_visited,
+                                 functools.partial(pair, True), dq_t)
+    dq_ref[...] = (dq_t * scale).T.astype(dq_ref.dtype)
 
-    def body(i, dq_acc):
-        start = i * block_k
-        k_blk = k_ref[pl.dslice(start, block_k), :]
-        v_blk = v_ref[pl.dslice(start, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (Bq, Bk)
-        if causal:
-            k_pos = start + jax.lax.broadcasted_iota(jnp.int32,
-                                                     (1, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.exp(s - lse)                             # masked rows -> 0
-        dp = jax.lax.dot_general(
-            do, v_blk.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (Bq, Bk)
-        ds = p * (dp - delta) * scale
-        return dq_acc + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (Bq, D)
-
-    dq = jax.lax.fori_loop(0, n_blocks,
-                           body, jnp.zeros(q.shape, jnp.float32))
-    dq_ref[:] = dq.astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                          glse_ref, dk_ref, dv_ref, *, block_q, seq_len,
-                          causal, scale):
-    """dK/dV for one (bh, k-block): stream Q/dO/O blocks. Causal skip from
-    the other side — q-blocks strictly above this k-block see none of it
-    (fori_loop lower bound derived from the grid position)."""
-    import jax.experimental.pallas as pl
-
-    block_k = k_ref.shape[0]
-    k = k_ref[:]                                        # (Bk, D)
-    v = v_ref[:]
-    k_start = pl.program_id(1) * block_k
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-
-    first_block = k_start // block_q if causal else 0
-    n_blocks = seq_len // block_q
-
-    def body(i, carry):
-        dk_acc, dv_acc = carry
-        start = i * block_q
-        q_blk = q_ref[pl.dslice(start, block_q), :]      # (Bq, D)
-        do_blk = do_ref[pl.dslice(start, block_q), :].astype(jnp.float32)
-        lse = lse_ref[pl.dslice(start, block_q), 0:1]    # (Bq, 1)
-        delta = jnp.sum(
-            do_blk * o_ref[pl.dslice(start, block_q), :].astype(
-                jnp.float32), axis=1, keepdims=True)     # (Bq, 1)
-        if glse_ref is not None:
-            delta = delta - glse_ref[pl.dslice(start, block_q), 0:1]
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (Bq, Bk)
-        if causal:
-            q_pos = start + jax.lax.broadcasted_iota(jnp.int32,
-                                                     (block_q, 1), 0)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.exp(s - lse)
-        # dV += P^T dO  (contract over the q rows)
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (Bk, D)
-        dp = jax.lax.dot_general(
-            do_blk, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (Bq, Bk)
-        ds = p * (dp - delta) * scale
-        # dK += dS^T Q
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (Bk, D)
-        return dk_acc, dv_acc
-
-    dk, dv = jax.lax.fori_loop(
-        first_block, n_blocks, body,
-        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+    @pl.when(jnp.logical_and(g == pl.num_programs(1) - 1,
+                             qi == pl.num_programs(2) - 1))
+    def _():
+        def cast(j, _):
+            _, rows = _k_rows(j, block_k, seq_len)
+            dk_ref[rows, :] = (dkt_acc[:, rows] * scale).T.astype(  # analysis: allow=trace-state-mutation
+                dk_ref.dtype)
+            dv_ref[rows, :] = dvt_acc[:, rows].T.astype(dv_ref.dtype)  # analysis: allow=trace-state-mutation
+            return _
+        jax.lax.fori_loop(0, n_blocks, cast, 0)
 
 
 def _sds(shape, dtype, like):
@@ -269,9 +306,11 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _vmem_params(s, d, n_full_streams, interpret, itemsize=2):
+def _vmem_params(s, d, n_full_streams, interpret, itemsize=2,
+                 n_f32_streams=0):
     """Mosaic compiler params for long sequences: the kernels keep
-    full-length (S, D) K/V (and, in the backward, Q/dO/O) refs resident
+    full-length (S, D) K/V refs (and, in the backward, the dK/dV blocks
+    and their float32 accumulators, `n_f32_streams`) resident
     in VMEM with double buffering across grid cells; past ~8k tokens
     that legitimately exceeds the default 16MB scoped-vmem budget
     (measured on v5e: s=12288 wants 16.7M). Raise the per-kernel limit
@@ -280,9 +319,11 @@ def _vmem_params(s, d, n_full_streams, interpret, itemsize=2):
     if interpret:
         return {}
     # a minor dim under 128 is tiled out to a whole lane row in VMEM (heads
-    # of 64: the dK/dV kernel's three streams took 20.75M of the default
-    # 16M at 8,192 tokens; compile, PR 32)
-    need = n_full_streams * s * max(d, 128) * itemsize * 2   # x2 buffers
+    # of 64: three resident streams took 20.75M of the default 16M at
+    # 8,192 tokens; compile, PR 32)
+    row = s * max(d, 128)
+    need = n_full_streams * row * itemsize * 2 \
+        + n_f32_streams * row * 4                        # x2 buffers; x1
     if need <= 8 * 2 ** 20:
         # q/out blocks + lse + scratch ride within the default budget
         return {}
@@ -309,20 +350,14 @@ def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
     """Forward kernel. q, k (B, H | H_kv, S, D) and v (B, H_kv, S, Dv)
     with H % H_kv == 0 (GQA/MQA share kv blocks in-kernel), S % block == 0
     and D, Dv as _pallas_eligible takes them (Dv may differ from D: latent
-    attention's 192/128). Returns (out (B,H,S,Dv), lse (B*H, S, 8) f32 —
-    the row statistic lane-replicated for TPU block tiling)."""
+    attention's 192/128). Returns (out (B,H,S,Dv), lse (B*H, 1, S) f32 —
+    the row statistic along the lanes, as the backward reads it)."""
     import jax.experimental.pallas as pl
 
     b, h, s, d = q.shape
     d_v = v.shape[-1]
     h_kv = k.shape[1]
-    block_q = min(block_q or _auto_block(s), s)
-    block_k = min(block_k or _auto_block(s), s)
-    if s % block_q or s % block_k:
-        # forced/explicit blocks that don't tile S would silently leave
-        # grid-truncated output rows unwritten
-        raise ValueError(f"flash attention: seq {s} is not divisible by "
-                         f"blocks ({block_q}, {block_k})")
+    block_q, block_k = _blocks(s, block_q, block_k)
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * h_kv, s, d)
     vf = v.reshape(b * h_kv, s, d_v)
@@ -339,12 +374,11 @@ def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, d_v), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((None, block_q, _LSE_LANES),
-                         lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((None, 1, block_q), lambda bh, qi: (bh, 0, qi)),
         ],
         out_shape=[
             _sds((b * h, s, d_v), q.dtype, q),
-            _sds((b * h, s, _LSE_LANES), jnp.float32, q),
+            _sds((b * h, 1, s), jnp.float32, q),
         ],
         interpret=interpret,
         name="mx_flash_attention_fwd",
@@ -354,136 +388,92 @@ def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
 
 
 def _flash_pallas_kept(*args, **kwargs):
-    """`_flash_pallas` for the `fwd` of a custom VJP: (out, lse (B*H, S)),
-    both tagged as this op's kept residuals. The caller hands the TAGGED
-    values to its primal output and to its residuals alike: a stage that
-    keeps them then reads nothing of the rerun's kernel call, which is
-    dead code. Of the lane-replicated lse the first lane alone is kept
-    (`_lse_lanes` replicates it again for the backward kernels): a minor
-    dim of 8 is tiled out to 128 lanes in HBM, 16 times the row
-    statistic's bytes for as long as the array lives."""
+    """`_flash_pallas` for the `fwd` of a custom VJP: (out, lse (B*H, 1,
+    S)), both tagged as this op's kept residuals. The caller hands the
+    TAGGED values to its primal output and to its residuals alike: a stage
+    that keeps them then reads nothing of the rerun's kernel call, which is
+    dead code. Both are kept as the kernel wrote them and as the backward
+    kernel reads them: lse is 4 bytes a row."""
     out, lse = _flash_pallas(*args, **kwargs)
     return (checkpoint_name(out, _KEPT_OUT),
-            checkpoint_name(lse[:, :, 0], _KEPT_LSE))
+            checkpoint_name(lse, _KEPT_LSE))
 
 
-def _lse_lanes(lse):
-    """(B*H, S) -> the kernels' lane-replicated (B*H, S, 8)."""
-    return jnp.broadcast_to(lse[:, :, None], (*lse.shape, _LSE_LANES))
+BWD_COUNTER = "flash_bwd_kernel_calls_total"
 
 
 def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
                       g_lse=None, block_q=None, block_k=None):
-    """Recompute-based flash backward: two single-HBM-pass kernels (dQ
-    gridded over q-blocks; dK/dV over k-blocks) re-derive the softmax
-    from the saved lse — O(S) extra memory, never an (S, S) tensor.
+    """Recompute-based flash backward, ONE kernel (`_flash_bwd_kernel`): a
+    single pass over the block pairs re-derives the softmax from the saved
+    lse (B*H, 1, S) — O(S) extra memory, never an (S, S) tensor — and
+    feeds dQ, dK and dV from it.
     g_lse (B, H, S) is the lse output's cotangent when lse is itself a
-    differentiated output (blockwise/ring merging); None means zeros.
-    GQA: kv blocks stream shared via the index map (like the forward);
-    the dK/dV kernel still produces PER-Q-HEAD partials, reduced over
-    each group outside the kernel (one cheap XLA sum — the simple,
-    correct realization; an in-kernel cross-head accumulation would
-    need grid-order-dependent output aliasing)."""
+    differentiated output (blockwise/ring merging): dlse_i/ds_ij = p_ij,
+    so it subtracts from the row term delta = rowsum(dO * O), which one
+    XLA fusion computes once a row before the kernel.
+    GQA: the grid runs (kv head, query head of its group, q-block) with the
+    kv head's K/V and its float32 dK/dV resident in VMEM across the last
+    two, so a group's query heads add into the same dK/dV and no per-query-
+    head partial ever reaches HBM."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..telemetry import registry
 
+    registry.counter(
+        BWD_COUNTER, help="flash attention backward kernels traced (one a "
+        "call: dQ, dK and dV from a single pass over the block pairs)").inc()
     b, h, s, d = q.shape
     d_v = v.shape[-1]
     h_kv = k.shape[1]
-    kv_map = _kv_index_map(h, h_kv)
-    block_q = min(block_q or _auto_block(s), s)
-    block_k = min(block_k or _auto_block(s), s)
-    if s % block_q or s % block_k:
-        raise ValueError(f"flash attention bwd: seq {s} is not divisible "
-                         f"by blocks ({block_q}, {block_k})")
-    qf = q.reshape(b * h, s, d)
-    kf = k.reshape(b * h_kv, s, d)
-    vf = v.reshape(b * h_kv, s, d_v)
-    dof = g.reshape(b * h, s, d_v)
-    of = o.reshape(b * h, s, d_v)
-    have_glse = g_lse is not None
-    if have_glse:
-        # the masked-row lse can be +/-inf sentinels; 0*inf would NaN, so
-        # derive the vma-carrying zero from a finitized lse
-        glse_args = (jnp.broadcast_to(
-            g_lse.astype(jnp.float32).reshape(b * h, s, 1),
-            (b * h, s, _LSE_LANES))
-            + 0.0 * jnp.where(jnp.isfinite(lse), lse, 0.0),)
-    else:
-        glse_args = ()
+    group = h // h_kv
+    block_q, block_k = _blocks(s, block_q, block_k)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32)
 
-    def _with_optional_glse(kernel, n_lead):
-        """The hot no-glse path passes glse_ref=None statically — no
-        extra HBM stream for the common training backward."""
-        if have_glse:
-            return kernel
-        return functools.partial(
-            lambda *refs, k: k(*refs[:n_lead], None, *refs[n_lead:]),
-            k=kernel)
+    def q_map(bh_kv, gi, qi):
+        return (bh_kv * group + gi, qi, 0)
 
-    q_full = pl.BlockSpec((None, s, d), lambda bh, i: (bh, 0, 0))
-    o_full = pl.BlockSpec((None, s, d_v), lambda bh, i: (bh, 0, 0))
-    k_full = pl.BlockSpec((None, s, d), kv_map)
-    v_full = pl.BlockSpec((None, s, d_v), kv_map)
-    q_blk = pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0))
-    o_blk = pl.BlockSpec((None, block_q, d_v), lambda bh, qi: (bh, qi, 0))
-    lse_full = pl.BlockSpec((None, s, _LSE_LANES), lambda bh, i: (bh, 0, 0))
-    lse_blk = pl.BlockSpec((None, block_q, _LSE_LANES),
-                           lambda bh, qi: (bh, qi, 0))
+    def row_map(bh_kv, gi, qi):
+        return (bh_kv * group + gi, 0, qi)
 
-    dq_kernel = _with_optional_glse(
-        functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
-                          seq_len=s, causal=causal, scale=scale), 6)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b * h, s // block_q),
-        in_specs=[q_blk, k_full, v_full, o_blk, o_blk, lse_blk]
-        + ([lse_blk] if have_glse else []),
-        out_specs=q_blk,
-        out_shape=_sds((b * h, s, d), q.dtype, q),
-        interpret=interpret,
-        name="mx_flash_attention_bwd_dq",
-        **_vmem_params(s, max(d, d_v), 2, interpret, q.dtype.itemsize),
-    )(qf, kf, vf, dof, of, lse, *glse_args)
+    def kv_map(bh_kv, gi, qi):
+        return (bh_kv, 0, 0)
 
-    if h == h_kv:
-        def kv_blk_map(bh, ki):
-            return (bh, ki, 0)
-    else:
-        group = h // h_kv
-
-        def kv_blk_map(bh, ki):
-            return ((bh // h) * h_kv + (bh % h) // group, ki, 0)
-    k_blk = pl.BlockSpec((None, block_k, d), kv_blk_map)
-    v_blk = pl.BlockSpec((None, block_k, d_v), kv_blk_map)
-    dkv_kernel = _with_optional_glse(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                          seq_len=s, causal=causal, scale=scale), 6)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b * h, s // block_k),
-        in_specs=[q_full, k_blk, v_blk, o_full, o_full, lse_full]
-        + ([lse_full] if have_glse else []),
+    row_stat = pl.BlockSpec((None, 1, block_q), row_map)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_k=block_k, seq_len=s,
+                          causal=causal, scale=scale),
+        grid=(b * h_kv, group, s // block_q),
+        in_specs=[
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, s, d), kv_map),
+            pl.BlockSpec((None, s, d_v), kv_map),
+            pl.BlockSpec((None, block_q, d_v), q_map),
+            row_stat, row_stat,
+        ],
         out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, d_v), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, s, d), kv_map),
+            pl.BlockSpec((None, s, d_v), kv_map),
         ],
         out_shape=[
-            _sds((b * h, s, d), k.dtype, q),
-            _sds((b * h, s, d_v), v.dtype, q),
+            _sds((b * h, s, d), q.dtype, q),
+            _sds((b * h_kv, s, d), k.dtype, q),
+            _sds((b * h_kv, s, d_v), v.dtype, q),
         ],
+        scratch_shapes=[pltpu.VMEM((d, s), jnp.float32),
+                        pltpu.VMEM((d_v, s), jnp.float32)],
         interpret=interpret,
-        name="mx_flash_attention_bwd_dkv",
-        **_vmem_params(s, max(d, d_v), 3, interpret, q.dtype.itemsize),
-    )(qf, kf, vf, dof, of, lse, *glse_args)
-
-    dq = dq.reshape(b, h, s, d)
-    dk = dk.reshape(b, h, s, d)
-    dv = dv.reshape(b, h, s, d_v)
-    if h != h_kv:
-        group = h // h_kv
-        dk = dk.reshape(b, h_kv, group, s, d).sum(2).astype(k.dtype)
-        dv = dv.reshape(b, h_kv, group, s, d_v).sum(2).astype(v.dtype)
-    return dq, dk, dv
+        name="mx_flash_attention_bwd",
+        **_vmem_params(s, max(d, d_v), 4, interpret, q.dtype.itemsize,
+                       n_f32_streams=2),
+    )(q.reshape(b * h, s, d), k.reshape(b * h_kv, s, d),
+      v.reshape(b * h_kv, s, d_v), g.reshape(b * h, s, d_v),
+      lse, delta.reshape(b * h, 1, s))
+    return (dq.reshape(b, h, s, d), dk.reshape(b, h_kv, s, d),
+            dv.reshape(b, h_kv, s, d_v))
 
 
 def _pallas_eligible(q, k, platform=None, block_q=None, block_k=None,
@@ -501,8 +491,9 @@ def _pallas_eligible(q, k, platform=None, block_q=None, block_k=None,
     # attention: 192 and 128); each a multiple of 64
     if d % 64 != 0 or (v is not None and v.shape[3] % 64 != 0):
         return False
-    if s % min(block_q or _auto_block(s), s) != 0 or \
-            s % min(block_k or _auto_block(s), s) != 0:
+    try:
+        _blocks(s, block_q, block_k)
+    except ValueError:
         return False
     if s < 8:
         return False
@@ -518,7 +509,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              force=None, platform=None):
     """(out, lse) variant of flash_attention for blockwise/ring
     combiners. BOTH outputs are differentiable: the Pallas backward
-    folds the lse cotangent into its row term (glse in the kernels)."""
+    folds the lse cotangent into its row term (`delta`, before the kernel)."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     use_pallas = (force in ("pallas", "interpret") or
@@ -532,7 +523,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     def fn(q, k, v):
         out, lse = _flash_pallas(q, k, v, causal, scale,
                                  interpret=interpret)
-        return out, lse[:, :, 0].reshape(b, h, s)
+        return out, lse.reshape(b, h, s)
 
     def fwd(q, k, v):
         out, lse = _flash_pallas_kept(q, k, v, causal, scale,
@@ -542,8 +533,8 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     def bwd(res, cotangents):
         g_o, g_lse = cotangents
         q, k, v, out, lse = res
-        return _flash_pallas_bwd(q, k, v, out, _lse_lanes(lse), g_o, causal,
-                                 scale, interpret=interpret, g_lse=g_lse)
+        return _flash_pallas_bwd(q, k, v, out, lse, g_o, causal, scale,
+                                 interpret=interpret, g_lse=g_lse)
 
     fn.defvjp(fwd, bwd)
     return fn(q, k, v)
@@ -556,7 +547,8 @@ def _flash_pallas_trainable(q, k, v, causal, scale, interpret=False,
     backward re-materializes softmax blocks from them in VMEM. Activation
     memory is O(B*H*S*D + B*H*S), never O(S^2) — the long-context
     training path. The two are declared as kept (`_flash_pallas_kept`): a
-    rematerialised stage around this call runs the forward kernel once."""
+    rematerialised stage around this call runs the forward kernel once,
+    and its backward is one kernel (`_flash_pallas_bwd`)."""
 
     @jax.custom_vjp
     def fn(q, k, v):
@@ -572,8 +564,8 @@ def _flash_pallas_trainable(q, k, v, causal, scale, interpret=False,
 
     def bwd(res, g):
         q, k, v, out, lse = res
-        return _flash_pallas_bwd(q, k, v, out, _lse_lanes(lse), g, causal,
-                                 scale, interpret=interpret, block_q=block_q,
+        return _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale,
+                                 interpret=interpret, block_q=block_q,
                                  block_k=block_k)
 
     fn.defvjp(fwd, bwd)
@@ -591,9 +583,9 @@ def flash_attention(q, k, v, causal=False, scale=None, force=None,
     cpu-targeted program just because the DEFAULT backend is a TPU.
 
     GQA/MQA: k/v may carry fewer heads than q (H % H_kv == 0) — the
-    kernels stream the SHARED kv blocks (no repeated copy; dK/dV group
-    partials reduce outside the kernel). block_q/block_k override the
-    default 128 tiling.
+    kernels stream the SHARED kv blocks (no repeated copy; a group's query
+    heads add into one dK/dV inside the backward kernel). block_q/block_k
+    override `_auto_block`'s tiling.
     """
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)

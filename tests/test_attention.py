@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops import attention as at
+from test_kimi_linear import _close
 
 
 def _qkv(b=2, h=2, s=256, d=128, seed=0):
@@ -183,6 +184,78 @@ def test_flash_block_size_override_matches():
                                    err_msg=f"bq={bq} bk={bk}")
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [(2, 2), (4, 1), (4, 2)],
+                         ids=["ungrouped", "4_over_1", "4_over_2"])
+@pytest.mark.parametrize("with_glse", [False, True], ids=["out", "out_lse"])
+@pytest.mark.parametrize("blocks", [(128, 256), (256, 128)],
+                         ids=["128x256", "256x128"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_fused_backward_at_unequal_widths(causal, blocks, with_glse, heads,
+                                          dtype):
+    """The one backward kernel against the dense VJP at latent attention's
+    192/128 and S = 512 with block_q != block_k, so that a q-block meets
+    k-blocks wholly below the diagonal (no mask), straddling it (masked)
+    and above it (skipped); with and without a cotangent of lse (it folds
+    into delta outside the kernel); ungrouped and with a group's query
+    heads adding into one resident dK/dV. float32 at `highest` to the
+    float32 cases' tolerances; bf16 operands against the float32 oracle on
+    the same values to the bf16 kernels' 2e-2 of the largest entry."""
+    h, h_kv = heads
+    s, d, dv = 512, 192, 128
+    bq, bk = blocks
+    rng = np.random.RandomState(50 + h + h_kv)
+    q, k, v, g = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                  .astype(dtype) for shape in (
+        (1, h, s, d), (1, h_kv, s, d), (1, h_kv, s, dv), (1, h, s, dv)))
+    g_lse = jnp.asarray(rng.normal(size=(1, h, s)), jnp.float32) \
+        if with_glse else None
+    scale = d ** -0.5
+    with jax.default_matmul_precision("highest"):
+        out, lse = at._flash_pallas(q, k, v, causal, scale, interpret=True,
+                                    block_q=bq, block_k=bk)
+        assert lse.shape == (h, 1, s) and lse.dtype == jnp.float32
+        got = at._flash_pallas_bwd(q, k, v, out, lse, g, causal, scale,
+                                   interpret=True, g_lse=g_lse, block_q=bq,
+                                   block_k=bk)
+        exact = tuple(a.astype(jnp.float32) for a in (q, k, v))
+        (want_out, want_lse), vjp = jax.vjp(
+            lambda *a: at.reference_attention_with_lse(*a, causal, scale),
+            *exact)
+        want = vjp((g.astype(jnp.float32),
+                    jnp.zeros_like(want_lse) if g_lse is None else g_lse))
+    assert [(a.dtype, a.shape) for a in got] == [
+        (q.dtype, a.shape) for a in want]
+    if dtype == "float32":
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                                   rtol=2e-3, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(lse).reshape(1, h, s),
+                                   np.asarray(want_lse), rtol=1e-4, atol=1e-4)
+        for name, a, b in zip("qkv", got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=3e-4,
+                                       err_msg=f"d{name}")
+    else:
+        for a, b in zip((out,) + got, (want_out,) + want):
+            _close(a, b, 2e-2)
+
+
+def test_flash_backward_is_one_counted_kernel():
+    """A trace of the backward holds one `mx_flash_attention_bwd` kernel
+    and counts it in the telemetry registry, once a trace."""
+    from mxnet_tpu.telemetry import registry
+    q, k, v = _qkv(b=1, h=2, s=256, d=128, seed=7)
+    counter = registry.counter(at.BWD_COUNTER)
+    before = counter.value()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(at.flash_attention(
+        *a, causal=True, force="interpret")), argnums=(0, 1, 2)))(q, k, v)
+    names = [e.params["name"] for e in _stage_eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert sorted(names) == ["mx_flash_attention_bwd",
+                             "mx_flash_attention_fwd"]
+    assert counter.value() == before + 1
+
+
 def test_gqa_eligibility():
     import numpy as _np
     q = jnp.zeros((2, 8, 256, 128), jnp.bfloat16)
@@ -261,11 +334,17 @@ def test_mirror_stage_keeps_flash_residuals(with_lse):
 
     forwards, products = count(stage)
     assert forwards == 1
+    # lse stays a row of s along the lanes from the forward kernel to the
+    # backward kernel: no slice of, and no broadcast to, a lane-replicated
+    # (b*h, s, 8) array in the gradient's program
+    assert not [v.aval.shape for e in _stage_eqns(jax.make_jaxpr(
+        gradient(executor._rematerialised(stage)))(x, w_in, w_out).jaxpr)
+        for v in e.outvars if v.aval.shape[-2:] == (s, 8)]
     # a bare checkpoint runs the whole stage again, the kernel with it
     assert count(jax.checkpoint(stage)) == (2, products + 2)
     before = kept()
     assert count(executor._rematerialised(stage)) == (1, products + 2)
-    # out (b, h, s, dv) and one lane of lse (b*h, s), float32
+    # out (b, h, s, dv) and lse (b*h, 1, s) as the kernel wrote it, float32
     assert tuple(np.subtract(kept(), before)) == (
         2, 4 * b * h * s * dv + 4 * b * h * s)
 

@@ -68,9 +68,9 @@ def test_flash_attention_forward_and_gradient(one_chip, b, h, h_kv, s):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     assert _compiled_text(fwd, q, kv, kv).count(KERNEL) == 1
-    # dq, and dk+dv, each from their own recompute kernel beside the forward
+    # dq, dk and dv from ONE recompute kernel beside the forward
     assert _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
-                          q, kv, kv).count(KERNEL) == 3
+                          q, kv, kv).count(KERNEL) == 2
 
 
 def test_flash_attention_unequal_head_widths(one_chip):
@@ -87,7 +87,7 @@ def test_flash_attention_unequal_head_widths(one_chip):
                                platform="tpu").astype(jnp.float32).sum()
 
     assert _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
-                          qk, qk, v).count(KERNEL) == 3
+                          qk, qk, v).count(KERNEL) == 2
 
 
 @pytest.mark.parametrize("chunk", [64, 128])
@@ -115,11 +115,11 @@ def test_kda_kernels_forward_and_gradient(one_chip, chunk):
 def test_flash_attention_grouped_heads_of_64(one_chip):
     """Grouped-query attention at LFM2-8B-A1B's published widths: 32 query
     heads over 8 k/v heads of 64, two sequences of 8,192. A minor dim of 64
-    is tiled out to 128 lanes in VMEM: the dK/dV kernel's three full
-    streams took 20.75M of the default 16M of scoped VMEM until
-    `_vmem_params` counted that (PERF.md, PR 32). k and v enter at their
-    own 8 heads: nothing of 32 heads' size but q, the output and their
-    gradients exists."""
+    is tiled out to 128 lanes in VMEM, which `_vmem_params` counts
+    (PERF.md, PR 32). k and v enter at their own 8 heads and dK and dV
+    leave at them (a group's query heads add into one resident block):
+    nothing of 32 heads' size but q, the output and their gradients
+    exists."""
     from mxnet_tpu.ops.attention import flash_attention
     q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16,
                              sharding=one_chip)
@@ -135,8 +135,11 @@ def test_flash_attention_grouped_heads_of_64(one_chip):
 
     assert _compiled_text(fwd, q, kv, kv).count(KERNEL) == 1
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count(KERNEL) == 3
+    assert text.count(KERNEL) == 2
     assert "bf16[16,8192,64]" in text and "repeat" not in text
+    # no per-query-head dK / dV partial to sum over a group afterwards
+    assert "f32[2,8,4,8192,64]" not in text and \
+        "bf16[2,8,4,8192,64]" not in text
 
 
 def _kda_operands(one_chip, b=2, s=8192, h=32, d=128):
@@ -363,9 +366,10 @@ def test_mirror_stage_keeps_flash_residuals(one_chip):
     (2 sequences of 8,192, 32 heads of 192 / 128, bf16) under the
     executor's rematerialisation: the flash kernels declare out and lse as
     kept, so the loss-and-gradient program runs the forward kernel ONCE
-    (twice under a bare checkpoint) and pays for it with out and one lane
-    of lse, 0.136 GB. Kept as the kernel writes it, lane-replicated to a
-    minor dim of 8, lse alone would be 0.27 GB: tiled out to 128 lanes."""
+    (twice under a bare checkpoint) and pays for it with out and lse,
+    0.136 GB: the forward writes lse as rows along the lanes,
+    (B*H, 1, S), 4 bytes a row in HBM, and the ONE backward kernel reads
+    them so."""
     from mxnet_tpu import executor
     from mxnet_tpu.ops.attention import flash_attention
     b, h, s, d, dv, hidden = 2, 32, 8192, 192, 128, 2048
@@ -391,12 +395,11 @@ def test_mirror_stage_keeps_flash_residuals(one_chip):
         calls = [line for line in program.as_text().split("\n")
                  if "custom-call(" in line and KERNEL in line]
         return [sum(name in line for line in calls) for name in (
-            "mx_flash_attention_fwd", "mx_flash_attention_bwd_dq",
-            "mx_flash_attention_bwd_dkv")]
+            "mx_flash_attention_fwd", "mx_flash_attention_bwd")]
 
     bare = compiled(jax.checkpoint(stage))
     kept = compiled(executor._rematerialised(stage))
-    assert kernels(bare) == [2, 1, 1]
-    assert kernels(kept) == [1, 1, 1]
+    assert kernels(bare) == [2, 1]
+    assert kernels(kept) == [1, 1]
     assert kept.memory_analysis().temp_size_in_bytes <= \
         bare.memory_analysis().temp_size_in_bytes + 0.16e9

@@ -446,10 +446,9 @@ def test_autograd_function_on_chip():
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_backward_parity_on_chip(causal):
-    """Compiled Pallas flash backward (dq/dk/dv from the recompute
-    kernels, ops/attention.py:_flash_pallas_bwd) vs the dense-XLA vjp on
-    the real chip — multi-block so lse streaming and both causal skips
-    run."""
+    """Compiled Pallas flash backward (dq/dk/dv from the one recompute
+    kernel, ops/attention.py:_flash_pallas_bwd) vs the dense-XLA vjp on
+    the real chip."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention as at
@@ -571,6 +570,53 @@ def test_flash_gqa_parity_on_chip(h_kv):
         assert_almost_equal(np.asarray(a), np.asarray(b), rtol=2e-2,
                             atol=2e-3, names=(f"gqa_d{name}",
                                               f"dense_d{name}"))
+
+
+@pytest.mark.parametrize("h,h_kv,d,dv", [(4, 4, 192, 128), (8, 2, 64, 64)],
+                         ids=["4_heads_192_128", "8_over_2_heads_64"])
+def test_flash_kernels_at_the_cells_shapes_on_chip(h, h_kv, d, dv):
+    """The forward and the one backward kernel at the shapes the language-
+    model cells run (8,192 tokens, bf16, causal, the default blocks of 512:
+    16 q-blocks meeting unmasked, straddling and skipped k-blocks; heads of
+    192 / 128, and a group of four query heads adding into one resident
+    dK / dV at heads of 64), against the dense oracle in float32 at
+    HIGHEST on the same bf16 values: within 2e-2 of the largest entry."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as at
+
+    s = 8192
+    ks = jax.random.split(jax.random.PRNGKey(33), 4)
+    q, k, v, g = (jax.random.normal(key, shape, jnp.float32)
+                  .astype(jnp.bfloat16) for key, shape in zip(ks, (
+                      (1, h, s, d), (1, h_kv, s, d), (1, h_kv, s, dv),
+                      (1, h, s, dv))))
+    out, vjp = jax.vjp(lambda *a: at.flash_attention(
+        *a, causal=True, scale=d ** -0.5, force="pallas"), q, k, v)
+    got = [np.asarray(a, np.float32) for a in (out,) + vjp(g)]
+    del out, vjp
+    # the oracle one k/v head and its group at a time: four heads' dense
+    # (S, S) float32 scores and their cotangents are what the chip holds
+    group = h // h_kv
+    want = [[], [], [], []]
+    for j in range(h_kv):
+        heads = slice(j * group, (j + 1) * group)
+        with jax.default_matmul_precision("highest"):
+            want_out, vjp = jax.vjp(
+                lambda *a: at.reference_attention(*a, causal=True,
+                                                  scale=d ** -0.5),
+                *(a.astype(jnp.float32) for a in (
+                    q[:, heads], k[:, j:j + 1], v[:, j:j + 1])))
+            parts = (want_out,) + vjp(g[:, heads].astype(jnp.float32))
+        for acc, part in zip(want, parts):
+            acc.append(np.asarray(part, np.float32))
+        del want_out, vjp, parts
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        b = np.concatenate(b, axis=1)
+        assert a.shape == b.shape
+        err = np.max(np.abs(a - b)) / np.max(np.abs(b))
+        print(f"flash {h}/{h_kv} x {d}/{dv} {name}: {err:.3e}")
+        assert err <= 2e-2, (name, err)
 
 
 def test_step_k_parity_on_chip():
