@@ -14,6 +14,9 @@ Conventional usage mirrors MXNet:
 """
 from __future__ import annotations
 
+import time as _time
+_T_IMPORT = _time.perf_counter()    # first: `import.mxnet_tpu` starts here
+
 __version__ = "0.1.0"
 
 from .base import MXNetError, AttrScope, NameManager
@@ -62,3 +65,5 @@ from . import serving
 from . import pipeline
 from . import checkpoint
 from . import test_utils
+from .telemetry import tracing as _tracing
+_tracing.record_startup(_T_IMPORT)  # last: `import.mxnet_tpu` ends here

@@ -50,7 +50,7 @@ import threading
 import time
 
 from .. import config
-from . import flightrec
+from . import flightrec, tracing
 from .registry import counter as _counter, gauge as _gauge
 
 __all__ = [
@@ -480,8 +480,11 @@ def _run_extraction(name, fn, sds, steps, kind, do_preflight=False):
     with _LOCK:
         if _SIGS.get(name) == sig and not do_preflight:
             return
-    compiled = fn.lower(*sds).compile()
-    stats = record_program(name, compiled=compiled, kind=kind)
+    # the span tells this second trace, lowering and compile-or-load of
+    # the program (its `compile.*` children) from the jit call's own
+    with tracing.span("devstats.extract", program=name):
+        compiled = fn.lower(*sds).compile()
+        stats = record_program(name, compiled=compiled, kind=kind)
     with _LOCK:
         _SIGS[name] = sig
     if steps:

@@ -1,8 +1,10 @@
 """Crash flight recorder — the always-on black box.
 
 A bounded ring of the most recent spans/events (`record()` is a dict
-build + deque append, ~µs, no I/O, no device syncs) that is dumped to a
-postmortem JSON file when something dies:
+build + deque append, ~µs, no I/O, no device syncs), beside a head of the
+process's first `HEAD_EVENTS` records that is never evicted (a start-up
+timeline outlives a long run), dumped to a postmortem JSON file when
+something dies:
 
   - `dist.DistRankFailure` (dist._fail calls `dump()` on its exit ramp),
   - a watchdog stall/deadline dump (`watchdog.dump_now` appends
@@ -46,7 +48,9 @@ _lock = threading.Lock()
 _dump_lock = threading.RLock()   # re-entrant: SIGTERM may land mid-dump
 _PERIODIC = "periodic-flush"     # the flusher's reason
 _crash_boxes = set()             # paths a crash trigger has written
-_ring = None          # deque, created lazily at first record
+HEAD_EVENTS = 2048    # the process's first records, kept for good
+_head = []            # filled first; then records go to the ring
+_ring = None          # deque (the tail), created lazily at first record
 _total = 0            # appended since reset
 _installed = {
     "excepthook": None,     # prev sys.excepthook when chained
@@ -92,16 +96,20 @@ def record(kind, name, dur_us=None, **fields):
     with _lock:
         if _ring is None:
             _ring = deque(maxlen=_capacity())
-        _ring.append(ev)
+        if len(_head) < HEAD_EVENTS:
+            _head.append(ev)
+        else:
+            _ring.append(ev)
         _total += 1
 
 
 def snapshot(last_s=None):
-    """Copy of the buffered events, optionally only the last `last_s`
+    """Copy of the buffered events, head then tail (oldest first; what
+    the tail dropped lay between them), optionally only the last `last_s`
     seconds (relative to the newest event, not the wall clock — a long
     stall should not empty the tail)."""
     with _lock:
-        evs = list(_ring) if _ring is not None else []
+        evs = _head + list(_ring) if _ring is not None else []
     if last_s is not None and evs:
         cutoff = evs[-1]["t"] - float(last_s)
         evs = [e for e in evs if e["t"] >= cutoff]
@@ -109,17 +117,22 @@ def snapshot(last_s=None):
 
 
 def stats():
+    """`events` buffered = `head` (never evicted) + `tail` (the ring, of
+    `capacity`); `dropped` were evicted from between the two."""
     with _lock:
-        n = len(_ring) if _ring is not None else 0
+        head = len(_head)
+        tail = len(_ring) if _ring is not None else 0
         cap = _ring.maxlen if _ring is not None else _capacity()
-        return {"events": n, "total": _total,
-                "dropped": max(0, _total - n), "capacity": cap}
+        return {"events": head + tail, "head": head, "tail": tail,
+                "total": _total, "dropped": max(0, _total - head - tail),
+                "capacity": cap}
 
 
 def reset():
     """Drop all buffered events (tests)."""
     global _ring, _total
     with _lock:
+        del _head[:]
         _ring = None
         _total = 0
 

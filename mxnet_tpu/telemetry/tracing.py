@@ -20,7 +20,19 @@ lifecycle (queue -> batch -> compute). Four sinks per span:
   - per-phase registry histograms (`mxnet_trace_<phase>_seconds`) plus
     the phase accumulators StepLogger samples for its per-step
     feed/compute/comm/ckpt breakdown and measured overlap fractions;
-  - the flight recorder ring (always-on black box, see flightrec.py).
+  - the flight recorder ring (always-on black box, see flightrec.py): a
+    record carries the span's start `t0_us` (on `time.perf_counter`), its
+    `id` and its `parent` (the id of the span open beneath it on the same
+    thread, else None), so the ring's spans can be laid on one timeline
+    and a span's self time computed.
+
+JAX's compile pipeline shows up as spans too: `_CompileListener` (one a
+process, registered with `jax.monitoring` when this module is first
+imported) turns the outermost trace, lowering and backend-compile event
+of a thread into a retrospective `compile.trace`, `compile.lower` or
+`compile.backend` span with `fun=<name>` under whatever span that thread
+has open, so "which dispatch recompiled, which function, did the cache
+answer" is read off the ring.
 
 Discipline: monotonic clocks only (`time.perf_counter`), zero device
 syncs, per-thread span stacks (threading.local), and `MXNET_TRACE=0`
@@ -46,19 +58,21 @@ per step, and which rank went quiet first.
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import os
 import re
 import threading
 import time
 
+import jax
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from . import flightrec
 from .. import profiler
 
 __all__ = ["enabled", "active", "span", "stopwatch", "traced", "event",
-           "set_step", "current_stack", "phase_totals",
+           "record_startup", "set_step", "current_stack", "phase_totals",
            "reset_phase_totals",
            "dump", "shard_path", "merge", "format_summary",
            "arm_autodump", "disarm_autodump", "exchange_clock",
@@ -70,9 +84,11 @@ __all__ = ["enabled", "active", "span", "stopwatch", "traced", "event",
 # value. _phase_us/_phase_n aggregation is held to _phase_lock. _tls is
 # threading.local — every attribute write lands in per-thread storage
 # by construction, so no cross-thread interleaving exists to guard.
-__analysis_thread_safe__ = {"_step_ctx", "_clock", "_autodump", "_tls"}
+__analysis_thread_safe__ = {"_step_ctx", "_clock", "_autodump", "_tls",
+                            "_startup"}
 
 _tls = threading.local()
+_ids = itertools.count(1)      # span ids; next() is atomic under the GIL
 
 _phase_lock = threading.Lock()
 _phase_us = {}                 # phase -> accumulated span µs
@@ -82,6 +98,7 @@ _histograms = {}               # phase -> registry Histogram (get-or-create)
 _step_ctx = {"trace_id": None, "step": None}
 _clock = {"skew_us": 0.0, "exchanged": False}
 _autodump = {"armed": False, "path": None, "stop": None}
+_startup = {"recorded": False}
 
 # span durations: µs-scale queue hops through multi-second ckpt commits
 SPAN_BUCKETS = (0.00001, 0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05,
@@ -124,7 +141,7 @@ def _phase_hist(phase):
     return h
 
 
-def _emit(name, phase, t0_perf, dur_us, args, error=None):
+def _emit(name, phase, t0_perf, dur_us, args, ident, parent, error=None):
     """Common span-close path for _Span.__exit__ and event()."""
     if enabled():
         ev_args = dict(args) if args else {}
@@ -146,6 +163,7 @@ def _emit(name, phase, t0_perf, dur_us, args, error=None):
                 pass
     if flightrec.enabled():
         flightrec.record("span", name, dur_us=dur_us,
+                         t0_us=int(t0_perf * 1e6), id=ident, parent=parent,
                          **({"err": error} if error else {}),
                          **(args or {}))
 
@@ -153,8 +171,10 @@ def _emit(name, phase, t0_perf, dur_us, args, error=None):
 class _Span:
     """A timed span. `dur_us` is set when it closes. A span opened inside
     another of the same thread takes over the parent's `seq` (the number
-    of the block of work both belong to) unless it names its own."""
-    __slots__ = ("name", "phase", "args", "dur_us", "_t0", "_ann")
+    of the block of work both belong to) unless it names its own, and
+    records the parent's `id` as its `parent`."""
+    __slots__ = ("name", "phase", "args", "dur_us", "id", "parent", "_t0",
+                 "_ann")
 
     def __init__(self, name, phase, args):
         self.name = name
@@ -165,7 +185,9 @@ class _Span:
         st = getattr(_tls, "stack", None)
         if st is None:
             st = _tls.stack = []
+        self.id, self.parent = next(_ids), None
         if st:
+            self.parent = st[-1].id
             parent = st[-1].args
             if parent and "seq" in parent and \
                     "seq" not in (self.args or ()):
@@ -181,6 +203,7 @@ class _Span:
         self._ann.__exit__(exc_type, exc, tb)
         _tls.stack.pop()
         _emit(self.name, self.phase, self._t0, self.dur_us, self.args,
+              self.id, self.parent,
               error=exc_type.__name__ if exc_type is not None else None)
         return False
 
@@ -246,11 +269,129 @@ def traced(name=None, phase=None):
 def event(name, t0_perf, t1_perf=None, phase=None, **args):
     """Record a retrospective span from timestamps the caller already
     holds (serving's queue time: t_submit was captured at submit, the
-    span is known only at dequeue)."""
+    span is known only at dequeue). Its parent is the innermost span this
+    thread has open now."""
     if not active():
         return
     t1 = t1_perf if t1_perf is not None else time.perf_counter()
-    _emit(name, phase, t0_perf, max(0.0, (t1 - t0_perf) * 1e6), args or None)
+    st = getattr(_tls, "stack", None)
+    _emit(name, phase, t0_perf, max(0.0, (t1 - t0_perf) * 1e6), args or None,
+          next(_ids), st[-1].id if st else None)
+
+
+class _CompileListener:
+    """JAX's compile-pipeline events as retrospective spans on the thread
+    that compiled: a span ends when its duration event fires and starts
+    `duration` earlier. JAX announces the start of each timed stage with a
+    scalar event of the same name, so a depth per thread tells the
+    OUTERMOST stage: the trace event fires for every nested `jit` (each
+    `jnp` helper) inside the outer trace, and again for the helpers a
+    lowering traces (a `scan`'s `less` and `add`), and only a stage that
+    no other encloses becomes a span: three a compiled program. A program
+    compiled and run eagerly from inside a trace stays part of that trace.
+    The persistent cache's events seen on the thread since its last
+    `compile.backend` say whether that one was a "hit" (with the cache's
+    `retrieval_s`), a "miss" (a placed cache was asked) or the cache was "off".
+    A callback that raised would fail the compile, so none does."""
+
+    SPANS = {"/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                 "compile.lower",
+             "/jax/core/compile/backend_compile_duration": "compile.backend"}
+    REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+    CACHE = {"/jax/compilation_cache/cache_hits": "hit", REQUEST: "miss",
+             "/jax/compilation_cache/cache_misses": "miss"}
+    RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+    def __init__(self):
+        self._tls = threading.local()
+
+    def _state(self):
+        """This thread's `depth` (stages open) and `cache` (what the
+        persistent cache said since the last backend compile)."""
+        tls = self._tls
+        if not hasattr(tls, "depth"):
+            tls.depth, tls.cache = 0, {}
+        return tls
+
+    def on_start(self, key, value, **kw):
+        if key in self.SPANS:
+            self._state().depth += 1
+
+    def on_event(self, key, **kw):
+        answer = self.CACHE.get(key)
+        # the request event fires with no cache placed too
+        if answer is None or (key == self.REQUEST and
+                              not jax.config.jax_compilation_cache_dir):
+            return
+        cache = self._state().cache
+        if cache.get("cache") != "hit":
+            cache["cache"] = answer
+
+    def on_duration(self, key, duration, **kw):
+        try:
+            tls = self._state()
+            name = self.SPANS.get(key)
+            if name is None:
+                if key == self.RETRIEVAL:
+                    tls.cache["retrieval_s"] = float(duration)
+                return
+            tls.depth = max(0, tls.depth - 1)
+            if tls.depth:
+                return
+            args = {"fun": str(kw.get("fun_name", ""))}
+            if name == "compile.backend":
+                args.update({"cache": "off"}, **tls.cache)
+                tls.cache = {}
+            now = time.perf_counter()
+            event(name, now - float(duration), now, **args)
+        except Exception:                # pragma: no cover
+            pass
+
+
+def _listen_to_compiles():
+    """Register the process's one listener. `jax.monitoring` keeps its
+    callbacks for the life of the process, so the listener is kept on
+    that module: a reload of this one, or a second import of the package,
+    finds it there and registers nothing."""
+    from jax import monitoring
+    if getattr(monitoring, "_mxnet_tpu_compile_listener", None) is not None:
+        return
+    listener = monitoring._mxnet_tpu_compile_listener = _CompileListener()
+    monitoring.register_scalar_listener(listener.on_start)
+    monitoring.register_event_listener(listener.on_event)
+    monitoring.register_event_duration_secs_listener(listener.on_duration)
+
+
+def _process_start_perf():
+    """When the operating system started this process, on the clock of
+    `time.perf_counter`: its age by `/proc/self/stat` (field 22, ticks
+    since boot) against CLOCK_BOOTTIME, taken off now. None where the
+    platform has no such record."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            after_comm = f.read().rsplit(")", 1)[1].split()
+        born = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - born
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter() - age if age >= 0 else None
+
+
+def record_startup(t_import):
+    """The two spans of the time before any of the program's own: called
+    on the last line of `mxnet_tpu/__init__.py` with the clock read on its
+    first. `import.mxnet_tpu` is the import itself; `process.start` runs
+    from the process's start to the import's first line (the interpreter
+    and whatever the caller imported and started first: `jax`, its
+    backend). Once a process: a reload of the package records nothing."""
+    if _startup["recorded"]:
+        return
+    _startup["recorded"] = True
+    born = _process_start_perf()
+    if born is not None and born <= t_import:
+        event("process.start", born, t_import)
+    event("import.mxnet_tpu", t_import)
 
 
 def current_stack():
@@ -822,6 +963,9 @@ def main(argv=None):
         return 0
     p.print_help()
     return 2
+
+
+_listen_to_compiles()
 
 
 if __name__ == "__main__":              # pragma: no cover

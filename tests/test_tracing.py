@@ -509,11 +509,14 @@ def test_fused_fit_bit_identical_and_span_sites_counted(monkeypatch):
     spans = [e for e in flightrec.snapshot() if e["kind"] == "span"]
     per_block = {}
     for e in spans:
-        if e["name"] not in SETUP_SPANS:
+        # dispatch 0 holds set-up's spans (the compile pipeline's,
+        # devstats' extraction): every dispatch after it is counted
+        if e["name"] not in SETUP_SPANS + ("devstats.extract",) and \
+                not e["name"].startswith("compile."):
             per_block[e["seq"]] = per_block.get(e["seq"], 0) + 1
     assert set(range(FUSED_DISPATCHES)) <= set(per_block)
     assert all(5 <= n < 16 for s, n in per_block.items()
-               if s < FUSED_DISPATCHES), per_block
+               if 0 < s < FUSED_DISPATCHES), per_block
 
 
 def test_setup_spans_and_first_dispatch_in_flightrec(monkeypatch):
@@ -556,6 +559,271 @@ def test_stopwatch_times_with_every_sink_off(monkeypatch):
     (inner, outer) = flightrec.snapshot()
     assert outer["dur_us"] == int(sw.dur_us)
     assert inner["seq"] == 3                  # taken over from the parent
+
+
+# -- a record that can be put on a timeline, and the compile pipeline ---------
+# (ISSUE 34)
+
+def _ring_spans():
+    return [e for e in flightrec.snapshot() if e["kind"] == "span"]
+
+
+def test_ring_record_carries_start_id_and_parent(monkeypatch):
+    """Every span record holds `t0_us` (its start on perf_counter), `id`
+    and `parent`; a child's parent is the enclosing span's id, whether the
+    child is a span, a stopwatch or a retrospective event."""
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    before = time.perf_counter()
+    with tracing.span("outer", seq=5) as outer:
+        with tracing.span("a.span") as a:
+            with tracing.stopwatch("a.stopwatch") as b:
+                t_ev = time.perf_counter()
+                time.sleep(0.001)
+                tracing.event("an.event", t_ev, note="x")
+        tracing.event("late.event", before)
+    tracing.event("orphan.event", before)
+    after = time.perf_counter()
+    recs = {e["name"]: e for e in _ring_spans()}
+    assert set(recs) == {"outer", "a.span", "a.stopwatch", "an.event",
+                         "late.event", "orphan.event"}
+    for e in recs.values():
+        assert isinstance(e["t0_us"], int) and isinstance(e["id"], int)
+        assert before * 1e6 - 1 <= e["t0_us"] <= after * 1e6
+        assert e["t0_us"] + e["dur_us"] <= after * 1e6 + 1
+    assert len({e["id"] for e in recs.values()}) == len(recs)
+    assert recs["outer"]["parent"] is None
+    assert recs["outer"]["id"] == outer.id
+    assert recs["a.span"]["parent"] == outer.id
+    assert recs["a.stopwatch"]["parent"] == a.id
+    assert recs["an.event"]["parent"] == b.id
+    assert recs["an.event"]["t0_us"] == int(t_ev * 1e6)
+    assert recs["an.event"]["dur_us"] >= 1000
+    # an event's start may lie before its parent's: the parent is the span
+    # that was open when the event was recorded
+    assert recs["late.event"]["parent"] == outer.id
+    assert recs["late.event"]["t0_us"] < recs["outer"]["t0_us"]
+    assert recs["orphan.event"]["parent"] is None
+    # a child lies inside its parent on the timeline
+    child, parent = recs["a.span"], recs["outer"]
+    assert parent["t0_us"] <= child["t0_us"]
+    assert child["t0_us"] + child["dur_us"] <= \
+        parent["t0_us"] + parent["dur_us"] + 1
+
+
+def _compiles(under=None):
+    return [(e["name"], e["fun"]) for e in _ring_spans()
+            if e["name"].startswith("compile.")
+            and (under is None or e["parent"] == under)]
+
+
+def test_compile_pipeline_spans_once_a_program(monkeypatch):
+    """A fresh jit function called under span("x") leaves exactly one
+    compile.trace, compile.lower and compile.backend naming it under x; the
+    jit nested inside it, the helpers its lowering traces and a second call
+    add none."""
+    import jax
+    import jax.numpy as jnp
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    x = jnp.ones((4,))          # whatever building it compiles: before x
+    x.block_until_ready()
+
+    @jax.jit
+    def nested_helper(v):
+        return jnp.sin(v) + 1.0
+
+    def fresh_program(v):
+        # a scan: its lowering traces helpers of its own
+        def body(c, row):
+            return c + nested_helper(row).sum(), c
+        return jax.lax.scan(body, 0.0, jnp.stack([v, v]))[0]
+
+    fn = jax.jit(fresh_program)
+    flightrec.reset()
+    with tracing.span("x") as sp:
+        fn(x).block_until_ready()
+    assert _compiles(under=sp.id) == [
+        ("compile.trace", "fresh_program"),
+        ("compile.lower", "jit(fresh_program)"),
+        ("compile.backend", "jit(fresh_program)")]
+    assert _compiles() == _compiles(under=sp.id)
+    (backend,) = [e for e in _ring_spans() if e["name"] == "compile.backend"]
+    assert backend["cache"] in ("off", "miss", "hit")
+    x_rec = [e for e in _ring_spans() if e["name"] == "x"][0]
+    for e in _ring_spans():
+        assert x_rec["t0_us"] <= e["t0_us"] and e["t0_us"] + e["dur_us"] \
+            <= x_rec["t0_us"] + x_rec["dur_us"] + 1000, e
+    flightrec.reset()
+    with tracing.span("x"):
+        fn(x).block_until_ready()
+    assert _compiles() == []
+
+
+def test_compile_backend_span_says_whether_the_cache_answered(
+        monkeypatch, tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_compilation_cache_max_size")
+    saved = {n: getattr(jax.config, n) for n in names}
+    x = jnp.arange(6.0)
+    x.block_until_ready()
+
+    def cached_program(v):
+        return jnp.cos(v) * 3.0 - v
+
+    def backend_spans():
+        return [e for e in _ring_spans() if e["name"] == "compile.backend"
+                and e["fun"] == "jit(cached_program)"]
+    try:
+        flightrec.reset()
+        jax.jit(cached_program)(x).block_until_ready()
+        (off,) = backend_spans()
+        if not saved["jax_compilation_cache_dir"]:
+            assert off["cache"] == "off" and "retrieval_s" not in off
+        mx.config.enable_compile_cache(str(tmp_path / "cache"))
+        states = []
+        for _ in range(2):
+            jax.clear_caches()
+            flightrec.reset()
+            jax.jit(cached_program)(x).block_until_ready()
+            (rec,) = backend_spans()
+            states.append(rec)
+        assert [r["cache"] for r in states] == ["miss", "hit"]
+        assert "retrieval_s" not in states[0]
+        assert 0 <= states[1]["retrieval_s"] <= states[1]["dur_us"] / 1e6
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
+
+
+def test_fit_tells_extraction_from_the_jit_calls_own_compile(monkeypatch):
+    """A fused fit: devstats' extraction runs under `devstats.extract`, the
+    jit call's own trace, lowering and compile under `step.enqueue` of
+    dispatch 0; no later dispatch has a compile.* descendant."""
+    from mxnet_tpu.telemetry import devstats
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    devstats.reset()
+    _fused_fit()
+    assert devstats.drain()
+    spans = _ring_spans()
+    by_id = {e["id"]: e for e in spans}
+
+    def chain(e):
+        out = []
+        while e["parent"] is not None:
+            e = by_id[e["parent"]]
+            out.append(e)
+        return out
+
+    step = [e for e in spans if e["name"].startswith("compile.")
+            and e["fun"] in ("multi", "jit(multi)")]
+    under = {}
+    for e in step:
+        under.setdefault(chain(e)[0]["name"], []).append(e["name"])
+    assert set(under) == {"devstats.extract", "step.enqueue"}, under
+    for names in under.values():
+        assert sorted(names) == ["compile.backend", "compile.lower",
+                                 "compile.trace"]
+    (extract,) = [e for e in spans if e["name"] == "devstats.extract"]
+    assert extract["program"] == "dp.step_k%d" % K
+    for e in spans:
+        if e["name"].startswith("compile."):
+            dispatches = [a for a in chain(e)
+                          if a["name"] == "step.fused_dispatch"]
+            assert all(a["seq"] == 0 for a in dispatches), (e, dispatches)
+    # the jit call's own compile is dispatch 0's
+    enqueue = [a for e in step for a in chain(e)
+               if a["name"] == "step.enqueue"]
+    assert enqueue and all(a["seq"] == 0 for a in enqueue)
+
+
+def test_head_keeps_the_first_records(monkeypatch):
+    """The process's first 2,048 records outlive any number of later ones:
+    snapshot() is head + tail, stats() counts both."""
+    monkeypatch.setenv("MXNET_FLIGHTREC", "1")
+    assert flightrec.HEAD_EVENTS == 2048
+    n = flightrec.HEAD_EVENTS + 6000
+    for i in range(n):
+        flightrec.record("event", "beat", i=i)
+    st = flightrec.stats()
+    assert st["head"] == 2048 and st["tail"] == st["capacity"] == 4096
+    assert st["events"] == 2048 + 4096 and st["total"] == n
+    assert st["dropped"] == n - st["events"]
+    got = [e["i"] for e in flightrec.snapshot()]
+    assert got == list(range(2048)) + list(range(n - 4096, n))
+    assert "beat" in flightrec.tail_text(n=3)
+    flightrec.reset()
+    assert flightrec.stats()["events"] == 0 and flightrec.snapshot() == []
+
+
+def test_compile_listener_is_registered_once():
+    import importlib
+    from jax._src import monitoring
+
+    def ours(callbacks):
+        return [cb for cb in callbacks if type(getattr(
+            cb, "__self__", None)).__name__ == "_CompileListener"]
+
+    importlib.reload(tracing)
+    importlib.reload(mx.telemetry)
+    tracing._listen_to_compiles()
+    assert len(ours(monitoring.get_event_duration_listeners())) == 1
+    assert len(ours(monitoring.get_event_listeners())) == 1
+    assert len(ours(monitoring.get_scalar_listeners())) == 1
+
+
+def test_startup_spans_recorded_once(monkeypatch):
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    monkeypatch.setitem(tracing._startup, "recorded", False)
+    t_import = time.perf_counter()
+    tracing.record_startup(t_import)
+    tracing.record_startup(t_import)         # a reload of the package
+    recs = _ring_spans()
+    assert [e["name"] for e in recs] == ["process.start", "import.mxnet_tpu"]
+    start, imp = recs
+    assert start["parent"] is None and imp["parent"] is None
+    # the process is older than this test, and process.start ends where
+    # the import begins
+    assert start["dur_us"] > 0
+    assert abs(start["t0_us"] + start["dur_us"] - imp["t0_us"]) <= 1
+    assert imp["t0_us"] == int(t_import * 1e6)
+
+
+def test_import_records_the_time_before_the_programs_spans():
+    """In a fresh process: `process.start` from the operating system's
+    record of the process's start to the import's first line, then
+    `import.mxnet_tpu`, which is the ring's last record once the import
+    has returned."""
+    import subprocess
+    code = ("import time, json; t0 = time.perf_counter(); "
+            "import mxnet_tpu; t1 = time.perf_counter(); "
+            "from mxnet_tpu.telemetry import flightrec; "
+            "print(json.dumps({'t0': t0, 't1': t1, "
+            "'ring': flightrec.snapshot()}))")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_FLIGHTREC="1",
+               PYTHONPATH=REPO)
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    ring = [e for e in got["ring"] if e["kind"] == "span"]
+    assert ring[-1]["name"] == "import.mxnet_tpu"
+    imp = ring[-1]
+    assert got["t0"] * 1e6 <= imp["t0_us"]
+    assert imp["t0_us"] + imp["dur_us"] <= got["t1"] * 1e6 + 1
+    # the import took nearly all of the time the caller measured around it
+    assert imp["dur_us"] >= 0.9 * (got["t1"] - got["t0"]) * 1e6
+    (start,) = [e for e in ring if e["name"] == "process.start"]
+    assert start["t0_us"] < got["t0"] * 1e6
+    assert abs(start["t0_us"] + start["dur_us"] - imp["t0_us"]) <= 1
+    # the interpreter's start-up, not minutes
+    assert 0 < start["dur_us"] < 60e6
 
 
 def test_serve_infer_children_cover_infer(monkeypatch, tmp_path):
@@ -604,11 +872,13 @@ def test_serve_infer_children_cover_infer(monkeypatch, tmp_path):
 def test_flightrec_ring_dump_and_tail(monkeypatch, tmp_path):
     monkeypatch.setenv("MXNET_FLIGHTREC", "1")
     monkeypatch.setenv("MXNET_FLIGHTREC_EVENTS", "32")
+    monkeypatch.setattr(flightrec, "HEAD_EVENTS", 0)    # the tail alone
     for i in range(50):
         flightrec.record("event", f"beat{i}", step=i)
     st = flightrec.stats()
     assert st["events"] == 32 and st["total"] == 50
     assert st["dropped"] == 18 and st["capacity"] == 32
+    assert st["head"] == 0 and st["tail"] == 32
     p = flightrec.dump(path=str(tmp_path / "fr.json"), reason="test")
     box = json.loads(open(p, encoding="utf-8").read())
     assert box["reason"] == "test" and box["rank"] == 0
