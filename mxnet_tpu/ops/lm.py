@@ -563,9 +563,46 @@ def moe_route(x, router_weight, router_bias, top_k, scaling, renormalize):
     return idx.astype(jnp.int32), w * scaling
 
 
-def _swiglu_experts(x, w_gate, w_up, w_down, group_sizes):
+MOE_KERNEL_COUNTER = "moe_grouped_kernel_calls_total"
+MOE_FALLBACK_COUNTER = "moe_grouped_xla_fallback_total"
+
+
+def _swiglu_experts(x, w_gate, w_up, w_down, group_sizes, force=None,
+                    platform=None):
     """Grouped SwiGLU: rows of x (M, D) sorted by expert, weights
-    (E, D, W) / (E, W, D)."""
+    (E, D, W) / (E, W, D), float32 (M, D).
+
+    Which path, from what the trace can see: a program for a TPU whose
+    widths and rows the tiles divide (`moe_pallas.eligible`), in bf16 or
+    float32, takes the Pallas kernels `mx_moe_gmm` / `mx_moe_tgmm`, whose
+    cost follows the pairs that arrived and which write the rows past the
+    last pair as ZEROS; everything else (a CPU program, shapes the kernels
+    refuse) takes `jax.lax.ragged_dot`, which leaves those rows unwritten
+    (on the TPU: whatever the memory held). Callers select them away either
+    way. force: None (auto) | 'pallas' | 'xla' | 'interpret', as `kda`'s;
+    `platform` is the platform the program is compiled for. Both paths are
+    counted once a trace: a kernel call, and a bf16 program for a TPU whose
+    shapes the kernels refuse (logged with the shapes)."""
+    from . import moe_pallas
+    m, d = x.shape
+    held, _, width = w_gate.shape
+    one_type = x.dtype == w_gate.dtype == w_up.dtype == w_down.dtype
+    if force in ("pallas", "interpret") or (
+            force is None and one_type and moe_pallas.eligible(
+                x.dtype, d, width, m, held, platform)):
+        if force is None:
+            _count(MOE_KERNEL_COUNTER, "grouped SwiGLUs of held experts "
+                   "traced for a TPU that went through the Pallas kernels")
+        return moe_pallas.swiglu_experts(x, w_gate, w_up, w_down, group_sizes,
+                                         interpret=force == "interpret")
+    if force is None and x.dtype == jnp.bfloat16 and \
+            (platform or jax.default_backend()) == "tpu":
+        import logging
+        _count(MOE_FALLBACK_COUNTER, "bf16 grouped SwiGLUs of held experts "
+               "traced for a TPU whose shapes the Pallas kernels do not take")
+        logging.getLogger(__name__).warning(
+            "moe_experts: rows %s weights %s not eligible for the TPU "
+            "kernels; jax.lax.ragged_dot", x.shape, w_gate.shape)
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
                             preferred_element_type=_F32)
     hidden = jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)
@@ -622,7 +659,8 @@ MOE_CAPACITY = 4
 
 
 def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
-                first_expert, top_k, scaling, renormalize):
+                first_expert, top_k, scaling, renormalize, force=None,
+                platform=None):
     """y (T, D) = sum over the chosen experts held here of weight *
     expert(x), and stats (E_held + 3,) int32: tokens per held expert,
     token-expert pairs on held experts, pairs computed, dense fall-backs.
@@ -631,7 +669,10 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
     `capacity` rows (MOE_CAPACITY times the balanced share) and go
     through grouped products; should more pairs than that arrive, the
     layer computes every held expert on every token instead: no pair is
-    ever dropped."""
+    ever dropped. The grouped products are `_swiglu_experts`'s: Pallas
+    kernels in a program for a TPU (the rows past the last pair come back
+    as zeros), `jax.lax.ragged_dot` in every other (they come back
+    unwritten); `force` and `platform` are passed on to it."""
     t, d = x.shape
     n_held = w_gate.shape[0]
     n_all = router_weight.shape[0]
@@ -649,7 +690,7 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
                        -(-balanced * MOE_CAPACITY // 128) * 128)
         order = jnp.argsort(key, stable=True)[:capacity]
         token = order // top_k
-        # rows past the held pairs belong to no group: a grouped product
+        # rows past the held pairs belong to no group: `ragged_dot`
         # leaves them unwritten (on the TPU: whatever the memory held), so
         # they are SELECTED away on both sides of it, never multiplied away
         valid = (jnp.arange(capacity) < n_pairs)[:, None]
@@ -658,7 +699,8 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
     def grouped(_):
         rows = jnp.where(valid, jnp.take(x, token, axis=0), 0)
         with jax.named_scope("mx.moe.experts.matmul"):
-            out = _swiglu_experts(rows, w_gate, w_up, w_down, load)
+            out = _swiglu_experts(rows, w_gate, w_up, w_down, load,
+                                  force=force, platform=platform)
         # selected BEFORE the product with the pair's weight: the weight's
         # gradient is a sum over out, and 0 x whatever the memory held is
         # NaN wherever that is no number
@@ -686,7 +728,7 @@ def _moe_op(attrs, octx, data, router_weight, router_bias, w_gate, w_up,
         data.reshape(-1, shape[-1]), router_weight, router_bias,
         w_gate, w_up, w_down, first_expert=attrs["first_expert"],
         top_k=attrs["top_k"], scaling=attrs["scaling"],
-        renormalize=attrs["renormalize"])
+        renormalize=attrs["renormalize"], platform=octx.platform)
     return _t(y.reshape(shape), stats)
 
 

@@ -221,8 +221,8 @@ def test_rows_of_no_group_hold_anything(monkeypatch):
                     jnp.float32)
     grouped = lm._swiglu_experts
 
-    def unwritten(rows, w_gate, w_up, w_down, group_sizes):
-        out = grouped(rows, w_gate, w_up, w_down, group_sizes)
+    def unwritten(rows, w_gate, w_up, w_down, group_sizes, **path):
+        out = grouped(rows, w_gate, w_up, w_down, group_sizes, **path)
         in_a_group = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
         return jnp.where(in_a_group[:, None], out, jnp.nan)
 

@@ -344,7 +344,7 @@ def test_mla_block_matches_reference():
            want, 2e-6)
 
 
-def _moe_system(cfg, p, x):
+def _moe_system(cfg, p, x, way=None):
     first, n = cfg["experts_held"]
     t = jnp.asarray(x).reshape(-1, x.shape[-1])
     y, stats = lm.moe_experts(
@@ -352,18 +352,21 @@ def _moe_system(cfg, p, x):
         jnp.swapaxes(p["e_up"], 1, 2), jnp.swapaxes(p["e_down"], 1, 2),
         first_expert=first, top_k=cfg["num_experts_per_token"],
         scaling=cfg["routed_scaling_factor"],
-        renormalize=cfg["moe_renormalize"])
+        renormalize=cfg["moe_renormalize"], force=way)
     return y.reshape(x.shape), np.asarray(stats)
 
 
+@pytest.mark.parametrize("way", ["xla", "interpret"])
 @pytest.mark.parametrize("crowd", [0.0, 10.0],
                          ids=["grouped", "dense_fallback"])
-def test_moe_routed_part_matches_reference(crowd):
+def test_moe_routed_part_matches_reference(crowd, way):
     """The pairs on held experts through grouped products, and through
     the dense path that takes over when they exceed the capacity (here:
     the router's selection bias sends every token to both held experts,
     twice the rows the grouped products have): the same result, nothing
-    dropped either way."""
+    dropped either way; with the grouped products as `jax.lax.ragged_dot`
+    and as the Pallas kernels under the interpreter, the fork around
+    them the same."""
     cfg = dict(CFG, num_experts=32, experts_held=[0, 2])
     params = ref.init_params(cfg, 6)
     p = _layer(params, 1)
@@ -371,7 +374,7 @@ def test_moe_routed_part_matches_reference(crowd):
     bias[:2] += crowd
     p["r_bias"] = jnp.asarray(bias, jnp.float32)
     x = np.random.default_rng(3).standard_normal((2, 64, 32)).astype("f4")
-    y, stats = _moe_system(cfg, p, x)
+    y, stats = _moe_system(cfg, p, x, way)
     _close(y, ref.moe_mlp(cfg, p, jnp.asarray(x), shared=False), 2e-5)
     load, n_pairs, computed, fell_back = (stats[:2], stats[2], stats[3],
                                           stats[4])
@@ -380,7 +383,7 @@ def test_moe_routed_part_matches_reference(crowd):
     if crowd:
         assert n_pairs == 2 * 2 * 64
     fn = lambda x: jnp.sum(jnp.sin(ref.moe_mlp(cfg, p, x, shared=False)))
-    fs = lambda x: jnp.sum(jnp.sin(_moe_system(cfg, p, x)[0]))
+    fs = lambda x: jnp.sum(jnp.sin(_moe_system(cfg, p, x, way)[0]))
     _close(jax.grad(fs)(jnp.asarray(x)), jax.grad(fn)(jnp.asarray(x)), 5e-5)
 
 

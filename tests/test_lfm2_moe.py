@@ -341,7 +341,7 @@ def _reference_grads():
 
 
 @functools.lru_cache(maxsize=None)
-def _system_grads(mirror):
+def _system_grads(mirror, way):
     params = ref.init_params(CFG, 3)
     tokens, labels = _batch(1)
     return _grads_through_executor(
@@ -351,16 +351,23 @@ def _system_grads(mirror):
 PARAMETERS = sorted(PREFIX + name for name in ref.param_shapes(CFG))
 
 
+@pytest.mark.parametrize("way", ["xla", "interpret"])
 @pytest.mark.parametrize("mirror", [False, True],
                          ids=["saved", "mirror_stages"])
 @pytest.mark.parametrize("name", PARAMETERS)
-def test_symbol_gradient_matches_reference(name, mirror, monkeypatch):
+def test_symbol_gradient_matches_reference(name, mirror, way, monkeypatch):
     """The graph the trainers run: each parameter's gradient against
     jax.grad of the reference; with MXNET_BACKWARD_DO_MIRROR each layer is
-    rematerialised as one stage and nothing changes."""
+    rematerialised as one stage and nothing changes; with the mixture
+    layers' grouped products as `jax.lax.ragged_dot` (a CPU program's path)
+    and as the Pallas kernels under the interpreter (steered here, in the
+    test: the op has no option for it)."""
     if mirror:
         monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
-    loss, grads = _system_grads(mirror)
+    grouped = lm._swiglu_experts
+    monkeypatch.setattr(lm, "_swiglu_experts", lambda *a, **path: grouped(
+        *a, **dict(path, force=way)))
+    loss, grads = _system_grads(mirror, way)
     want_loss, want = _reference_grads()
     _close(loss.mean(), want_loss, 1e-5)
     want = ref.system_params({k: np.asarray(v) for k, v in want.items()},

@@ -112,6 +112,42 @@ def test_kda_kernels_forward_and_gradient(one_chip, chunk):
                           qkv, qkv, qkv, g, beta).count(KERNEL) == 2
 
 
+@pytest.mark.parametrize("cell,rows,d,w,held,dtype", [
+    ("lfm2_moe.train", 65536, 2048, 1792, 8, "bfloat16"),
+    ("kanana2.train", 49152, 2048, 768, 16, "bfloat16"),
+    ("kimi_linear.train", 16384, 2304, 1024, 8, "bfloat16"),
+    # check (b)'s program: one sequence, amp off, `highest`
+    ("lfm2_moe.train", 32768, 2048, 1792, 8, "float32"),
+])
+def test_moe_grouped_kernels_forward_and_gradient(one_chip, cell, rows, d, w,
+                                                  held, dtype):
+    """The held experts' grouped SwiGLU at the three cells' shapes through
+    the auto pick: two `mx_moe_gmm` forward; the forward that keeps the two
+    products, hidden's and x's gradients and two `mx_moe_tgmm` backward;
+    no `ragged-dot` left."""
+    from mxnet_tpu.ops import lm
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    specs = (sds((rows, d)), sds((held, d, w)), sds((held, d, w)),
+             sds((held, w, d)), sds((held,), jnp.int32))
+
+    def fwd(*a):            # the auto pick, told its target is a TPU
+        return lm._swiglu_experts(*a, platform="tpu")
+
+    def loss(*a):
+        return fwd(*a).sum()
+
+    with jax.default_matmul_precision(
+            "highest" if dtype == "float32" else "default"):
+        forward = _compiled_text(fwd, *specs)
+        both = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+                              *specs)
+    assert forward.count(KERNEL) == 2 and both.count(KERNEL) == 6
+    assert "ragged-dot" not in forward + both
+
+
 def test_flash_attention_grouped_heads_of_64(one_chip):
     """Grouped-query attention at LFM2-8B-A1B's published widths: 32 query
     heads over 8 k/v heads of 64, two sequences of 8,192. A minor dim of 64
