@@ -5,20 +5,29 @@ whose mixer and MLP kinds are read per layer from the published keys of a
 model's config.json (ROADMAP R0). A config with `layer_types` names each
 layer's mixer there (0-based, as that list counts): `"conv"` is the gated
 short convolution, `"full_attention"` grouped-query attention with per-head
-q/k norms and a rotation of the whole head. Otherwise
+q/k norms and a rotation of the whole head, `"sliding_attention"` the same
+over a window of `sliding_window` keys (`head_dim` where the config gives
+it, else hidden / heads). Otherwise
 `linear_attn_config.kda_layers` / `full_attn_layers` (1-based, as
 config.json counts them) pick Kimi Delta Attention or multi-head latent
 attention, and a config without `linear_attn_config` is latent attention in
 every layer. `num_dense_layers` or `first_k_dense_replace` picks the dense
 SwiGLU or the mixture of experts. Latent attention rotates its
 `qk_rope_head_dim` dims (`rope_theta`, `rope_interleave`) unless the config
-says `mla_use_nope`. The mixture's keys are read in any of three spellings
+says `mla_use_nope`. The mixture's keys are read in any of four spellings
 (`MIXTURE_KEYS`), the norms' epsilon in two (`rms_norm_eps`, `norm_eps`).
 `tie_word_embeddings` makes the head the embedding matrix: one parameter
-with two uses, whose gradient is the sum of both. Users: Kimi-Linear-48B-A3B
-(arXiv:2510.26692), `deepseek_v3` configs (Kanana-2-30B-A3B) and `lfm2_moe`
-configs (LFM2-8B-A1B). What a config asks for and no layer here computes
-raises NotImplementedError with the key's name. `TransformerEncoder`
+with two uses, whose gradient is the sum of both. `mup_enabled` scales the
+embedding's output by sqrt(hidden). What a family's modeling code does and
+its config.json does not say is in `FAMILIES`, by `model_type`: the
+`afmoe` family rotates q and k in its windowed layers only, gates the
+attention's output with a sigmoid of a product of the layer's input, and
+puts an RMSNorm on each sub-layer's output before its residual add
+(sandwich norms, `post_norm1` / `post_norm2`). Users: Kimi-Linear-48B-A3B
+(arXiv:2510.26692), `deepseek_v3` configs (Kanana-2-30B-A3B), `lfm2_moe`
+configs (LFM2-8B-A1B) and `afmoe` configs (Trinity-Mini). What a config
+asks for and no layer here computes raises NotImplementedError with the
+key's name. `TransformerEncoder`
 (transformer.py) is the older, hard-wired block and stays as it is.
 
 A chip's share of a deployment: `experts_held = (first, n)` makes every
@@ -29,10 +38,13 @@ Parameters are named `<prefix>l<i>_<name>`; matrices are (out, in), the
 experts (E, in, out) as `ops/lm.py` multiplies them (down: in is the
 expert's width). Weights default to normal(0, 0.02), norm scales to 1,
 `A_log`, `dt_bias` and the router's bias to 0. Under a Symbol every block
-tags its nodes with `profiler_scope` (the executor turns it into a `jax.named_scope`, so the
-profiler's operation metadata names the mechanism, forward and backward)
-and each layer with a `mirror_stage` of its own, the unit
-`MXNET_BACKWARD_DO_MIRROR` rematerialises.
+tags its nodes with `profiler_scope` (the executor turns it into a
+`jax.named_scope`, so the profiler's operation metadata names the
+mechanism, forward and backward) and each layer with a `mirror_stage` of
+its own, the unit `MXNET_BACKWARD_DO_MIRROR` rematerialises. Grouped-query
+attention is `mx.gqa` (with `mx.gqa.rope` and `mx.gqa.gate` under it), or
+`mx.swa` (`mx.swa.rope`, `mx.swa.gate`) in a windowed layer, whose flash
+kernels run under `mx.flash_attention.window`.
 """
 from __future__ import annotations
 
@@ -66,19 +78,21 @@ def _scope(name):
     return AttrScope(profiler_scope=name)
 
 
-# the mixture's settings: (Kimi-Linear's key, DeepSeek-V3's key); LFM2's
-# third spelling takes a key from either (`num_experts`,
-# `num_experts_per_tok`, `norm_topk_prob`), has none for shared experts and
-# one of its own for the selection bias
+# the mixture's settings: (Kimi-Linear's key, DeepSeek-V3's key, and the
+# `afmoe` family's where it has one of its own: `score_func`, `route_norm`,
+# `route_scale`, `num_expert_groups`); LFM2's third spelling takes a key from
+# the first two (`num_experts`, `num_experts_per_tok`, `norm_topk_prob`),
+# has none for shared experts and one of its own for the selection bias
 MIXTURE_KEYS = {
     "num_experts": ("num_experts", "n_routed_experts"),
     "top_k": ("num_experts_per_token", "num_experts_per_tok"),
     "num_shared": ("num_shared_experts", "n_shared_experts"),
-    "renormalize": ("moe_renormalize", "norm_topk_prob"),
-    "scoring": ("moe_router_activation_func", "scoring_func"),
-    "groups": ("num_expert_group", "n_group"),
-    "method": ("topk_method", "topk_method"),
-    "bias": ("use_expert_bias", "use_expert_bias"),
+    "renormalize": ("moe_renormalize", "norm_topk_prob", "route_norm"),
+    "scaling": ("routed_scaling_factor", "route_scale"),
+    "scoring": ("moe_router_activation_func", "scoring_func", "score_func"),
+    "groups": ("num_expert_group", "n_group", "num_expert_groups"),
+    "method": ("topk_method",),
+    "bias": ("use_expert_bias",),
 }
 # what `ops/lm.py::moe_route` computes: sigmoid scores, one group, the top
 # k of score + bias (a buffer that takes no gradient)
@@ -89,24 +103,25 @@ _ROUTER_COMPUTES = {"scoring": "sigmoid", "groups": 1, "method": "noaux_tc",
 def mixture_settings(cfg):
     """{setting: value} of the mixture layers, from whichever spelling the
     config uses; a router other than the one computed here raises by the
-    key's name. A config silent on the router gets the one computed here;
-    one silent on its shared experts has none only in LFM2's spelling (the
-    family with `use_expert_bias` has no key for them), and a KeyError in
-    Kimi's or DeepSeek-V3's."""
+    key's name, in every spelling the config has. A config silent on the
+    router gets the one computed here; one silent on its shared experts has
+    none only in LFM2's spelling (the family with `use_expert_bias` has no
+    key for them), and a KeyError in the others."""
     silent = dict(_ROUTER_COMPUTES)
     if "use_expert_bias" in cfg:
         silent["num_shared"] = 0
     out = {}
     for name, keys in MIXTURE_KEYS.items():
-        key = next((k for k in keys if k in cfg), None)
-        if key is None and name not in silent:
-            raise KeyError(f"mixture of experts: the config has neither "
-                           f"{keys[0]!r} nor {keys[1]!r}")
-        out[name] = cfg[key] if key else silent[name]
-        if out[name] != _ROUTER_COMPUTES.get(name, out[name]):
-            raise NotImplementedError(
-                f"mixture of experts: {key} = {out[name]!r} (computed "
-                f"here: {_ROUTER_COMPUTES[name]!r})")
+        given = [k for k in keys if k in cfg]
+        if not given and name not in silent:
+            raise KeyError(f"mixture of experts: the config has none of "
+                           f"{keys!r}")
+        out[name] = cfg[given[0]] if given else silent[name]
+        for key in given:
+            if cfg[key] != _ROUTER_COMPUTES.get(name, cfg[key]):
+                raise NotImplementedError(
+                    f"mixture of experts: {key} = {cfg[key]!r} (computed "
+                    f"here: {_ROUTER_COMPUTES[name]!r})")
     return out
 
 
@@ -118,6 +133,19 @@ def _either(cfg, *keys):
 
 def _eps(cfg):
     return _either(cfg, "rms_norm_eps", "norm_eps")
+
+
+# what a family's modeling code does that its config.json does not say:
+# which layer kinds rotate q and k, a sigmoid gate on the attention's
+# output, an RMSNorm on each sub-layer's output before its residual add
+FAMILIES = {"afmoe": {"rotate": ("sliding_attention",), "output_gate": True,
+                      "sandwich_norms": True}}
+_PLAIN = {"rotate": ("full_attention", "sliding_attention"),
+          "output_gate": False, "sandwich_norms": False}
+
+
+def family(cfg):
+    return FAMILIES.get(cfg.get("model_type"), _PLAIN)
 
 
 class KDAMixer(HybridBlock):
@@ -173,7 +201,10 @@ class MLAMixer(HybridBlock):
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
-        for key in ("q_lora_rank", "rope_scaling", "attention_bias"):
+        # a low-rank query, rope scaling, a bias, and a learned sparse
+        # indexer over the keys (`index_n_heads`, `index_topk`)
+        for key in ("q_lora_rank", "rope_scaling", "attention_bias",
+                    "index_n_heads", "index_topk"):
             if cfg.get(key):
                 raise NotImplementedError(f"MLAMixer: {key} = {cfg[key]!r}")
         self._rope = None if cfg.get("mla_use_nope") else dict(
@@ -255,57 +286,81 @@ class ShortConvMixer(HybridBlock):
 
 
 class GQAMixer(HybridBlock):
-    """Grouped-query attention as LFM2 has it: `num_attention_heads` query
-    heads over `num_key_value_heads` key/value heads of hidden / heads dims
-    (query head h attends to k/v head h // group), an RMSNorm with a learned
-    scale over each query and key head, then the rotation of the whole head
-    by position, halves paired (`_contrib_rope`, `interleave` false). k and
-    v reach `_contrib_flash_attention` at their own head count: a group's
-    shared head is never repeated."""
+    """Grouped-query attention: `num_attention_heads` query heads over
+    `num_key_value_heads` key/value heads of `head_dim` dims (hidden / heads
+    where the config has no `head_dim`; query head h attends to k/v head
+    h // group), an RMSNorm with a learned scale over each query and key
+    head, then the rotation of the whole head by position, halves paired
+    (`_contrib_rope`, `interleave` false) in the layer kinds the family
+    rotates (`family`). `kind` "sliding_attention" attends over a window of
+    `sliding_window` keys (the flash kernels skip the blocks outside it).
+    Where the family gates the output, o <- o * sigmoid(x W_g) before W_o.
+    k and v reach `_contrib_flash_attention` at their own head count: a
+    group's shared head is never repeated."""
 
-    def __init__(self, cfg, **kwargs):
+    def __init__(self, cfg, kind="full_attention", **kwargs):
         super().__init__(**kwargs)
-        for key in ("rope_scaling", "attention_bias", "sliding_window"):
+        for key in ("rope_scaling", "attention_bias"):
             if cfg.get(key):
                 raise NotImplementedError(f"GQAMixer: {key} = {cfg[key]!r}")
+        if cfg.get("sliding_window") and \
+                "sliding_attention" not in cfg.get("layer_types", ()):
+            raise NotImplementedError(
+                f"GQAMixer: sliding_window = {cfg['sliding_window']!r} in a "
+                "config whose layer_types has no sliding_attention")
         d = cfg["hidden_size"]
         self._h, self._hkv = (cfg["num_attention_heads"],
                               cfg["num_key_value_heads"])
-        if d % self._h or self._h % self._hkv:
+        if ("head_dim" not in cfg and d % self._h) or self._h % self._hkv:
             raise ValueError(
                 f"GQAMixer: hidden_size {d}, num_attention_heads {self._h}, "
                 f"num_key_value_heads {self._hkv} do not divide")
-        self._dh, self._d, self._eps = d // self._h, d, _eps(cfg)
-        self._theta = cfg["rope_theta"]
+        self._dh = cfg.get("head_dim") or d // self._h
+        self._d, self._eps = d, _eps(cfg)
+        self._window = cfg["sliding_window"] \
+            if kind == "sliding_attention" else None
+        self._where = "mx.swa" if self._window else "mx.gqa"
+        self._theta = cfg["rope_theta"] \
+            if kind in family(cfg)["rotate"] else None
         get = _getter(self)
-        self.wq = get("wq", shape=(self._h * self._dh, d))
+        c = self._h * self._dh
+        self.wq = get("wq", shape=(c, d))
         self.wk = get("wk", shape=(self._hkv * self._dh, d))
         self.wv = get("wv", shape=(self._hkv * self._dh, d))
         self.q_norm = get("q_norm", shape=(self._dh,), init="ones")
         self.k_norm = get("k_norm", shape=(self._dh,), init="ones")
-        self.wo = get("wo", shape=(d, self._h * self._dh))
+        if family(cfg)["output_gate"]:
+            self.wg = get("wg", shape=(c, d))
+        self.wo = get("wo", shape=(d, c))
 
-    def hybrid_forward(self, F, x, wq, wk, wv, q_norm, k_norm, wo):
+    def hybrid_forward(self, F, x, wq, wk, wv, q_norm, k_norm, wo, wg=None):
         h, hkv, dh = self._h, self._hkv, self._dh
 
         def heads(t, n):                # (B, S, n*dh) -> (B, n, S, dh)
             return F.transpose(F.reshape(t, shape=(0, 0, n, dh)),
                                axes=(0, 2, 1, 3))
 
-        with _scope("mx.gqa"):
+        with _scope(self._where):
             q = F.RMSNorm(heads(_dense(F, x, wq, h * dh), h), q_norm,
                           eps=self._eps)
             k = F.RMSNorm(heads(_dense(F, x, wk, hkv * dh), hkv), k_norm,
                           eps=self._eps)
             v = heads(_dense(F, x, wv, hkv * dh), hkv)
-            with _scope("mx.gqa.rope"):
-                q, k = (F._contrib_rope(t, rotary_dim=dh, offset=0,
-                                        theta=self._theta, interleave=False)
-                        for t in (q, k))
+            if self._theta is not None:
+                with _scope(self._where + ".rope"):
+                    q, k = (F._contrib_rope(t, rotary_dim=dh, offset=0,
+                                            theta=self._theta,
+                                            interleave=False)
+                            for t in (q, k))
+            window = {} if self._window is None else \
+                {"window": self._window}
             o = F._contrib_flash_attention(q, k, v, causal=True,
-                                           scale=dh ** -0.5)
+                                           scale=dh ** -0.5, **window)
             o = F.reshape(F.transpose(o, axes=(0, 2, 1, 3)),
                           shape=(0, 0, -1))
+            if wg is not None:
+                with _scope(self._where + ".gate"):
+                    o = o * F.sigmoid(_dense(F, x, wg, h * dh))
             return _dense(F, o, wo, self._d)
 
 
@@ -340,8 +395,7 @@ class MoEMLP(HybridBlock):
         moe = mixture_settings(cfg)
         self._attrs = dict(
             num_experts=moe["num_experts"], num_held=n, first_expert=first,
-            hidden_size=w, top_k=moe["top_k"],
-            scaling=cfg["routed_scaling_factor"],
+            hidden_size=w, top_k=moe["top_k"], scaling=moe["scaling"],
             renormalize=bool(moe["renormalize"]))
         get = _getter(self)
         self.w_r = get("w_r", shape=(moe["num_experts"], d))
@@ -368,18 +422,23 @@ class MoEMLP(HybridBlock):
 
 
 class DecoderBlock(HybridBlock):
-    """One pre-norm layer; `layer` is its published 1-based number."""
+    """One pre-norm layer; `layer` is its published 1-based number. Under
+    the family's sandwich norms each sub-layer's output is normalised
+    (`post_norm1`, `post_norm2`) before its residual add."""
 
     def __init__(self, cfg, layer, **kwargs):
         super().__init__(**kwargs)
         lin = cfg.get("linear_attn_config")
+        mixer_kwargs = {}
         if "layer_types" in cfg:
             kind = cfg["layer_types"][layer - 1]
-            mixer = {"conv": ShortConvMixer,
-                     "full_attention": GQAMixer}.get(kind)
+            mixer = {"conv": ShortConvMixer, "full_attention": GQAMixer,
+                     "sliding_attention": GQAMixer}.get(kind)
             if mixer is None:
                 raise NotImplementedError(
                     f"DecoderBlock: layer_types[{layer - 1}] = {kind!r}")
+            if mixer is GQAMixer:
+                mixer_kwargs["kind"] = kind
         elif lin is None or layer in lin["full_attn_layers"]:
             mixer = MLAMixer
         elif layer in lin["kda_layers"]:
@@ -394,21 +453,29 @@ class DecoderBlock(HybridBlock):
         get = _getter(self)
         self.norm1 = get("norm1", shape=(d,), init="ones")
         self.norm2 = get("norm2", shape=(d,), init="ones")
+        if family(cfg)["sandwich_norms"]:
+            self.post_norm1 = get("post_norm1", shape=(d,), init="ones")
+            self.post_norm2 = get("post_norm2", shape=(d,), init="ones")
         with self.name_scope():
-            self.mixer = mixer(cfg, prefix="")
+            self.mixer = mixer(cfg, prefix="", **mixer_kwargs)
             self.mlp = MoEMLP(cfg, prefix="") if self._moe else \
                 SwiGLU(d, cfg["intermediate_size"], prefix="")
 
-    def hybrid_forward(self, F, x, norm1, norm2):
+    def hybrid_forward(self, F, x, norm1, norm2, post_norm1=None,
+                       post_norm2=None):
+        def out(y, scale):              # a sub-layer's output, to be added
+            return y if scale is None else F.RMSNorm(y, scale, eps=self._eps)
+
         # one stage a layer: its backward recomputes it from the stream
         with AttrScope(mirror_stage=self.prefix + "layer"):
-            x = x + self.mixer(F.RMSNorm(x, norm1, eps=self._eps))
+            x = x + out(self.mixer(F.RMSNorm(x, norm1, eps=self._eps)),
+                        post_norm1)
             h = F.RMSNorm(x, norm2, eps=self._eps)
             if self._moe:
                 y, stats = self.mlp(h)
-                return x + y, stats
+                return x + out(y, post_norm2), stats
             with _scope("mx.mlp"):
-                return x + self.mlp(h), None
+                return x + out(self.mlp(h), post_norm2), None
 
 
 class DecoderLM(HybridBlock):
@@ -419,12 +486,17 @@ class DecoderLM(HybridBlock):
     uses'. `net(tokens)` gives the logits; `net(tokens, labels)` gives the
     (batch, sequence) cross-entropy of each position and, where there are
     mixture layers, their stacked stats (layers, experts held + 3) as a
-    second output."""
+    second output. Under `mup_enabled` the embedding's output is scaled by
+    sqrt(hidden) (its input alone: the head is not scaled)."""
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
+        if cfg.get("hidden_act", "silu") != "silu":
+            raise NotImplementedError(
+                f"DecoderLM: hidden_act = {cfg['hidden_act']!r}")
         d, v = cfg["hidden_size"], cfg["vocab_size"]
         self._d, self._v, self._eps = d, v, _eps(cfg)
+        self._embed_scale = d ** 0.5 if cfg.get("mup_enabled") else None
         get = _getter(self)
         self.embed = get("embed", shape=(v, d))
         self.norm_f = get("norm_f", shape=(d,), init="ones")
@@ -440,6 +512,8 @@ class DecoderLM(HybridBlock):
     def hybrid_forward(self, F, tokens, labels=None, embed=None,
                        norm_f=None, head=None):
         x = F.Embedding(tokens, embed, input_dim=self._v, output_dim=self._d)
+        if self._embed_scale is not None:
+            x = x * self._embed_scale
         if head is None:        # tied: the embedding matrix is the head
             head = embed
         stats = []

@@ -9,11 +9,20 @@ stream resident in VMEM, and ONE backward kernel that rebuilds each block
 pair's softmax once from the saved row statistic and feeds dQ, dK and dV
 from it. Forward and backward share the causal loop bounds
 (`_causal_bounds`: no mask below the diagonal, a mask on the blocks that
-straddle it, nothing above it), and the row statistics (lse, delta) travel
-along the lanes, (B*H, 1, S). Dispatch picks the kernels on TPU for
-tile-friendly shapes and falls back to a fused XLA implementation
-elsewhere (including the CPU test mesh). The sequence-parallel versions
-live in mxnet_tpu.parallel.sp.
+straddle it, nothing above it) and, for a sliding window of W keys
+(`window`: key j is seen from query i where i - W < j <= i), the window's
+(`_window_bounds`: the loops start at the first k-block that meets the
+window, a mask on the window's lower edge block and on the diagonal, the
+blocks between unmasked, nothing outside the window ever visited), and the
+row statistics (lse, delta) travel along the lanes, (B*H, 1, S). Dispatch
+picks the kernels on TPU for tile-friendly shapes and falls back to a
+fused XLA implementation elsewhere (including the CPU test mesh). The
+sequence-parallel versions live in mxnet_tpu.parallel.sp.
+
+The op `_contrib_flash_attention` runs under the scope `mx.flash_attention`;
+a call with a `window` shorter than the sequence runs under
+`mx.flash_attention.window` instead and is counted once a trace
+(`window_attention_calls_total`).
 """
 from __future__ import annotations
 
@@ -69,18 +78,21 @@ def _t(*o):
     return tuple(o)
 
 
-def reference_attention(q, k, v, causal=False, scale=None):
+def reference_attention(q, k, v, causal=False, scale=None, window=None):
     """Dense oracle. One implementation shared with the with-lse variant
     below — the score/mask/softmax math must not fork."""
-    return reference_attention_with_lse(q, k, v, causal, scale)[0]
+    return reference_attention_with_lse(q, k, v, causal, scale, window)[0]
 
 
-def reference_attention_with_lse(q, k, v, causal=False, scale=None):
+def reference_attention_with_lse(q, k, v, causal=False, scale=None,
+                                 window=None):
     """Dense oracle returning (out, lse (B,H,S) f32) — the merge
     statistic blockwise/ring combiners need. Rows with NO valid key get
     out=0 and lse=-inf (the logsumexp of an empty set), so such a block
     contributes exactly nothing to a logaddexp merge. GQA (k/v with
-    fewer heads) is handled by repeating kv across each query group."""
+    fewer heads) is handled by repeating kv across each query group.
+    `window` (causal only): a query sees the `window` keys up to and
+    including its own position."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     if k.shape[1] != q.shape[1]:
@@ -92,6 +104,9 @@ def reference_attention_with_lse(q, k, v, causal=False, scale=None):
     if causal:
         s_q, s_k = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((s_q, s_k), bool),
+                                    k=s_k - s_q - window)
         scores = jnp.where(mask, scores, -jnp.inf)
     m = jnp.max(scores, axis=-1)
     safe = jnp.where(jnp.isneginf(m), 0.0, m)
@@ -119,6 +134,24 @@ def _causal_bounds(q_start, block_q, block_k, n_blocks, causal):
                         (q_start + block_q + block_k - 1) // block_k))
 
 
+def _window_bounds(q_start, block_q, block_k, n_blocks, window):
+    """(first, n_edge, n_full, n_visited) of the k-blocks a q-block starting
+    at `q_start` meets under a sliding window of `window` keys, shared by
+    the forward and the backward: blocks [first, n_edge) hold the window's
+    lower edge for some row of the q-block and are masked; [n_edge,
+    n_full) lie inside the window and on or below the diagonal for every
+    row and need no mask; [n_full, n_visited) straddle the diagonal
+    (`_causal_bounds`) and are masked. Blocks before `first` lie wholly
+    outside the window and are never visited."""
+    n_full, n_visited = _causal_bounds(q_start, block_q, block_k, n_blocks,
+                                       True)
+    first = jnp.maximum(q_start - window + 1, 0) // block_k
+    # the first block whose first key the q-block's LAST row still sees
+    edge = (jnp.maximum(q_start + block_q - window, 0) + block_k - 1) \
+        // block_k
+    return first, jnp.clip(edge, first, n_full), n_full, n_visited
+
+
 def _k_rows(i, block_k, seq_len):
     """(first row, slice) of k-block `i` inside a kernel. A sequence of one
     block (the only case of a block under 128) is sliced statically: the
@@ -131,10 +164,11 @@ def _k_rows(i, block_k, seq_len):
     return start, pl.dslice(start, block_k)
 
 
-def _scores_t(k_blk, q, scale, k_start, q_start, masked):
+def _scores_t(k_blk, q, scale, k_start, q_start, masked, window=None):
     """One pair's scaled scores, TRANSPOSED: (Bk, Bq) float32 from operands
-    in their storage dtype, with the causal mask where the pair straddles
-    the diagonal. The forward's and the backward's alike: a backward that
+    in their storage dtype, with the causal mask (and the window's, under
+    a `window`) where the pair straddles the diagonal or the window's
+    edge. The forward's and the backward's alike: a backward that
     rebuilt p from other scores than the forward's would be a bug to
     chase. The scale is applied to the float32 scores (numerically at
     least as good as scaling q)."""
@@ -146,12 +180,38 @@ def _scores_t(k_blk, q, scale, k_start, q_start, masked):
             jnp.int32, (k_blk.shape[0], 1), 0)
         q_pos = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (1, q.shape[0]), 1)
-        s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen = seen & (q_pos - k_pos < window)
+        s = jnp.where(seen, s, -jnp.inf)
     return s
 
 
+def _pair_runs(q_start, block_q, block_k, n_blocks, causal, window):
+    """[(first, stop, masked)]: the runs of k-blocks a q-block meets, in
+    order, each of one kind; the forward's and the backward's alike, so
+    that both rebuild the same softmax."""
+    if window is None:
+        n_full, n_visited = _causal_bounds(q_start, block_q, block_k,
+                                           n_blocks, causal)
+        return [(0, n_full, False)] + \
+            ([(n_full, n_visited, True)] if causal else [])
+    first, n_edge, n_full, n_visited = _window_bounds(
+        q_start, block_q, block_k, n_blocks, window)
+    return [(first, n_edge, True), (n_edge, n_full, False),
+            (n_full, n_visited, True)]
+
+
+def _visit_pairs(pair, carry, runs):
+    """`pair(masked, i, carry)` over the k-blocks of `_pair_runs`."""
+    for first, stop, masked in runs:
+        carry = jax.lax.fori_loop(first, stop,
+                                  functools.partial(pair, masked), carry)
+    return carry
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_len,
-                  causal, scale):
+                  causal, scale, window=None):
     """One (bh, q-block) grid cell: stream K/V blocks with online softmax.
     Each score block is computed TRANSPOSED, (Bk, Bq): the running max and
     sum are ROWS along the lanes (a reduction over sublanes is elementwise
@@ -167,15 +227,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_len,
     q = q_ref[...]                                      # (Bq, D)
     block_q = q.shape[0]
     q_start = pl.program_id(1) * block_q
-    n_full, n_visited = _causal_bounds(q_start, block_q, block_k,
-                                       seq_len // block_k, causal)
+    runs = _pair_runs(q_start, block_q, block_k, seq_len // block_k, causal,
+                      window)
 
     def pair(masked, i, carry):
         acc, m, l = carry
         start, rows = _k_rows(i, block_k, seq_len)
         k_blk = k_ref[rows, :]
         v_blk = v_ref[rows, :]
-        s = _scores_t(k_blk, q, scale, start, q_start, masked)
+        s = _scores_t(k_blk, q, scale, start, q_start, masked, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         if masked:
             safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
@@ -194,11 +254,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_len,
     carry = (jnp.zeros((v_ref.shape[1], block_q), jnp.float32),
              jnp.full((1, block_q), -jnp.inf, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32))
-    carry = jax.lax.fori_loop(0, n_full, functools.partial(pair, False),
-                              carry)
-    if causal:
-        carry = jax.lax.fori_loop(n_full, n_visited,
-                                  functools.partial(pair, True), carry)
+    carry = _visit_pairs(pair, carry, runs)
     acc, m, l = carry
     l_safe = jnp.where(l == 0, 1.0, l)
     o_ref[...] = (acc / l_safe).T.astype(o_ref.dtype)
@@ -213,7 +269,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_len,
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc, *, block_k,
-                      seq_len, causal, scale):
+                      seq_len, causal, scale, window=None):
     """The whole backward for one (kv head, query head of its group,
     q-block) grid cell: every (q-block, k-block) pair's softmax is rebuilt
     ONCE from the saved lse (p = exp(s - lse); never an (S, S) tensor) and
@@ -248,8 +304,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     lse = lse_ref[...]                                  # (1, Bq)
     delta = delta_ref[...]                              # (1, Bq)
     q_start = qi * block_q
-    n_full, n_visited = _causal_bounds(q_start, block_q, block_k, n_blocks,
-                                       causal)
+    runs = _pair_runs(q_start, block_q, block_k, n_blocks, causal, window)
 
     def pair(masked, j, dq_t):
         # (the writes below are to Pallas refs: they are the kernel's
@@ -257,7 +312,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         start, rows = _k_rows(j, block_k, seq_len)
         k_blk = k_ref[rows, :]                          # (Bk, D)
         v_blk = v_ref[rows, :]                          # (Bk, Dv)
-        s = _scores_t(k_blk, q, scale, start, q_start, masked)
+        s = _scores_t(k_blk, q, scale, start, q_start, masked, window)
         p = jnp.exp(s - lse)                            # masked -> 0
         dp = jax.lax.dot_general(
             v_blk, do, (((1,), (1,)), ((), ())),
@@ -274,11 +329,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k_blk, ds, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (D, Bq)
 
-    dq_t = jax.lax.fori_loop(0, n_full, functools.partial(pair, False),
-                             jnp.zeros(q_t.shape, jnp.float32))
-    if causal:
-        dq_t = jax.lax.fori_loop(n_full, n_visited,
-                                 functools.partial(pair, True), dq_t)
+    dq_t = _visit_pairs(pair, jnp.zeros(q_t.shape, jnp.float32), runs)
     dq_ref[...] = (dq_t * scale).T.astype(dq_ref.dtype)
 
     @pl.when(jnp.logical_and(g == pl.num_programs(1) - 1,
@@ -346,7 +397,7 @@ def _kv_index_map(h, h_kv):
 
 
 def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
-                  block_k=None):
+                  block_k=None, window=None):
     """Forward kernel. q, k (B, H | H_kv, S, D) and v (B, H_kv, S, Dv)
     with H % H_kv == 0 (GQA/MQA share kv blocks in-kernel), S % block == 0
     and D, Dv as _pallas_eligible takes them (Dv may differ from D: latent
@@ -363,7 +414,7 @@ def _flash_pallas(q, k, v, causal, scale, interpret=False, block_q=None,
     vf = v.reshape(b * h_kv, s, d_v)
     kv_map = _kv_index_map(h, h_kv)
     kernel = functools.partial(_flash_kernel, block_k=block_k, seq_len=s,
-                               causal=causal, scale=scale)
+                               causal=causal, scale=scale, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, s // block_q),
@@ -403,7 +454,7 @@ BWD_COUNTER = "flash_bwd_kernel_calls_total"
 
 
 def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
-                      g_lse=None, block_q=None, block_k=None):
+                      g_lse=None, block_q=None, block_k=None, window=None):
     """Recompute-based flash backward, ONE kernel (`_flash_bwd_kernel`): a
     single pass over the block pairs re-derives the softmax from the saved
     lse (B*H, 1, S) — O(S) extra memory, never an (S, S) tensor — and
@@ -444,7 +495,7 @@ def _flash_pallas_bwd(q, k, v, o, lse, g, causal, scale, interpret=False,
     row_stat = pl.BlockSpec((None, 1, block_q), row_map)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, block_k=block_k, seq_len=s,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale, window=window),
         grid=(b * h_kv, group, s // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), q_map),
@@ -506,42 +557,45 @@ def _pallas_eligible(q, k, platform=None, block_q=None, block_k=None,
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
-                             force=None, platform=None):
+                             force=None, platform=None, window=None):
     """(out, lse) variant of flash_attention for blockwise/ring
     combiners. BOTH outputs are differentiable: the Pallas backward
-    folds the lse cotangent into its row term (`delta`, before the kernel)."""
+    folds the lse cotangent into its row term (`delta`, before the kernel).
+    `window` as flash_attention's."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    window = _window(window, causal, q.shape[2])
     use_pallas = (force in ("pallas", "interpret") or
                   (force is None and _pallas_eligible(q, k, platform)))
     if not use_pallas:
-        return reference_attention_with_lse(q, k, v, causal, scale)
+        return reference_attention_with_lse(q, k, v, causal, scale, window)
     interpret = force == "interpret"
     b, h, s, _ = q.shape
 
     @jax.custom_vjp
     def fn(q, k, v):
         out, lse = _flash_pallas(q, k, v, causal, scale,
-                                 interpret=interpret)
+                                 interpret=interpret, window=window)
         return out, lse.reshape(b, h, s)
 
     def fwd(q, k, v):
         out, lse = _flash_pallas_kept(q, k, v, causal, scale,
-                                      interpret=interpret)
+                                      interpret=interpret, window=window)
         return (out, lse.reshape(b, h, s)), (q, k, v, out, lse)
 
     def bwd(res, cotangents):
         g_o, g_lse = cotangents
         q, k, v, out, lse = res
         return _flash_pallas_bwd(q, k, v, out, lse, g_o, causal, scale,
-                                 interpret=interpret, g_lse=g_lse)
+                                 interpret=interpret, g_lse=g_lse,
+                                 window=window)
 
     fn.defvjp(fwd, bwd)
     return fn(q, k, v)
 
 
 def _flash_pallas_trainable(q, k, v, causal, scale, interpret=False,
-                            block_q=None, block_k=None):
+                            block_q=None, block_k=None, window=None):
     """Pallas forward + Pallas recompute-based backward (FlashAttention-2
     style): the forward saves only O and the per-row logsumexp; the
     backward re-materializes softmax blocks from them in VMEM. Activation
@@ -553,27 +607,28 @@ def _flash_pallas_trainable(q, k, v, causal, scale, interpret=False,
     @jax.custom_vjp
     def fn(q, k, v):
         out, _ = _flash_pallas(q, k, v, causal, scale, interpret=interpret,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               window=window)
         return out
 
     def fwd(q, k, v):
         out, lse = _flash_pallas_kept(q, k, v, causal, scale,
                                       interpret=interpret, block_q=block_q,
-                                      block_k=block_k)
+                                      block_k=block_k, window=window)
         return out, (q, k, v, out, lse)
 
     def bwd(res, g):
         q, k, v, out, lse = res
         return _flash_pallas_bwd(q, k, v, out, lse, g, causal, scale,
                                  interpret=interpret, block_q=block_q,
-                                 block_k=block_k)
+                                 block_k=block_k, window=window)
 
     fn.defvjp(fwd, bwd)
     return fn(q, k, v)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, force=None,
-                    platform=None, block_q=None, block_k=None):
+                    platform=None, block_q=None, block_k=None, window=None):
     """Blockwise attention: Pallas kernel on TPU, fused XLA otherwise.
 
     force: None (auto) | 'pallas' | 'xla' | 'interpret' (kernel under the
@@ -586,23 +641,41 @@ def flash_attention(q, k, v, causal=False, scale=None, force=None,
     kernels stream the SHARED kv blocks (no repeated copy; a group's query
     heads add into one dK/dV inside the backward kernel). block_q/block_k
     override `_auto_block`'s tiling.
+
+    window: None, or a sliding window of that many keys (causal only):
+    query i sees keys i - window < j <= i, and the kernels never visit a
+    block wholly outside it. A window that covers the sequence is the
+    causal call itself.
     """
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    window = _window(window, causal, q.shape[2])
     if force == "xla":
-        return reference_attention(q, k, v, causal, scale)
+        return reference_attention(q, k, v, causal, scale, window=window)
     if force == "interpret":
         return _flash_pallas_trainable(q, k, v, causal, scale,
                                        interpret=True, block_q=block_q,
-                                       block_k=block_k)
+                                       block_k=block_k, window=window)
     if force == "pallas" or (force is None and
                              _pallas_eligible(q, k, platform, block_q,
                                               block_k, v=v)):
         return _flash_pallas_trainable(q, k, v, causal, scale,
-                                       block_q=block_q, block_k=block_k)
+                                       block_q=block_q, block_k=block_k,
+                                       window=window)
     if force is None and (platform or jax.default_backend()) == "tpu":
         _count_dense_fallback(q, k, v)
-    return reference_attention(q, k, v, causal, scale)
+    return reference_attention(q, k, v, causal, scale, window=window)
+
+
+def _window(window, causal, seq_len):
+    """The window a call computes: None where there is none or where it
+    covers every key a causal query sees."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"flash attention: a sliding window ({window}) "
+                         "needs causal=True and at least one key")
+    return None if window >= seq_len else int(window)
 
 
 DENSE_FALLBACK_COUNTER = "attention_dense_fallback_total"
@@ -773,23 +846,36 @@ def decode_attention(q, k, v, lengths, scale=None, force=None,
 # -- registry surface -------------------------------------------------------
 
 GQA_COUNTER = "gqa_attention_calls_total"
+WINDOW_COUNTER = "window_attention_calls_total"
 
 
 def _flash_attention_op(attrs, octx, q, k, v):
+    from ..telemetry import registry
     if k.shape[1] != q.shape[1]:
-        from ..telemetry import registry
         registry.counter(
             GQA_COUNTER, help="attention calls traced with fewer k/v heads "
             "than query heads (a group of query heads shares one)").inc()
-    with jax.named_scope("mx.flash_attention"):
-        return _t(flash_attention(q, k, v, causal=attrs["causal"],
-                                  scale=attrs["scale"],
-                                  platform=octx.platform))
+    window = _window(attrs.get("window"), attrs["causal"], q.shape[2])
+    if window is None:
+        with jax.named_scope("mx.flash_attention"):
+            return _t(flash_attention(q, k, v, causal=attrs["causal"],
+                                      scale=attrs["scale"],
+                                      platform=octx.platform))
+    registry.counter(
+        WINDOW_COUNTER, help="attention calls traced with a sliding window "
+        "shorter than the sequence (the kernels skip the blocks outside "
+        "it)").inc()
+    with jax.named_scope("mx.flash_attention.window"):
+        return _t(flash_attention(q, k, v, causal=True, scale=attrs["scale"],
+                                  platform=octx.platform, window=window))
 
 
+# `window`: a sliding window of that many keys (causal only), under the
+# scope `mx.flash_attention.window` and counted once a trace
 register("_contrib_flash_attention", _flash_attention_op,
          params={"causal": Param("bool", False),
-                 "scale": Param("float", None)},
+                 "scale": Param("float", None),
+                 "window": Param("int", None)},
          inputs=("query", "key", "value"),
          # the output has the value's width (latent attention: 192 / 128)
          infer_shape=lambda attrs, s: (s, [

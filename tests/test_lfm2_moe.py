@@ -281,8 +281,8 @@ def test_no_shared_branch_is_built_at_zero_shared_experts():
 def test_the_third_spelling_of_the_mixtures_keys():
     got = decoder.mixture_settings(CFG)
     assert got == {"num_experts": 16, "top_k": 4, "num_shared": 0,
-                   "renormalize": True, "scoring": "sigmoid", "groups": 1,
-                   "method": "noaux_tc", "bias": True}
+                   "renormalize": True, "scaling": 1, "scoring": "sigmoid",
+                   "groups": 1, "method": "noaux_tc", "bias": True}
     assert decoder.mixture_settings(DSV3)["num_shared"] == 2
     assert decoder.mixture_settings(KIMI)["bias"] is True
 
@@ -456,7 +456,9 @@ def test_amp_keeps_router_and_taps_in_fp32_and_ids_exact():
 
 @pytest.mark.parametrize("key,value", [
     ("conv_bias", True),
-    ("layer_types", ["conv", "sliding_attention", "conv", "conv", "conv"]),
+    # (a windowed layer is computed since the `afmoe` family: a kind that
+    # no layer computes)
+    ("layer_types", ["conv", "chunked_attention", "conv", "conv", "conv"]),
     ("use_expert_bias", False),
     ("conv_L_cache", lm.SHORT_CONV_MAX_TAPS + 1), ("conv_L_cache", 0),
     ("rope_scaling", {"type": "yarn", "factor": 4}),

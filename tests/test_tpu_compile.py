@@ -178,6 +178,34 @@ def test_flash_attention_grouped_heads_of_64(one_chip):
         "bf16[2,8,4,8192,64]" not in text
 
 
+@pytest.mark.parametrize("window", [2048, 1000])
+def test_flash_attention_sliding_window(one_chip, window):
+    """Grouped-query attention over a sliding window at Trinity-Mini's
+    published widths: 32 query heads over 4 k/v heads of 128, two sequences
+    of 8,192, the window of 2,048 keys and one that is no multiple of a
+    block. The forward and the one backward kernel under value_and_grad,
+    each a body other than the causal call's (its loops start at the
+    window's first block)."""
+    from mxnet_tpu.ops.attention import flash_attention
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 4, 8192, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def program(w):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, scale=128 ** -0.5,
+                                   platform="tpu", window=w).astype(
+                                       jnp.float32).sum()
+        return _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                              q, kv, kv)
+
+    windowed = program(window)
+    assert windowed.count(KERNEL) == 2
+    assert "bf16[8,8192,128]" in windowed and "repeat" not in windowed
+    assert windowed != program(None)
+
+
 def _kda_operands(one_chip, b=2, s=8192, h=32, d=128):
     """`_contrib_kda`'s ten operands at `kimi_linear.train`'s shape."""
     def sds(shape, dtype=jnp.bfloat16):
