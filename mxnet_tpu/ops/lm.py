@@ -565,6 +565,7 @@ def moe_route(x, router_weight, router_bias, top_k, scaling, renormalize):
 
 MOE_KERNEL_COUNTER = "moe_grouped_kernel_calls_total"
 MOE_FALLBACK_COUNTER = "moe_grouped_xla_fallback_total"
+MOE_GATHER_COUNTER = "moe_gather_combine_calls_total"
 
 
 def _swiglu_experts(x, w_gate, w_up, w_down, group_sizes, force=None,
@@ -651,11 +652,102 @@ def _dense_experts_bwd(res, dy):
 _dense_experts.defvjp(_dense_experts_fwd, _dense_experts_bwd)
 
 
+# -- the grouped path's dispatch and combine: gathers both ways -------------------
+#
+# The pairs are sorted by expert; `order` is the sorted position -> pair map
+# (its first `capacity` entries: a row's pair), `inv` (T, k) its inverse (a
+# pair in token-major order -> its row) and `computed` (T, k) the pairs that
+# went through the products (inv < n_pairs). Each direction is then a
+# gather: rows from their tokens, tokens from their k rows, and so are the
+# transposes, so no scatter-add over rows exists forward or backward. Rows
+# past the last pair (on the TPU `ragged_dot` leaves them unwritten) are
+# never read: a pair that was not computed is SELECTED away on the (T, k)
+# index, never multiplied by 0.
+
+def _rows_of_pairs(a, inv):
+    """a[inv.T] (k, T, D): each pair's row, a token's k rows apart by T (a
+    layout without padding whatever k). A computed pair's row is below
+    n_pairs; any other is selected away by the caller."""
+    return jnp.take(a, inv.T, axis=0, mode="clip")
+
+
+def _sum_computed(rows, computed, weight=None):
+    """sum_j where(computed[:, j], weight[:, j] * rows[j], 0) (T, D) in
+    float32: k slices added in one elementwise pass."""
+    y = 0.0
+    for j in range(rows.shape[0]):
+        row = rows[j].astype(_F32)
+        if weight is not None:
+            row = weight[:, j, None] * row
+        y = y + jnp.where(computed[:, j, None], row, 0.0)
+    return y
+
+
+@jax.custom_vjp
+def _dispatch(x, order, inv, computed):
+    """rows (capacity, D) = x[order // k] in x's dtype; x's gradient is the
+    sum of each token's computed rows' gradients in float32, rounded once."""
+    return jnp.take(x, order // inv.shape[1], axis=0, mode="clip")
+
+
+def _dispatch_fwd(x, order, inv, computed):
+    return _dispatch(x, order, inv, computed), (inv, computed)
+
+
+def _dispatch_bwd(res, drows):
+    inv, computed = res
+    dx = _sum_computed(_rows_of_pairs(drows, inv), computed)
+    return dx.astype(drows.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine(out, w, order, inv, computed, dtype):
+    """y (T, D) = sum over a token's computed pairs of the pair's weight
+    (w (T, k) float32, token-major) times its row of out, in float32,
+    rounded once to `dtype`."""
+    return _sum_computed(_rows_of_pairs(out, inv), computed, w).astype(dtype)
+
+
+def _combine_fwd(out, w, order, inv, computed, dtype):
+    # the pairs' rows are kept for the weights' gradient: a mirror stage's
+    # rerun gathers them once, not once for y and again for the gradient
+    rows = _rows_of_pairs(out, inv)
+    y = _sum_computed(rows, computed, w).astype(dtype)
+    return y, (rows, w, order, computed)
+
+
+def _combine_bwd(dtype, res, dy):
+    rows, w, order, computed = res
+    # a row's gradient is its pair's weight times its token's; rows past the
+    # last pair get a finite value that no product reads. dy comes in y's
+    # dtype and is gathered so, then widened
+    k = w.shape[1]
+    dout = w.reshape(-1)[order][:, None] * jnp.take(
+        dy, order // k, axis=0, mode="clip").astype(_F32)
+    dw = jnp.where(computed, jnp.sum(dy.astype(_F32) * rows, -1).T, 0.0)
+    return dout, dw, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 # rows of the grouped products, in balanced shares (T * top_k * held / all):
 # four shares hold every step but those in which the tokens crowd onto the
 # held experts; past them the dense path takes over, so the number decides
 # speed and memory, never the result
 MOE_CAPACITY = 4
+
+# the gathers read a token's k rows whether its pairs were computed or not:
+# T * k rows, where the scatter-add writes `capacity`. One layer's value and
+# gradients with the forward rerun, kernels and router included, on a v5e
+# (tests_tpu/test_moe_combine.py; PERF.md section 6): 40.0 ms through the
+# scatter-add against 31.3 through the gathers where T * k = capacity, 30.3
+# against 33.0 at twice it, 26.6 against 33.5 and 22.2 against 35.2 at four
+# and eight times it. Past this ratio the grouped path keeps the scatter-add
+MOE_GATHER_MAX_RATIO = 1
 
 
 def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
@@ -672,8 +764,13 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
     ever dropped. The grouped products are `_swiglu_experts`'s: Pallas
     kernels in a program for a TPU (the rows past the last pair come back
     as zeros), `jax.lax.ragged_dot` in every other (they come back
-    unwritten); `force` and `platform` are passed on to it."""
-    t, d = x.shape
+    unwritten); `force` and `platform` are passed on to it. Rows reach
+    the products and their results their tokens by gathers through the
+    sort's permutation and its inverse (`_dispatch`, `_combine`), whose
+    transposes are gathers too, where T * k is at most
+    MOE_GATHER_MAX_RATIO times `capacity`; past it by a gather and a
+    scatter-add."""
+    t = x.shape[0]
     n_held = w_gate.shape[0]
     n_all = router_weight.shape[0]
     with jax.named_scope("mx.moe.route"):
@@ -688,37 +785,52 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
         balanced = -(-t * top_k * n_held // n_all)
         capacity = min(t * top_k,
                        -(-balanced * MOE_CAPACITY // 128) * 128)
-        order = jnp.argsort(key, stable=True)[:capacity]
-        token = order // top_k
+        order = jnp.argsort(key, stable=True)
+        rows_of = order[:capacity]          # a row's pair
+
+    def products(rows):
+        with jax.named_scope("mx.moe.experts.matmul"):
+            return _swiglu_experts(rows, w_gate, w_up, w_down, load,
+                                   force=force, platform=platform)
+
+    def gathered(_):
+        _count(MOE_GATHER_COUNTER, "grouped mixtures traced with gathers "
+               "for their dispatch and combine")
+        # the inverse permutation: a pair, token-major, -> its sorted row
+        inv = jnp.argsort(order).astype(order.dtype).reshape(t, top_k)
+        computed = inv < n_pairs
+        out = products(_dispatch(x, rows_of, inv, computed))
+        return _combine(out, w, rows_of, inv, computed, x.dtype)
+
+    def scattered(_):
+        token = rows_of // top_k
         # rows past the held pairs belong to no group: `ragged_dot`
         # leaves them unwritten (on the TPU: whatever the memory held), so
         # they are SELECTED away on both sides of it, never multiplied away
         valid = (jnp.arange(capacity) < n_pairs)[:, None]
-        weight = w.reshape(-1)[order][:, None]
-
-    def grouped(_):
-        rows = jnp.where(valid, jnp.take(x, token, axis=0), 0)
-        with jax.named_scope("mx.moe.experts.matmul"):
-            out = _swiglu_experts(rows, w_gate, w_up, w_down, load,
-                                  force=force, platform=platform)
+        out = products(jnp.where(valid, jnp.take(x, token, axis=0), 0))
         # selected BEFORE the product with the pair's weight: the weight's
         # gradient is a sum over out, and 0 x whatever the memory held is
         # NaN wherever that is no number
-        return jnp.zeros((t, d), _F32).at[token].add(
-            jnp.where(valid, out, 0.0) * weight)
+        return jnp.zeros((t, x.shape[1]), _F32).at[token].add(
+            jnp.where(valid, out, 0.0) * w.reshape(-1)[rows_of][:, None]
+        ).astype(x.dtype)
 
     def dense(_):
         per_expert = jnp.stack(
             [jnp.sum(jnp.where(local == e, w, 0.0), -1)
              for e in range(n_held)], axis=-1)                 # (T, E_held)
-        return _dense_experts(x, per_expert, w_gate, w_up, w_down)
+        return _dense_experts(x, per_expert, w_gate, w_up, w_down).astype(
+            x.dtype)
 
     with jax.named_scope("mx.moe.experts"):
         fits = n_pairs <= capacity
+        grouped = gathered if t * top_k <= MOE_GATHER_MAX_RATIO * capacity \
+            else scattered
         y = jax.lax.cond(fits, grouped, dense, None)
     stats = jnp.concatenate([load, jnp.stack([
         n_pairs, n_pairs, 1 - fits.astype(jnp.int32)]).astype(jnp.int32)])
-    return y.astype(x.dtype), jax.lax.stop_gradient(stats)
+    return y, jax.lax.stop_gradient(stats)
 
 
 def _moe_op(attrs, octx, data, router_weight, router_bias, w_gate, w_up,

@@ -344,7 +344,7 @@ def test_mla_block_matches_reference():
            want, 2e-6)
 
 
-def _moe_system(cfg, p, x, way=None):
+def _moe_layer(cfg, p, x, way=None):
     first, n = cfg["experts_held"]
     t = jnp.asarray(x).reshape(-1, x.shape[-1])
     y, stats = lm.moe_experts(
@@ -353,7 +353,12 @@ def _moe_system(cfg, p, x, way=None):
         first_expert=first, top_k=cfg["num_experts_per_token"],
         scaling=cfg["routed_scaling_factor"],
         renormalize=cfg["moe_renormalize"], force=way)
-    return y.reshape(x.shape), np.asarray(stats)
+    return y.reshape(x.shape), stats
+
+
+def _moe_system(cfg, p, x, way=None):
+    y, stats = _moe_layer(cfg, p, x, way)
+    return y, np.asarray(stats)
 
 
 @pytest.mark.parametrize("way", ["xla", "interpret"])
@@ -385,6 +390,91 @@ def test_moe_routed_part_matches_reference(crowd, way):
     fn = lambda x: jnp.sum(jnp.sin(ref.moe_mlp(cfg, p, x, shared=False)))
     fs = lambda x: jnp.sum(jnp.sin(_moe_system(cfg, p, x, way)[0]))
     _close(jax.grad(fs)(jnp.asarray(x)), jax.grad(fn)(jnp.asarray(x)), 5e-5)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+# tokens (2 sequences of S), experts a token, router, held: T * k is 1, 2,
+# 4 and 8 times the grouped rows (`capacity`), as in `lfm2_moe.train`,
+# `kanana2.train`, `trinity_mini.train` and `kimi_linear.train`
+RATIOS = {1: (32, 4, 16, 4), 2: (32, 4, 16, 2), 4: (64, 4, 64, 4),
+          8: (64, 8, 64, 2)}
+
+
+def _ratio_case(ratio):
+    s, k, e, held = RATIOS[ratio]
+    cfg = dict(CFG, num_experts=e, num_experts_per_token=k,
+               experts_held=[0, held])
+    p = _layer(ref.init_params(cfg, 6), 1)
+    x = np.random.default_rng(3).standard_normal((2, s, 32)).astype("f4")
+    t = 2 * s
+    balanced = -(-t * k * held // e)
+    capacity = min(t * k, -(-balanced * lm.MOE_CAPACITY // 128) * 128)
+    assert t * k == ratio * capacity
+    return cfg, p, jnp.asarray(x)
+
+
+@pytest.mark.parametrize("gathers_up_to", [None, 8], ids=["auto", "gathers"])
+@pytest.mark.parametrize("ratio", sorted(RATIOS))
+def test_moe_gathers_both_ways_and_rows_of_no_group(ratio, gathers_up_to,
+                                                     monkeypatch):
+    """Where T * k is at most MOE_GATHER_MAX_RATIO times the grouped rows
+    (or a larger ratio given here), the gradient program of the layer holds
+    no scatter-add into an array of the hidden width: rows reach the
+    products and their tokens by gathers both ways (past the ratio the
+    scatter-add combine stays). Either way the rows that belong to no
+    group may hold anything: planted as NaN, they reach no gradient, the
+    router's included, and every gradient is the reference's."""
+    if gathers_up_to:
+        monkeypatch.setattr(lm, "MOE_GATHER_MAX_RATIO", gathers_up_to)
+    cfg, p, x = _ratio_case(ratio)
+    names = ("w_r", "e_gate", "e_up", "e_down")
+    grouped = lm._swiglu_experts
+
+    def unwritten(rows, w_gate, w_up, w_down, group_sizes, **path):
+        out = grouped(rows, w_gate, w_up, w_down, group_sizes, **path)
+        in_a_group = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(in_a_group[:, None], out, jnp.nan)
+
+    def loss(x, *w, system=True):
+        q = dict(p, **dict(zip(names, w)))
+        if not system:
+            return jnp.sum(jnp.sin(ref.moe_mlp(cfg, q, x, shared=False)))
+        return jnp.sum(jnp.sin(_moe_layer(cfg, q, x, "xla")[0]))
+
+    args = (x,) + tuple(p[n] for n in names)
+    assert _moe_system(cfg, p, x)[1][-1] == 0          # the grouped path
+    grad = jax.value_and_grad(loss, argnums=range(5))
+    jaxpr = jax.make_jaxpr(grad)(*args).jaxpr
+    wide = [eqn for eqn in _eqns(jaxpr) if eqn.primitive.name == "scatter-add"
+            and eqn.invars[0].aval.shape[-1:] == (32,)]
+    assert (not wide) == (ratio <= lm.MOE_GATHER_MAX_RATIO), wide
+    monkeypatch.setattr(lm, "_swiglu_experts", unwritten)
+    _, got = grad(*args)
+    _, want = jax.value_and_grad(functools.partial(loss, system=False),
+                                 argnums=range(5))(*args)
+    for name, g, a in zip(("x",) + names, got, want):
+        assert np.isfinite(np.asarray(g)).all(), name
+        _close(g, a, 5e-5)
+
+
+@pytest.mark.parametrize("ratio", [1, 8])
+def test_gather_combine_is_counted_once_a_trace(ratio):
+    from mxnet_tpu.telemetry import registry
+    cfg, p, x = _ratio_case(ratio)
+    calls = registry.counter(lm.MOE_GATHER_COUNTER)
+    before = calls.value()
+    jax.make_jaxpr(lambda x: _moe_layer(cfg, p, x)[0])(x)
+    assert calls.value() - before == (ratio <= lm.MOE_GATHER_MAX_RATIO)
 
 
 def test_shares_add_up_to_the_uncut_layer():

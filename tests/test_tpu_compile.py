@@ -148,6 +148,47 @@ def test_moe_grouped_kernels_forward_and_gradient(one_chip, cell, rows, d, w,
     assert "ragged-dot" not in forward + both
 
 
+@pytest.mark.parametrize("cell,d,w,held,n_all,k,ratio", [
+    ("lfm2_moe.train", 2048, 1792, 8, 32, 4, 1),
+    ("kanana2.train", 2048, 768, 16, 128, 6, 2),
+    ("trinity_mini.train", 2048, 1024, 8, 128, 8, 4),
+    ("kimi_linear.train", 2304, 1024, 8, 256, 8, 8),
+])
+def test_moe_layer_dispatch_and_combine(one_chip, cell, d, w, held, n_all, k,
+                                        ratio):
+    """One mixture layer's value and gradients at a cell's shape (2 x 8,192
+    tokens, bf16) through `lm.moe_experts`: the kernels' 2 + 6 calls, and
+    where T * k is at most `MOE_GATHER_MAX_RATIO` times the grouped rows no
+    scatter writes an array of (T, D) or (rows, D), forward or backward:
+    both ways are gathers. Past the ratio the scatter-add combine stays."""
+    from mxnet_tpu.ops import lm
+    t = 2 * 8192
+    capacity = t * k // ratio
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    specs = (sds((t, d)), sds((n_all, d), jnp.float32),
+             sds((n_all,), jnp.float32), sds((held, d, w)),
+             sds((held, d, w)), sds((held, w, d)), sds((t, d)))
+
+    def fwd(x, rw, rb, wg, wu, wd, _):
+        return lm.moe_experts(x, rw, rb, wg, wu, wd, first_expert=0, top_k=k,
+                              scaling=1.0, renormalize=True,
+                              platform="tpu")[0]
+
+    def loss(*a):               # the layer's output feeds what follows it
+        return (fwd(*a).astype(jnp.float32) * a[-1]).sum()
+
+    forward = _compiled_text(fwd, *specs)
+    both = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5)),
+                          *specs)
+    assert forward.count(KERNEL) == 2 and both.count(KERNEL) == 6
+    wide = re.findall(r"\[(?:%d|%d),%d\]\S* scatter\(" % (t, capacity, d),
+                      forward + both)
+    assert (not wide) == (ratio <= lm.MOE_GATHER_MAX_RATIO), wide
+
+
 def test_flash_attention_grouped_heads_of_64(one_chip):
     """Grouped-query attention at LFM2-8B-A1B's published widths: 32 query
     heads over 8 k/v heads of 64, two sequences of 8,192. A minor dim of 64
