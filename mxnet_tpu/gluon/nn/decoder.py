@@ -12,7 +12,8 @@ it, else hidden / heads). Otherwise
 config.json counts them) pick Kimi Delta Attention or multi-head latent
 attention, and a config without `linear_attn_config` is latent attention in
 every layer. `num_dense_layers` or `first_k_dense_replace` picks the dense
-SwiGLU or the mixture of experts. Latent attention rotates its
+SwiGLU or the mixture of experts; a config without `moe_intermediate_size`
+is dense in every layer. Latent attention rotates its
 `qk_rope_head_dim` dims (`rope_theta`, `rope_interleave`) unless the config
 says `mla_use_nope`. The mixture's keys are read in any of four spellings
 (`MIXTURE_KEYS`), the norms' epsilon in two (`rms_norm_eps`, `norm_eps`).
@@ -23,9 +24,13 @@ its config.json does not say is in `FAMILIES`, by `model_type`: the
 `afmoe` family rotates q and k in its windowed layers only, gates the
 attention's output with a sigmoid of a product of the layer's input, and
 puts an RMSNorm on each sub-layer's output before its residual add
-(sandwich norms, `post_norm1` / `post_norm2`). Users: Kimi-Linear-48B-A3B
-(arXiv:2510.26692), `deepseek_v3` configs (Kanana-2-30B-A3B), `lfm2_moe`
-configs (LFM2-8B-A1B) and `afmoe` configs (Trinity-Mini). What a config
+(sandwich norms, `post_norm1` / `post_norm2`); the `ouro` family has the
+sandwich norms, no q/k norms and a loop: `total_ut_steps` runs the stack
+that many times with the same weights, with an exit gate after each pass
+(`DecoderLM`). Users: Kimi-Linear-48B-A3B (arXiv:2510.26692),
+`deepseek_v3` configs (Kanana-2-30B-A3B), `lfm2_moe` configs
+(LFM2-8B-A1B), `afmoe` configs (Trinity-Mini) and `ouro` configs
+(Ouro-2.6B, arXiv:2510.25741). What a config
 asks for and no layer here computes raises NotImplementedError with the
 key's name. `TransformerEncoder`
 (transformer.py) is the older, hard-wired block and stays as it is.
@@ -137,11 +142,20 @@ def _eps(cfg):
 
 # what a family's modeling code does that its config.json does not say:
 # which layer kinds rotate q and k, a sigmoid gate on the attention's
-# output, an RMSNorm on each sub-layer's output before its residual add
+# output, an RMSNorm on each sub-layer's output before its residual add,
+# an RMSNorm over each q and k head
 FAMILIES = {"afmoe": {"rotate": ("sliding_attention",), "output_gate": True,
-                      "sandwich_norms": True}}
+                      "sandwich_norms": True, "qk_norm": True},
+            "ouro": {"rotate": ("full_attention", "sliding_attention"),
+                     "output_gate": False, "sandwich_norms": True,
+                     "qk_norm": False}}
 _PLAIN = {"rotate": ("full_attention", "sliding_attention"),
-          "output_gate": False, "sandwich_norms": False}
+          "output_gate": False, "sandwich_norms": False, "qk_norm": True}
+
+
+# beta of a looped model's objective, sum_t p_t CE_t - beta H(p): the
+# weight of the exit distribution's entropy in Ouro's first training stage
+EXIT_ENTROPY_WEIGHT = 0.1
 
 
 def family(cfg):
@@ -290,9 +304,9 @@ class GQAMixer(HybridBlock):
     `num_key_value_heads` key/value heads of `head_dim` dims (hidden / heads
     where the config has no `head_dim`; query head h attends to k/v head
     h // group), an RMSNorm with a learned scale over each query and key
-    head, then the rotation of the whole head by position, halves paired
-    (`_contrib_rope`, `interleave` false) in the layer kinds the family
-    rotates (`family`). `kind` "sliding_attention" attends over a window of
+    head where the family has them (`qk_norm`), then the rotation of the
+    whole head by position, halves paired (`_contrib_rope`, `interleave`
+    false) in the layer kinds the family rotates (`family`). `kind` "sliding_attention" attends over a window of
     `sliding_window` keys (the flash kernels skip the blocks outside it).
     Where the family gates the output, o <- o * sigmoid(x W_g) before W_o.
     k and v reach `_contrib_flash_attention` at their own head count: a
@@ -327,24 +341,27 @@ class GQAMixer(HybridBlock):
         self.wq = get("wq", shape=(c, d))
         self.wk = get("wk", shape=(self._hkv * self._dh, d))
         self.wv = get("wv", shape=(self._hkv * self._dh, d))
-        self.q_norm = get("q_norm", shape=(self._dh,), init="ones")
-        self.k_norm = get("k_norm", shape=(self._dh,), init="ones")
+        if family(cfg)["qk_norm"]:
+            self.q_norm = get("q_norm", shape=(self._dh,), init="ones")
+            self.k_norm = get("k_norm", shape=(self._dh,), init="ones")
         if family(cfg)["output_gate"]:
             self.wg = get("wg", shape=(c, d))
         self.wo = get("wo", shape=(d, c))
 
-    def hybrid_forward(self, F, x, wq, wk, wv, q_norm, k_norm, wo, wg=None):
+    def hybrid_forward(self, F, x, wq, wk, wv, wo, q_norm=None, k_norm=None,
+                       wg=None):
         h, hkv, dh = self._h, self._hkv, self._dh
 
         def heads(t, n):                # (B, S, n*dh) -> (B, n, S, dh)
             return F.transpose(F.reshape(t, shape=(0, 0, n, dh)),
                                axes=(0, 2, 1, 3))
 
+        def normed(t, scale):
+            return t if scale is None else F.RMSNorm(t, scale, eps=self._eps)
+
         with _scope(self._where):
-            q = F.RMSNorm(heads(_dense(F, x, wq, h * dh), h), q_norm,
-                          eps=self._eps)
-            k = F.RMSNorm(heads(_dense(F, x, wk, hkv * dh), hkv), k_norm,
-                          eps=self._eps)
+            q = normed(heads(_dense(F, x, wq, h * dh), h), q_norm)
+            k = normed(heads(_dense(F, x, wk, hkv * dh), hkv), k_norm)
             v = heads(_dense(F, x, wv, hkv * dh), hkv)
             if self._theta is not None:
                 with _scope(self._where + ".rope"):
@@ -448,8 +465,12 @@ class DecoderBlock(HybridBlock):
                              "full_attn_layers")
         d = cfg["hidden_size"]
         self._eps = _eps(cfg)
-        self._moe = layer > _either(cfg, "num_dense_layers",
-                                    "first_k_dense_replace")
+        # a config with no mixture width is dense in every layer
+        self._moe = "moe_intermediate_size" in cfg and layer > _either(
+            cfg, "num_dense_layers", "first_k_dense_replace")
+        # the mirror_stage of the next application; a looped DecoderLM
+        # names one a pass
+        self.stage = self.prefix + "layer"
         get = _getter(self)
         self.norm1 = get("norm1", shape=(d,), init="ones")
         self.norm2 = get("norm2", shape=(d,), init="ones")
@@ -467,7 +488,7 @@ class DecoderBlock(HybridBlock):
             return y if scale is None else F.RMSNorm(y, scale, eps=self._eps)
 
         # one stage a layer: its backward recomputes it from the stream
-        with AttrScope(mirror_stage=self.prefix + "layer"):
+        with AttrScope(mirror_stage=self.stage):
             x = x + out(self.mixer(F.RMSNorm(x, norm1, eps=self._eps)),
                         post_norm1)
             h = F.RMSNorm(x, norm2, eps=self._eps)
@@ -487,35 +508,72 @@ class DecoderLM(HybridBlock):
     (batch, sequence) cross-entropy of each position and, where there are
     mixture layers, their stacked stats (layers, experts held + 3) as a
     second output. Under `mup_enabled` the embedding's output is scaled by
-    sqrt(hidden) (its input alone: the head is not scaled)."""
+    sqrt(hidden) (its input alone: the head is not scaled).
+
+    `total_ut_steps` T (Ouro's looped model) runs the same blocks T times:
+    after each pass the final norm, whose output feeds the next pass, the
+    head and, but for the last pass, an exit gate, lambda_t = sigmoid(w_g .
+    h_t + b_g). The exit distribution is p_t = lambda_t prod_{j<t} (1 -
+    lambda_j), p_T = prod_{j<T} (1 - lambda_j), and the loss of a position
+    is sum_t p_t CE_t - beta H(p) with beta = `EXIT_ENTROPY_WEIGHT` (Ouro's
+    stage-I setting, which config.json does not hold); `net(tokens, labels)` gives it and p (batch, sequence,
+    T) as a second output, `net(tokens)` the last pass's logits. The passes
+    run under `mx.loop`, each pass's head under `mx.lm_head`, the gate and
+    the objective under `mx.exit_gate`; every (pass, layer) application is
+    a `mirror_stage` of its own (`<layer prefix>p<t>_layer`), and so is each
+    pass's head (`p<t>_head`). A parameter used T times is one
+    variable, whose gradient is the sum of its uses'. Every token runs all
+    T passes: `early_exit_threshold` below 1 (adaptive exit) is refused."""
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
         if cfg.get("hidden_act", "silu") != "silu":
             raise NotImplementedError(
                 f"DecoderLM: hidden_act = {cfg['hidden_act']!r}")
+        for key, computed in (("early_exit_threshold", 1),
+                              ("use_sliding_window", False)):
+            if key in cfg and cfg[key] != computed:
+                raise NotImplementedError(
+                    f"DecoderLM: {key} = {cfg[key]!r} (computed here: "
+                    f"{computed!r})")
         d, v = cfg["hidden_size"], cfg["vocab_size"]
         self._d, self._v, self._eps = d, v, _eps(cfg)
         self._embed_scale = d ** 0.5 if cfg.get("mup_enabled") else None
+        self._passes = cfg.get("total_ut_steps")
         get = _getter(self)
         self.embed = get("embed", shape=(v, d))
         self.norm_f = get("norm_f", shape=(d,), init="ones")
         if not cfg.get("tie_word_embeddings"):
             self.head = get("head", shape=(v, d))
+        if self._passes is not None:
+            if int(self._passes) < 1:
+                raise ValueError(
+                    f"DecoderLM: total_ut_steps = {self._passes!r}")
+            if int(self._passes) > 1:
+                self.exit_gate = get("exit_gate", shape=(1, d))
+                self.exit_gate_bias = get("exit_gate_bias", shape=(1,),
+                                          init="zeros")
         self.blocks = []
         with self.name_scope():
             for i in range(cfg["num_hidden_layers"]):
                 block = DecoderBlock(cfg, i + 1, prefix=f"l{i}_")
                 self.register_child(block)
                 self.blocks.append(block)
+        if self._passes is not None and any(b._moe for b in self.blocks):
+            raise NotImplementedError(
+                "DecoderLM: total_ut_steps with mixture layers")
 
     def hybrid_forward(self, F, tokens, labels=None, embed=None,
-                       norm_f=None, head=None):
+                       norm_f=None, head=None, exit_gate=None,
+                       exit_gate_bias=None):
         x = F.Embedding(tokens, embed, input_dim=self._v, output_dim=self._d)
         if self._embed_scale is not None:
             x = x * self._embed_scale
         if head is None:        # tied: the embedding matrix is the head
             head = embed
+        if self._passes is not None:
+            return self._loop(F, x, labels, norm_f, head, exit_gate,
+                              exit_gate_bias)
         stats = []
         for block in self.blocks:
             x, s = block(x)
@@ -531,3 +589,51 @@ class DecoderLM(HybridBlock):
             return loss
         stats = stats[0] if len(stats) == 1 else F.concat(*stats, dim=0)
         return loss, F.BlockGrad(stats)
+
+    def _loop(self, F, x, labels, norm_f, head, exit_gate, exit_gate_bias):
+        passes = int(self._passes)
+        ces, gates = [], []
+        for t in range(passes):
+            with _scope("mx.loop"):
+                for block in self.blocks:
+                    block.stage = f"{block.prefix}p{t}_layer"
+                    x, _ = block(x)
+            # the norm's output feeds the next pass too: it belongs to no
+            # head's stage, which the next pass would otherwise read
+            with _scope("mx.lm_head"):
+                x = F.RMSNorm(x, norm_f, eps=self._eps)
+            if labels is None:
+                continue
+            with _scope("mx.lm_head"), AttrScope(mirror_stage=f"p{t}_head"):
+                ces.append(F._contrib_lm_head_ce(x, head, labels,
+                                                 num_classes=self._v))
+            # a stage has one last node that all of it feeds: the gate,
+            # (batch, sequence) wide, is in none
+            if t < passes - 1:
+                with _scope("mx.exit_gate"):
+                    z = F.FullyConnected(x, exit_gate, exit_gate_bias,
+                                         num_hidden=1, flatten=False)
+                    gates.append(F.Cast(F.reshape(z, shape=(0, 0)),
+                                        dtype="float32"))
+        if labels is None:
+            with _scope("mx.lm_head"):
+                return _dense(F, x, head, self._v)
+        with _scope("mx.exit_gate"):
+            return _exit_objective(F, ces, gates, EXIT_ENTROPY_WEIGHT)
+
+
+def _exit_objective(F, ces, gates, beta):
+    """(sum_t p_t (CE_t + beta log p_t), p) of each position, from the T
+    cross-entropies and the T - 1 gates' logits z_t: log lambda_t =
+    -softplus(-z_t), log(1 - lambda_t) = -softplus(z_t)."""
+    log_p, log_stay = [], None
+    for z in gates:
+        leave = -F.Activation(-z, act_type="softrelu")
+        log_p.append(leave if log_stay is None else log_stay + leave)
+        stay = -F.Activation(z, act_type="softrelu")
+        log_stay = stay if log_stay is None else log_stay + stay
+    log_p.append(F.zeros_like(ces[0]) if log_stay is None else log_stay)
+    log_p = F.stack(*log_p, axis=-1)
+    p = F.exp(log_p)
+    loss = F.sum(p * (F.stack(*ces, axis=-1) + beta * log_p), axis=-1)
+    return loss, F.BlockGrad(p)

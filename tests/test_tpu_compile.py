@@ -15,6 +15,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
@@ -508,3 +509,62 @@ def test_mirror_stage_keeps_flash_residuals(one_chip):
     assert kernels(kept) == [1, 1]
     assert kept.memory_analysis().temp_size_in_bytes <= \
         bare.memory_analysis().temp_size_in_bytes + 0.16e9
+
+
+def test_ouro_train_step_fits_one_chip(topo, monkeypatch):
+    """`ouro.train`'s whole K = 2 step for one described v5e at the cell's
+    size (benchmarks/configs/ouro_2_6b.json: 6 layers run 4 times, 2
+    sequences of 8,192, adam, amp bf16, one mirror stage a (pass, layer)):
+    the arguments (parameters and adam's two moments, float32) and the
+    temporaries fit the chip's 15.75 GiB, and every one of the 24 layer
+    applications runs the flash kernels. 5.70 + 7.17 GiB here, a minute."""
+    import importlib.util
+    import json
+    import os
+    from jax.sharding import Mesh
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp
+    from mxnet_tpu import random as mx_random
+    from mxnet_tpu.parallel.dp import DataParallelTrainer
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    spec = importlib.util.spec_from_file_location(
+        "ouro_model_under_compile", os.path.join(bench, "models", "ouro.py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+    with open(os.path.join(bench, "configs", "ouro_2_6b.json")) as f:
+        cfg = json.load(f)
+    b, s = cfg["per_chip_batch"], cfg["sequence_length"]
+    monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    amp.init("bfloat16")
+    try:
+        sym = model.build(cfg)
+        tr = DataParallelTrainer(
+            sym, Mesh(topo.devices[:1], ("data",)), data_names=("data",),
+            label_names=("label",), optimizer="adam", learning_rate=1e-4,
+            rescale_grad=1.0 / (b * s), dtype="bfloat16", beta1=0.9,
+            beta2=0.95, epsilon=1e-8)
+        shapes = dict(zip(sym.list_arguments(),
+                          sym.infer_shape(data=(b, s), label=(b, s))[0]))
+        f32, sds = jnp.float32, jax.ShapeDtypeStruct
+        params = tuple(sds(shapes[n], f32) for n in tr.param_names)
+        states = tuple(tuple(sds(shapes[n], f32) for _ in range(2))
+                       for n in tr.param_names)
+        inputs = tuple(sds((2, b, s), jnp.int32) for _ in tr.input_names)
+        key = mx_random.next_key()
+        compiled = tr._multi_step_fn(2, "all").lower(
+            params, states, (), inputs, sds(key.shape, key.dtype),
+            sds((), f32), sds((), f32)).compile()
+    finally:
+        amp._reset_for_tests()
+    assert sum(np.prod(p.shape) for p in params) == 509_661_185
+    mem = compiled.memory_analysis()
+    args_gib = mem.argument_size_in_bytes / 2**30
+    temp_gib = mem.temp_size_in_bytes / 2**30
+    assert 5.6 < args_gib < 5.8          # 12 B a parameter, whole
+    assert args_gib + temp_gib < 15.75, (args_gib, temp_gib)
+    calls = [line for line in compiled.as_text().split("\n")
+             if "custom-call(" in line and KERNEL in line]
+    # the forward kernel once a layer application (its out and lse kept)
+    assert sum("mx_flash_attention_fwd" in line for line in calls) == 24
