@@ -566,6 +566,8 @@ def moe_route(x, router_weight, router_bias, top_k, scaling, renormalize):
 MOE_KERNEL_COUNTER = "moe_grouped_kernel_calls_total"
 MOE_FALLBACK_COUNTER = "moe_grouped_xla_fallback_total"
 MOE_GATHER_COUNTER = "moe_gather_combine_calls_total"
+MOE_COMBINE_COUNTER = "moe_combine_kernel_calls_total"
+MOE_COMBINE_FALLBACK_COUNTER = "moe_combine_xla_fallback_total"
 
 
 def _swiglu_experts(x, w_gate, w_up, w_down, group_sizes, force=None,
@@ -658,11 +660,15 @@ _dense_experts.defvjp(_dense_experts_fwd, _dense_experts_bwd)
 # (its first `capacity` entries: a row's pair), `inv` (T, k) its inverse (a
 # pair in token-major order -> its row) and `computed` (T, k) the pairs that
 # went through the products (inv < n_pairs). Each direction is then a
-# gather: rows from their tokens, tokens from their k rows, and so are the
-# transposes, so no scatter-add over rows exists forward or backward. Rows
-# past the last pair (on the TPU `ragged_dot` leaves them unwritten) are
-# never read: a pair that was not computed is SELECTED away on the (T, k)
-# index, never multiplied by 0.
+# gather: rows from their tokens, tokens from their computed rows, and so are
+# the transposes, so no scatter-add over rows exists forward or backward. The
+# two directions that sum a token's rows (the combine, and the dispatch's
+# transpose) take `moe_pallas.gather_sum` where `links` carries its plan: a
+# kernel that reads the computed pairs' rows only; elsewhere XLA's gathers
+# below, which read a token's k rows, computed or not. Rows past the last
+# pair (on the TPU `ragged_dot` leaves them unwritten) are never read: a
+# pair that was not computed is SELECTED away on the (T, k) index, never
+# multiplied by 0.
 
 def _rows_of_pairs(a, inv):
     """a[inv.T] (k, T, D): each pair's row, a token's k rows apart by T (a
@@ -683,52 +689,65 @@ def _sum_computed(rows, computed, weight=None):
     return y
 
 
-@jax.custom_vjp
-def _dispatch(x, order, inv, computed):
+def _sum_rows(rows, links, weight, dtype, interpret):
+    """Each token's computed rows of `rows` summed in float32 (times the
+    pair's weight where given), rounded once to `dtype`: by the kernel
+    where `links` holds its plan, else by XLA's gathers."""
+    inv, computed, plan = links
+    if plan is None:
+        return _sum_computed(_rows_of_pairs(rows, inv), computed,
+                             weight).astype(dtype)
+    from . import moe_pallas
+    return moe_pallas.gather_sum(rows, plan, weight, k=inv.shape[1],
+                                 out_dtype=dtype, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, links, interpret):
     """rows (capacity, D) = x[order // k] in x's dtype; x's gradient is the
     sum of each token's computed rows' gradients in float32, rounded once."""
-    return jnp.take(x, order // inv.shape[1], axis=0, mode="clip")
+    return jnp.take(x, order // links[0].shape[1], axis=0, mode="clip")
 
 
-def _dispatch_fwd(x, order, inv, computed):
-    return _dispatch(x, order, inv, computed), (inv, computed)
+def _dispatch_fwd(x, order, links, interpret):
+    return _dispatch(x, order, links, interpret), links
 
 
-def _dispatch_bwd(res, drows):
-    inv, computed = res
-    dx = _sum_computed(_rows_of_pairs(drows, inv), computed)
-    return dx.astype(drows.dtype), None, None, None
+def _dispatch_bwd(interpret, links, drows):
+    return (_sum_rows(drows, links, None, drows.dtype, interpret), None,
+            None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _combine(out, w, order, inv, computed, dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _combine(out, w, order, links, dtype, interpret):
     """y (T, D) = sum over a token's computed pairs of the pair's weight
     (w (T, k) float32, token-major) times its row of out, in float32,
     rounded once to `dtype`."""
-    return _sum_computed(_rows_of_pairs(out, inv), computed, w).astype(dtype)
+    return _sum_rows(out, links, w, dtype, interpret)
 
 
-def _combine_fwd(out, w, order, inv, computed, dtype):
-    # the pairs' rows are kept for the weights' gradient: a mirror stage's
-    # rerun gathers them once, not once for y and again for the gradient
-    rows = _rows_of_pairs(out, inv)
-    y = _sum_computed(rows, computed, w).astype(dtype)
-    return y, (rows, w, order, computed)
+def _combine_fwd(out, w, order, links, dtype, interpret):
+    # the products' rows are the residual: the weights' gradient is taken
+    # row by row, beside `dout`, and brought to (T, k) as scalars
+    return _combine(out, w, order, links, dtype, interpret), (out, w, order,
+                                                              links)
 
 
-def _combine_bwd(dtype, res, dy):
-    rows, w, order, computed = res
+def _combine_bwd(dtype, interpret, res, dy):
+    out, w, order, (inv, computed, _) = res
     # a row's gradient is its pair's weight times its token's; rows past the
-    # last pair get a finite value that no product reads. dy comes in y's
-    # dtype and is gathered so, then widened
+    # last pair get a finite value that no product reads, and their
+    # weights' gradients are selected away. dy comes in y's dtype and is
+    # gathered so, then widened
     k = w.shape[1]
-    dout = w.reshape(-1)[order][:, None] * jnp.take(
-        dy, order // k, axis=0, mode="clip").astype(_F32)
-    dw = jnp.where(computed, jnp.sum(dy.astype(_F32) * rows, -1).T, 0.0)
-    return dout, dw, None, None, None
+    dy_rows = jnp.take(dy, order // k, axis=0, mode="clip").astype(_F32)
+    dout = w.reshape(-1)[order][:, None] * dy_rows
+    dw_row = jnp.sum(dy_rows * out, -1)
+    dw = jnp.where(computed, jnp.take(dw_row, inv, mode="clip"), 0.0)
+    return dout, dw, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -740,14 +759,38 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 # speed and memory, never the result
 MOE_CAPACITY = 4
 
-# the gathers read a token's k rows whether its pairs were computed or not:
-# T * k rows, where the scatter-add writes `capacity`. One layer's value and
-# gradients with the forward rerun, kernels and router included, on a v5e
-# (tests_tpu/test_moe_combine.py; PERF.md section 6): 40.0 ms through the
-# scatter-add against 31.3 through the gathers where T * k = capacity, 30.3
-# against 33.0 at twice it, 26.6 against 33.5 and 22.2 against 35.2 at four
-# and eight times it. Past this ratio the grouped path keeps the scatter-add
-MOE_GATHER_MAX_RATIO = 1
+def _combine_links(expert, inv, n_pairs, load, rows, d, dtype, force,
+                   platform):
+    """(inv, computed, plan) of a grouped layer: `plan` is
+    `moe_pallas.combine_plan`'s where `mx_moe_combine` sums a token's rows
+    (the products' float32 rows and x's gradient's rows, of `dtype`), None
+    where XLA's gathers do. Which, from what the trace can see: a program
+    for a TPU whose shapes the kernel takes (`moe_pallas.combine_eligible`);
+    force: as `_swiglu_experts`'s. Counted once a trace: the kernel, and a
+    bf16 program for a TPU whose shapes it refuses (logged with them)."""
+    from . import moe_pallas
+    t, k = inv.shape
+    held = load.shape[0]
+    computed = inv < n_pairs
+    kinds = (_F32, dtype)
+    if force in ("pallas", "interpret") or (force is None and all(
+            moe_pallas.combine_eligible(d, rows, t, k, held, dt, platform)
+            for dt in kinds)):
+        if force is None:
+            _count(MOE_COMBINE_COUNTER, "grouped mixtures traced for a TPU "
+                   "whose rows reach their tokens through mx_moe_combine")
+        tt = min(moe_pallas.combine_tile(t, k, held, d, dt) for dt in kinds)
+        return inv, computed, moe_pallas.combine_plan(expert, inv, load,
+                                                      tt=tt or t)
+    if force is None and dtype == jnp.bfloat16 and \
+            (platform or jax.default_backend()) == "tpu":
+        import logging
+        _count(MOE_COMBINE_FALLBACK_COUNTER, "bf16 grouped mixtures traced "
+               "for a TPU whose shapes mx_moe_combine does not take")
+        logging.getLogger(__name__).warning(
+            "moe_experts: %d tokens, %d a token, rows %s not eligible for "
+            "mx_moe_combine; XLA's gathers", t, k, (rows, d))
+    return inv, computed, None
 
 
 def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
@@ -764,12 +807,11 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
     ever dropped. The grouped products are `_swiglu_experts`'s: Pallas
     kernels in a program for a TPU (the rows past the last pair come back
     as zeros), `jax.lax.ragged_dot` in every other (they come back
-    unwritten); `force` and `platform` are passed on to it. Rows reach
-    the products and their results their tokens by gathers through the
-    sort's permutation and its inverse (`_dispatch`, `_combine`), whose
-    transposes are gathers too, where T * k is at most
-    MOE_GATHER_MAX_RATIO times `capacity`; past it by a gather and a
-    scatter-add."""
+    unwritten); `force` and `platform` are passed on to it and to
+    `_combine_links`. Rows reach the products by a gather through the
+    sort's permutation, and the products' rows their tokens by the
+    kernel `mx_moe_combine` or by XLA's gathers through its inverse
+    (`_dispatch`, `_combine`), both ways and in both directions."""
     t = x.shape[0]
     n_held = w_gate.shape[0]
     n_all = router_weight.shape[0]
@@ -788,33 +830,20 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
         order = jnp.argsort(key, stable=True)
         rows_of = order[:capacity]          # a row's pair
 
-    def products(rows):
-        with jax.named_scope("mx.moe.experts.matmul"):
-            return _swiglu_experts(rows, w_gate, w_up, w_down, load,
-                                   force=force, platform=platform)
-
-    def gathered(_):
+    def grouped(_):
         _count(MOE_GATHER_COUNTER, "grouped mixtures traced with gathers "
                "for their dispatch and combine")
         # the inverse permutation: a pair, token-major, -> its sorted row
         inv = jnp.argsort(order).astype(order.dtype).reshape(t, top_k)
-        computed = inv < n_pairs
-        out = products(_dispatch(x, rows_of, inv, computed))
-        return _combine(out, w, rows_of, inv, computed, x.dtype)
-
-    def scattered(_):
-        token = rows_of // top_k
-        # rows past the held pairs belong to no group: `ragged_dot`
-        # leaves them unwritten (on the TPU: whatever the memory held), so
-        # they are SELECTED away on both sides of it, never multiplied away
-        valid = (jnp.arange(capacity) < n_pairs)[:, None]
-        out = products(jnp.where(valid, jnp.take(x, token, axis=0), 0))
-        # selected BEFORE the product with the pair's weight: the weight's
-        # gradient is a sum over out, and 0 x whatever the memory held is
-        # NaN wherever that is no number
-        return jnp.zeros((t, x.shape[1]), _F32).at[token].add(
-            jnp.where(valid, out, 0.0) * w.reshape(-1)[rows_of][:, None]
-        ).astype(x.dtype)
+        links = _combine_links(key.reshape(t, top_k), inv, n_pairs, load,
+                               capacity, x.shape[1], x.dtype, force,
+                               platform)
+        interpret = force == "interpret"
+        rows = _dispatch(x, rows_of, links, interpret)
+        with jax.named_scope("mx.moe.experts.matmul"):
+            out = _swiglu_experts(rows, w_gate, w_up, w_down, load,
+                                  force=force, platform=platform)
+        return _combine(out, w, rows_of, links, x.dtype, interpret)
 
     def dense(_):
         per_expert = jnp.stack(
@@ -825,8 +854,6 @@ def moe_experts(x, router_weight, router_bias, w_gate, w_up, w_down, *,
 
     with jax.named_scope("mx.moe.experts"):
         fits = n_pairs <= capacity
-        grouped = gathered if t * top_k <= MOE_GATHER_MAX_RATIO * capacity \
-            else scattered
         y = jax.lax.cond(fits, grouped, dense, None)
     stats = jnp.concatenate([load, jnp.stack([
         n_pairs, n_pairs, 1 - fits.astype(jnp.int32)]).astype(jnp.int32)])

@@ -1,4 +1,5 @@
-"""The held experts' grouped products as Pallas TPU kernels.
+"""The held experts' grouped products, and the sum of a token's rows of
+them, as Pallas TPU kernels.
 
 `ops/lm.py::_swiglu_experts` over `jax.lax.ragged_dot` is the algorithm and
 the reference: rows of x (M, D) sorted by expert, `group_sizes` (E,) rows an
@@ -36,6 +37,11 @@ whatever the operands' type; `hidden` is rounded to the operands' type once;
 `ragged_dot`), and a gradient is rounded where autodiff's transpose rounds it
 to its primal's type. float32 operands multiply at the precision the trace's
 `jax.default_matmul_precision` asks, as `ragged_dot`'s do.
+
+Beside them `mx_moe_combine` (`gather_sum`) sums each token's computed rows,
+of the products' result with the routing weights (the combine) or of x's
+gradient without (the dispatch's transpose); `lm._sum_computed` over
+`lm._rows_of_pairs` is its algorithm and reference, to the bit.
 """
 from __future__ import annotations
 
@@ -175,13 +181,12 @@ def _row_mask(lo, hi, shape):
     return (row >= lo) & (row < hi)
 
 
-def _params(interpret):
+def _params(interpret, semantics=("parallel", "arbitrary")):
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT)}
+        dimension_semantics=semantics, vmem_limit_bytes=VMEM_LIMIT)}
 
 
 # -- mx_moe_gmm -------------------------------------------------------------------
@@ -417,3 +422,227 @@ def swiglu_experts(x, w_gate, w_up, w_down, group_sizes, interpret=False):
     fn.defvjp(fwd, bwd)
     return fn(x, w_gate, w_up, w_down,
               *_schedules(group_sizes, m=x.shape[0], tm=tm))
+
+
+# -- mx_moe_combine: a token's computed rows, summed where they land ---------------
+#
+# `lm._dispatch` / `lm._combine` move rows between tokens (T, k pairs each,
+# token-major) and the rows sorted by expert. The sort is stable, so inside
+# one expert's rows the tokens ascend, and for a tile of consecutive tokens
+# the rows of its computed pairs are ONE contiguous run of rows an expert.
+# `mx_moe_combine` walks the token tiles: it copies each run from the rows in
+# HBM into VMEM (whole blocks of CHUNK rows, the copies of the next tile in
+# flight while this one is summed), then adds each computed pair's row,
+# times its weight where there is one, into its token's float32 row in the
+# order j = 0 .. k-1 of `lm._sum_computed`, and writes the tile rounded once:
+# what `lm._sum_computed(lm._rows_of_pairs(...))` gives, to the bit, from the
+# computed rows only (and at most a partial block at each end of a run).
+
+COMBINE_TOKENS = 256            # tokens a grid step, at most
+COMBINE_VMEM = 48 * 2 ** 20     # the runs' two buffers and the tile's rows
+SMEM_BLOCK = 1024               # a 1-D SMEM block's entries come in these
+CHUNK = 16                      # rows a copy: a bf16 (16, 128) tile's
+
+
+def _combine_buffer_rows(tt, k, held):
+    """Rows the runs of one token tile can take: a token's pairs are on
+    distinct experts, and a run's copy adds at most a block at each end."""
+    return tt * min(k, held) + 2 * CHUNK * held
+
+
+def combine_tile(t, k, held, d, dtype):
+    """Tokens a grid step: the largest power of two up to COMBINE_TOKENS
+    that divides t and whose buffers fit COMBINE_VMEM; 0 if none of 16
+    tokens or more does."""
+    size = jnp.dtype(dtype).itemsize
+    tt = COMBINE_TOKENS
+    while tt >= 16:
+        rows = _combine_buffer_rows(tt, k, held)
+        vmem = rows * d * (2 * size + (4 if size < 4 else 0)) + 3 * tt * d * 4
+        if t % tt == 0 and vmem <= COMBINE_VMEM:
+            return tt
+        tt //= 2
+    return 0
+
+
+def combine_eligible(d, rows, t, k, held, dtype, platform=None):
+    """The kernel takes a program for a TPU whose rows (the products' `out`
+    or x's gradient, bf16 or float32) are LANES-wide multiples and whole
+    blocks of copies, over a token count some tile divides, with a
+    token's pairs fewer than the bits of its int32 mask."""
+    return ((platform or jax.default_backend()) == "tpu"
+            and dtype in (jnp.bfloat16, jnp.float32)
+            and d % LANES == 0 and rows % CHUNK == 0 and k < 31
+            and combine_tile(t, k, held, d, dtype) > 0)
+
+
+def _lanes_a_token(k):
+    """A token's slots in the per-pair lists: k rounded up to a power of two,
+    so that a tile's slots are whole SMEM blocks."""
+    return 1 << (k - 1).bit_length()
+
+
+def _smem_block(total, per_tile):
+    """Entries of a 1-D SMEM block that holds a tile's `per_tile` of the
+    `total`: Mosaic takes whole multiples of SMEM_BLOCK, or the whole
+    array."""
+    if per_tile % SMEM_BLOCK == 0:
+        return per_tile
+    if SMEM_BLOCK % per_tile == 0 and total % SMEM_BLOCK == 0:
+        return SMEM_BLOCK
+    return total
+
+
+@functools.partial(jax.jit, static_argnames=("tt",))
+@_under_scope("mx.moe.experts")
+def combine_plan(expert, inv, load, tt):
+    """What the kernel reads besides the rows, from a pair's held expert
+    (T, k) (`held` where it has none), its row `inv` (T, k) and the rows an
+    expert (held,); elementwise work and reductions over the held experts,
+    no sort and no gather. For each (token tile, expert): the run's first
+    row (a whole block), its number of blocks and where it lands in the
+    tile's buffer, int32 (tiles, held) each, for scalar prefetch; a
+    token's computed pairs as a bit mask, int32 (T,); each pair's row in
+    its tile's buffer, int32 (T * kp,) (-1 where not computed; kp slots a
+    token, `_lanes_a_token`)."""
+    t, k = inv.shape
+    held = load.shape[0]
+    tiles, per_tile = t // tt, tt * k
+    ex = expert.reshape(tiles, per_tile)
+    mine = ex[:, :, None] == jnp.arange(held, dtype=ex.dtype)
+    count = jnp.sum(mine, axis=1, dtype=jnp.int32)              # (tiles, E)
+    first = (jnp.cumsum(load) - load).astype(jnp.int32)
+    start = first[None, :] + jnp.cumsum(count, axis=0) - count
+    lo = start // CHUNK * CHUNK
+    blocks = jnp.where(count > 0, -(-(start + count) // CHUNK) - lo // CHUNK,
+                       0)
+    at = (jnp.cumsum(blocks, axis=1) - blocks) * CHUNK           # in the buffer
+    base = jnp.sum(jnp.where(mine, (at - lo)[:, None, :], 0), axis=2)
+    computed = (ex < held).reshape(t, k)
+    row = jnp.where(computed, (base + inv.reshape(tiles, per_tile)).reshape(
+        t, k), -1)
+    mask = jnp.sum(jnp.where(computed, 1 << jnp.arange(k, dtype=jnp.int32),
+                             0), axis=1, dtype=jnp.int32)
+    row = jnp.pad(row, ((0, 0), (0, _lanes_a_token(k) - k)),
+                  constant_values=-1)
+    return lo, blocks, at, mask, row.reshape(-1)
+
+
+def _combine_kernel(lo_ref, blocks_ref, at_ref, mask_ref, row_ref, *refs,
+                    held, tiles, tt, k, kp, blocks_of, weighted):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    if weighted:
+        w_ref, rows_hbm, y_ref, buf, acc, sem, *wide = refs
+    else:
+        rows_hbm, y_ref, buf, acc, sem, *wide = refs
+    i = pl.program_id(0)
+    slot = i % 2
+
+    def copies(tile, slot, start):
+        """Start (or wait for) every block of a tile's runs."""
+        for e in range(held):
+            run = tile * held + e
+
+            def one(b, carry, run=run):
+                dma = pltpu.make_async_copy(
+                    rows_hbm.at[pl.ds(pl.multiple_of(
+                        lo_ref[run] + b * CHUNK, CHUNK), CHUNK)],
+                    buf.at[slot, pl.ds(pl.multiple_of(
+                        at_ref[run] + b * CHUNK, CHUNK), CHUNK)],
+                    sem.at[slot])
+                if start:
+                    dma.start()
+                else:
+                    dma.wait()
+                return carry
+            jax.lax.fori_loop(0, blocks_ref[run], one, 0)
+
+    @pl.when(i == 0)
+    def _():
+        copies(0, 0, True)
+
+    @pl.when(i + 1 < tiles)
+    def _():
+        copies(i + 1, 1 - slot, True)
+
+    copies(i, slot, False)
+    rows = buf.at[slot]
+    if wide:            # bf16 rows: widened a block at a time, then picked
+        rows = wide[0]
+        last = i * held + held - 1
+        used = at_ref[last] // CHUNK + blocks_ref[last]
+
+        def widen(b, carry):
+            b0 = pl.multiple_of(b * CHUNK, CHUNK)
+            wide_rows = buf[slot, pl.ds(b0, CHUNK), :].astype(_F32)
+            rows[pl.ds(b0, CHUNK), :] = wide_rows  # analysis: allow=trace-state-mutation
+            return carry
+        jax.lax.fori_loop(0, used, widen, 0)
+    acc[...] = jnp.zeros_like(acc)
+    mask_at, row_at = ((i * n) % blocks_of[m] for m, n in
+                       (("mask", tt), ("row", tt * kp)))
+
+    def token(t, carry):
+        m = mask_ref[mask_at + t]
+
+        @pl.when(m != 0)
+        def _():
+            for j in range(k):          # in the order of `lm._sum_computed`
+                @pl.when(((m >> j) & 1) == 1)
+                def _(j=j):
+                    q = row_at + t * kp + j
+                    row = rows[pl.ds(row_ref[q], 1), :].astype(_F32)
+                    if weighted:
+                        row = w_ref[q] * row
+                    row = acc[pl.ds(t, 1), :] + row
+                    acc[pl.ds(t, 1), :] = row  # analysis: allow=trace-state-mutation
+        return carry
+    jax.lax.fori_loop(0, tt, token, 0)
+    y_ref[...] = acc[...].astype(y_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "out_dtype", "interpret"))
+@_under_scope("mx.moe.experts")
+def gather_sum(rows, plan, weight, k, out_dtype, interpret=False):
+    """y (T, D) in `out_dtype`: for each token the sum over its computed
+    pairs, in the order j = 0 .. k-1, of the pair's row of `rows` (R, D),
+    times its weight where `weight` (T, k) float32 is given, in float32.
+    `plan` is `combine_plan`'s; no row of `rows` outside the computed
+    pairs' runs is read."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lo, blocks, at, mask, row = plan
+    d = rows.shape[1]
+    t, kp = mask.shape[0], _lanes_a_token(k)
+    tiles, held = lo.shape
+    tt = t // tiles
+    buffer_rows = _combine_buffer_rows(tt, k, held)
+    weighted = weight is not None
+    blocks_of = {"mask": _smem_block(t, tt), "row": _smem_block(t * kp,
+                                                                tt * kp)}
+
+    def smem(name, per_tile):
+        size = blocks_of[name]
+        return pl.BlockSpec((size,), lambda i, *_: (i * per_tile // size,),
+                            memory_space=pltpu.SMEM)
+    operands, specs = [mask, row], [smem("mask", tt), smem("row", tt * kp)]
+    if weighted:
+        operands.append(jnp.pad(weight, ((0, 0), (0, kp - k))).reshape(-1))
+        specs.append(smem("row", tt * kp))
+    scratch = [pltpu.VMEM((2, buffer_rows, d), rows.dtype),
+               pltpu.VMEM((tt, d), _F32), pltpu.SemaphoreType.DMA((2,))]
+    if rows.dtype != _F32:
+        scratch.append(pltpu.VMEM((buffer_rows, d), _F32))
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, held=held, tiles=tiles, tt=tt, k=k,
+                          kp=kp, blocks_of=blocks_of, weighted=weighted),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(tiles,),
+            in_specs=specs + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tt, d), lambda i, *_: (i, 0)),
+            scratch_shapes=scratch),
+        out_shape=_sds((t, d), out_dtype, rows), interpret=interpret,
+        name="mx_moe_combine", **_params(interpret, ("arbitrary",)),
+    )(*(a.reshape(-1) for a in (lo, blocks, at)), *operands, rows)

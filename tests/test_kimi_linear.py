@@ -423,19 +423,18 @@ def _ratio_case(ratio):
     return cfg, p, jnp.asarray(x)
 
 
-@pytest.mark.parametrize("gathers_up_to", [None, 8], ids=["auto", "gathers"])
+@pytest.mark.parametrize("path", ["xla", "interpret"],
+                         ids=["gathers", "kernel"])
 @pytest.mark.parametrize("ratio", sorted(RATIOS))
-def test_moe_gathers_both_ways_and_rows_of_no_group(ratio, gathers_up_to,
+def test_moe_gathers_both_ways_and_rows_of_no_group(ratio, path,
                                                      monkeypatch):
-    """Where T * k is at most MOE_GATHER_MAX_RATIO times the grouped rows
-    (or a larger ratio given here), the gradient program of the layer holds
-    no scatter-add into an array of the hidden width: rows reach the
-    products and their tokens by gathers both ways (past the ratio the
-    scatter-add combine stays). Either way the rows that belong to no
-    group may hold anything: planted as NaN, they reach no gradient, the
-    router's included, and every gradient is the reference's."""
-    if gathers_up_to:
-        monkeypatch.setattr(lm, "MOE_GATHER_MAX_RATIO", gathers_up_to)
+    """At every ratio of T * k to the grouped rows the gradient program of
+    the layer holds no scatter-add into an array of the hidden width: rows
+    reach the products and their tokens by gathers both ways, XLA's or
+    `mx_moe_combine`'s (under the Pallas interpreter). Either way the rows
+    that belong to no group may hold anything: planted as NaN, they reach
+    no gradient, the router's included, and every gradient is the
+    reference's."""
     cfg, p, x = _ratio_case(ratio)
     names = ("w_r", "e_gate", "e_up", "e_down")
     grouped = lm._swiglu_experts
@@ -449,7 +448,7 @@ def test_moe_gathers_both_ways_and_rows_of_no_group(ratio, gathers_up_to,
         q = dict(p, **dict(zip(names, w)))
         if not system:
             return jnp.sum(jnp.sin(ref.moe_mlp(cfg, q, x, shared=False)))
-        return jnp.sum(jnp.sin(_moe_layer(cfg, q, x, "xla")[0]))
+        return jnp.sum(jnp.sin(_moe_layer(cfg, q, x, path)[0]))
 
     args = (x,) + tuple(p[n] for n in names)
     assert _moe_system(cfg, p, x)[1][-1] == 0          # the grouped path
@@ -457,7 +456,7 @@ def test_moe_gathers_both_ways_and_rows_of_no_group(ratio, gathers_up_to,
     jaxpr = jax.make_jaxpr(grad)(*args).jaxpr
     wide = [eqn for eqn in _eqns(jaxpr) if eqn.primitive.name == "scatter-add"
             and eqn.invars[0].aval.shape[-1:] == (32,)]
-    assert (not wide) == (ratio <= lm.MOE_GATHER_MAX_RATIO), wide
+    assert not wide, wide
     monkeypatch.setattr(lm, "_swiglu_experts", unwritten)
     _, got = grad(*args)
     _, want = jax.value_and_grad(functools.partial(loss, system=False),
@@ -469,12 +468,17 @@ def test_moe_gathers_both_ways_and_rows_of_no_group(ratio, gathers_up_to,
 
 @pytest.mark.parametrize("ratio", [1, 8])
 def test_gather_combine_is_counted_once_a_trace(ratio):
+    """Every grouped layer is traced through the gathers, at every ratio;
+    on the CPU its rows reach their tokens through XLA's, so the kernel's
+    counters stay where they were."""
     from mxnet_tpu.telemetry import registry
     cfg, p, x = _ratio_case(ratio)
-    calls = registry.counter(lm.MOE_GATHER_COUNTER)
-    before = calls.value()
+    names = (lm.MOE_GATHER_COUNTER, lm.MOE_COMBINE_COUNTER,
+             lm.MOE_COMBINE_FALLBACK_COUNTER)
+    before = [registry.counter(n).value() for n in names]
     jax.make_jaxpr(lambda x: _moe_layer(cfg, p, x)[0])(x)
-    assert calls.value() - before == (ratio <= lm.MOE_GATHER_MAX_RATIO)
+    assert [registry.counter(n).value() - b
+            for n, b in zip(names, before)] == [1, 0, 0]
 
 
 def test_shares_add_up_to_the_uncut_layer():

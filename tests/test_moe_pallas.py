@@ -164,3 +164,168 @@ def test_eligible_counters_and_the_logged_fallback(cell, caplog):
     assert str((rows + 64, d)) in caplog.text and \
         str((held, d, w)) in caplog.text
     assert traced(rows + 64, "tpu", jnp.float32) == (0, 0)
+
+
+# -- mx_moe_combine ------------------------------------------------------------------
+
+# T * k over the grouped rows (1, 2, 4, 8: `lfm2_moe.train`, `kanana2.train`,
+# `trinity_mini.train`, `kimi_linear.train`): experts a token, held, router
+T_TOY = 128
+COMBINE_RATIOS = {1: (4, 4, 8), 2: (4, 2, 8), 4: (8, 2, 16), 8: (8, 2, 32)}
+
+
+def _routing(ratio, seed):
+    """A token's k distinct experts of the router's, as top-k chooses them,
+    with tokens 32-63 (the second tile of 32) on no held expert and the
+    last held expert chosen by no token. Returns a pair's held expert
+    (T, k) (`held` where none), its row `inv` (T, k), the rows an expert
+    and the grouped rows."""
+    k, held, n_all = COMBINE_RATIOS[ratio]
+    rng = np.random.default_rng(seed)
+    chosen = np.stack([rng.choice(n_all, k, replace=False)
+                       for _ in range(T_TOY)])
+    chosen[32:64] = np.stack([rng.choice(np.arange(held, n_all), k,
+                                         replace=False) for _ in range(32)])
+    chosen = np.where(chosen == held - 1, n_all + 1, chosen)  # never held
+    expert = np.where(chosen < held, chosen, held)
+    order = np.argsort(expert.reshape(-1), kind="stable")
+    inv = np.argsort(order).reshape(T_TOY, k)
+    load = np.bincount(expert.reshape(-1), minlength=held + 1)[:held]
+    rows = T_TOY * k // ratio
+    assert load[-1] == 0 and load.sum() <= rows
+    return (jnp.asarray(expert, jnp.int32), jnp.asarray(inv, jnp.int32),
+            jnp.asarray(load, jnp.int32), rows)
+
+
+@pytest.fixture(scope="class")
+def toy_tokens():
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(mp, "COMBINE_TOKENS", 32)
+        jax.clear_caches()
+        yield
+    jax.clear_caches()
+
+
+class TestCombine:
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("weighted", [True, False],
+                             ids=["combine", "x_grad"])
+    @pytest.mark.parametrize("ratio", sorted(COMBINE_RATIOS))
+    def test_kernel_sums_the_computed_rows_as_the_gathers_do(
+            self, ratio, weighted, dtype, toy_tokens):
+        """`mx_moe_combine` under the interpreter against XLA's gathers
+        (`lm._sum_computed(lm._rows_of_pairs(...))`), to the bit, at each
+        ratio of pairs to rows: weighted (the combine) and not (x's
+        gradient), float32 and bf16 rows; every row past the last pair,
+        the rows of the pairs not computed among them, holds NaN, which the
+        kernel never reads. Four tiles of 32 tokens, whose runs start and
+        end inside blocks and span experts' boundaries; the second tile has
+        no computed pair and the last held expert no pair at all. The
+        weights are powers of two, so each product is exact and the CPU's
+        fused multiply-adds round as the chip's separate steps do: what is
+        compared is which rows are summed, and in which order."""
+        k = COMBINE_RATIOS[ratio][0]
+        expert, inv, load, n_rows = _routing(ratio, ratio)
+        n_pairs = int(load.sum())
+        rng = np.random.default_rng(ratio + 10 * weighted)
+        rows = rng.standard_normal((n_rows, 32)).astype(np.float32)
+        rows[n_pairs:] = np.nan
+        rows = jnp.asarray(rows, dtype)
+        weight = jnp.asarray(2.0 ** rng.integers(-3, 3, (T_TOY, k)),
+                             jnp.float32) if weighted else None
+        computed = inv < n_pairs
+        assert not bool(computed[32:64].any()) and bool(computed.any())
+        tt = mp.combine_tile(T_TOY, k, load.shape[0], 32, rows.dtype)
+        assert tt == 32
+        plan = mp.combine_plan(expert, inv, load, tt=tt)
+        out_dtype = rows.dtype if not weighted else jnp.float32
+        got = mp.gather_sum(rows, plan, weight, k=k, out_dtype=out_dtype,
+                            interpret=True)
+        want = lm._sum_computed(lm._rows_of_pairs(rows, inv), computed,
+                                weight).astype(out_dtype)
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert np.isfinite(got).all() and not got[32:64].any()
+        assert np.array_equal(got, want)
+
+    @pytest.mark.parametrize("ratio", sorted(COMBINE_RATIOS))
+    def test_plan_reads_only_the_computed_rows(self, ratio, toy_tokens):
+        """The runs a tile copies cover its computed pairs' rows, in whole
+        blocks of CHUNK rows below n_pairs rounded up to a block: the bytes
+        read are the computed rows and at most a block at each end of a
+        run; each computed pair's row in its tile's buffer holds the row it
+        names, and no other pair has one."""
+        k, held, _ = COMBINE_RATIOS[ratio]
+        expert, inv, load, n_rows = _routing(ratio, ratio)
+        n_pairs = int(load.sum())
+        tt = 32
+        lo, blocks, at, mask, row = (np.asarray(a) for a in mp.combine_plan(
+            expert, inv, load, tt=tt))
+        kp = mp._lanes_a_token(k)
+        row = row.reshape(T_TOY, kp)
+        inv, expert = np.asarray(inv), np.asarray(expert)
+        assert not (row[:, k:] + 1).any()
+        copied = 0
+        for i in range(T_TOY // tt):
+            buffer = np.full(tt * k + 2 * mp.CHUNK * held, -1)
+            for e in range(held):
+                n = blocks[i, e] * mp.CHUNK
+                assert lo[i, e] % mp.CHUNK == 0 and at[i, e] % mp.CHUNK == 0
+                assert lo[i, e] + n <= -(-n_pairs // mp.CHUNK) * mp.CHUNK
+                buffer[at[i, e]:at[i, e] + n] = np.arange(lo[i, e],
+                                                          lo[i, e] + n)
+                copied += n
+            for t in range(i * tt, (i + 1) * tt):
+                for j in range(k):
+                    on = expert[t, j] < held
+                    assert bool(mask[t] >> j & 1) == on
+                    assert (buffer[row[t, j]] == inv[t, j]) if on else \
+                        row[t, j] == -1
+        runs = int(np.sum(blocks > 0))
+        assert n_pairs <= copied <= n_pairs + 2 * mp.CHUNK * runs
+
+
+# T, k, grouped rows, D, held experts of the four cells' mixture layers
+COMBINE_CELLS = {"lfm2_moe.train": (16384, 4, 65536, 2048, 8),
+                 "kanana2.train": (16384, 6, 49152, 2048, 16),
+                 "trinity_mini.train": (16384, 8, 32768, 2048, 8),
+                 "kimi_linear.train": (16384, 8, 16384, 2304, 8)}
+
+
+@pytest.mark.parametrize("cell", COMBINE_CELLS)
+def test_combine_eligible_counters_and_the_logged_fallback(cell, caplog):
+    """`mx_moe_combine` takes every cell's shapes in a program for a TPU, at
+    tiles of 256 tokens; a bf16 program for a TPU whose shapes it refuses
+    is counted and logged with them, and takes XLA's gathers."""
+    from mxnet_tpu.telemetry import registry
+    t, k, rows, d, held = COMBINE_CELLS[cell]
+    for dtype in (jnp.bfloat16, jnp.float32):
+        assert mp.combine_eligible(d, rows, t, k, held, dtype, "tpu")
+        assert mp.combine_tile(t, k, held, d, dtype) == 256
+        assert not mp.combine_eligible(d, rows, t, k, held, dtype, "cpu")
+        assert not mp.combine_eligible(d + 64, rows, t, k, held, dtype,
+                                       "tpu")
+        assert not mp.combine_eligible(d, rows + 8, t, k, held, dtype, "tpu")
+    assert not mp.combine_eligible(d, rows, t, k, held, jnp.float16, "tpu")
+
+    calls = registry.counter(lm.MOE_COMBINE_COUNTER)
+    falls = registry.counter(lm.MOE_COMBINE_FALLBACK_COUNTER)
+
+    def traced(d, platform, dtype=jnp.bfloat16):
+        pairs = jax.ShapeDtypeStruct((t, k), jnp.int32)
+        before = calls.value(), falls.value()
+        inv, computed, plan = jax.eval_shape(
+            lambda e, i, n, l: lm._combine_links(e, i, n, l, rows, d, dtype,
+                                                 None, platform),
+            pairs, pairs, jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((held,), jnp.int32))
+        assert inv.shape == computed.shape == (t, k)
+        return calls.value() - before[0], falls.value() - before[1], plan
+
+    assert traced(d, "cpu")[:2] == (0, 0)
+    calls_, falls_, plan = traced(d, "tpu")             # once a trace
+    assert (calls_, falls_) == (1, 0) and plan[0].shape == (t // 256, held)
+    assert traced(d, "tpu", jnp.float32)[:2] == (1, 0)  # check (b)'s program
+    with caplog.at_level(logging.WARNING, logger=lm.__name__):
+        assert traced(d + 64, "tpu") == (0, 1, None)
+    assert str((rows, d + 64)) in caplog.text
+    assert traced(d + 64, "tpu", jnp.float32) == (0, 0, None)

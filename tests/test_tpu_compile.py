@@ -158,10 +158,12 @@ def test_moe_grouped_kernels_forward_and_gradient(one_chip, cell, rows, d, w,
 def test_moe_layer_dispatch_and_combine(one_chip, cell, d, w, held, n_all, k,
                                         ratio):
     """One mixture layer's value and gradients at a cell's shape (2 x 8,192
-    tokens, bf16) through `lm.moe_experts`: the kernels' 2 + 6 calls, and
-    where T * k is at most `MOE_GATHER_MAX_RATIO` times the grouped rows no
-    scatter writes an array of (T, D) or (rows, D), forward or backward:
-    both ways are gathers. Past the ratio the scatter-add combine stays."""
+    tokens, bf16) through `lm.moe_experts`: the grouped kernels' 2 + 6
+    calls, and `mx_moe_combine` once forward (the combine) and once more
+    backward (x's gradient). At every ratio of T * k to the grouped rows
+    no scatter writes an array of (T, D) or (rows, D), forward or
+    backward, and no array of a token's k rows, (k, T, D) or (T * k, D),
+    exists (at ratio 1 (T * k, D) is the grouped rows' own shape)."""
     from mxnet_tpu.ops import lm
     t = 2 * 8192
     capacity = t * k // ratio
@@ -184,10 +186,17 @@ def test_moe_layer_dispatch_and_combine(one_chip, cell, d, w, held, n_all, k,
     forward = _compiled_text(fwd, *specs)
     both = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5)),
                           *specs)
-    assert forward.count(KERNEL) == 2 and both.count(KERNEL) == 6
+    assert forward.count(KERNEL) == 2 + 1 and both.count(KERNEL) == 6 + 2
+    combine = r"%mx_moe_combine\S* = "
+    assert len(re.findall(combine, forward)) == 1
+    assert len(re.findall(combine, both)) == 2
     wide = re.findall(r"\[(?:%d|%d),%d\]\S* scatter\(" % (t, capacity, d),
                       forward + both)
-    assert (not wide) == (ratio <= lm.MOE_GATHER_MAX_RATIO), wide
+    assert not wide, wide
+    rows = [r"\[%d,%d,%d\]" % (k, t, d)] + (
+        [r"\[%d,%d\]" % (t * k, d)] if ratio > 1 else [])
+    kept = re.findall("|".join(rows), forward + both)
+    assert not kept, kept
 
 
 def test_flash_attention_grouped_heads_of_64(one_chip):
